@@ -43,7 +43,7 @@ def main(argv=None):
     ap.add_argument("--profile", default=None, metavar="NPZ",
                     help="saved SparsityProfile npz: the sim_speed compute "
                          "sweep adds rows priced under its trained "
-                         "densities/masks (falls back to synthetic only)")
+                         "densities/masks (an unreadable file is an error)")
     ap.add_argument("--devices", type=int, default=None,
                     help="force N CPU host devices for the sharded-search "
                          "section (must run before jax initializes)")
@@ -65,6 +65,16 @@ def main(argv=None):
     if args.compute:
         from repro.neuromorphic import compute
         compute.DEFAULT_COMPUTE = args.compute
+
+    from repro.launch.mesh import enable_compile_cache
+    enable_compile_cache()
+    profile = None
+    if args.profile:
+        from repro.sparsity import SparsityProfile
+        try:
+            profile = SparsityProfile.load(args.profile)
+        except (OSError, KeyError, ValueError) as e:
+            ap.error(f"--profile {args.profile} unreadable: {e}")
 
     from benchmarks import (act_schedules, compute_floor, iso_accuracy,
                             max_synops, model_zoo, search_mapping,
@@ -99,14 +109,6 @@ def main(argv=None):
         elif mod is model_zoo:
             res = mod.run(args.quick, arch=args.arch)
         elif mod is sim_speed:
-            profile = None
-            if args.profile:
-                from repro.sparsity import SparsityProfile
-                try:
-                    profile = SparsityProfile.load(args.profile)
-                except (OSError, KeyError, ValueError) as e:
-                    print(f"   [--profile {args.profile} unreadable ({e}); "
-                          "synthetic compute grid only]")
             res = mod.run(args.quick, profile=profile)
         else:
             res = mod.run(args.quick)

@@ -353,16 +353,14 @@ def main(argv=None) -> int:
                     help="rerun only the engine rows")
     ap.add_argument("--profile", default=None, metavar="NPZ",
                     help="price extra compute rows under a saved "
-                         "SparsityProfile (falls back to the synthetic "
-                         "grid alone if the file is unreadable)")
+                         "SparsityProfile (an unreadable file is an error)")
     args = ap.parse_args(argv)
     profile = None
     if args.profile:
         try:
             profile = SparsityProfile.load(args.profile)
         except (OSError, KeyError, ValueError) as e:
-            print(f"  [--profile {args.profile} unreadable ({e}); "
-                  "synthetic grid only]")
+            ap.error(f"--profile {args.profile} unreadable: {e}")
     only = None
     if args.compute and not args.engine:
         only = "compute"

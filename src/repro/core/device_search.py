@@ -77,14 +77,12 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 from jax.sharding import PartitionSpec
 
 from repro.core.resilience import (Demotion, FaultPlan, RetryPolicy,
                                    SearchCheckpointer, finite_mean,
                                    quarantine_rows, validate_resume_meta)
 from repro.distributed.collectives import gather_islands, ring_shift
-from repro.distributed.compat import shard_map
 from repro.core.search import (Candidate, EpsParetoArchive, GenStats,
                                MoveTables, Population, SearchResult,
                                _validate_search_args, decode, move_tables,
@@ -372,7 +370,7 @@ class DeviceSearchEngine:
         self.n_layers = len(cache.layers)
         self.n_slots = int(profile.n_cores)
         self.n_phys = int(tables.n_cores_phys)
-        with enable_x64():
+        with jax.enable_x64(True):
             self.feasible = jnp.asarray(tables.feasible)
         self._init_fn = jax.jit(self._init_impl)
         self._step_fn = jax.jit(self._step_impl, static_argnames=("n_off",))
@@ -402,12 +400,12 @@ class DeviceSearchEngine:
                                 self.explore_prob, state, draws)
 
     def init(self, cores, perm):
-        with enable_x64():
+        with jax.enable_x64(True):
             return self._init_fn(jnp.asarray(cores, jnp.int32),
                                  jnp.asarray(perm, jnp.int32))
 
     def step(self, state, key, n_off: int):
-        with enable_x64():
+        with jax.enable_x64(True):
             return self._step_fn(state, key, n_off=n_off)
 
 
@@ -475,7 +473,7 @@ class ShardedSearchEngine:
         self.n_layers = len(cache.layers)
         self.n_slots = int(profile.n_cores)
         self.n_phys = int(tables.n_cores_phys)
-        with enable_x64():
+        with jax.enable_x64(True):
             self.feasible = jnp.asarray(tables.feasible)
         spec = PartitionSpec("island")
         self._init_fn = self._wrap(self._init_impl, n_in=2,
@@ -489,9 +487,9 @@ class ShardedSearchEngine:
         axis (a spec is a pytree *prefix*, so one P("island") covers a
         whole state dict)."""
         spec = PartitionSpec("island")
-        return jax.jit(shard_map(f, mesh=self.mesh,
-                                 in_specs=(spec,) * n_in,
-                                 out_specs=out_specs, check_vma=False))
+        return jax.jit(jax.shard_map(f, mesh=self.mesh,
+                                     in_specs=(spec,) * n_in,
+                                     out_specs=out_specs, check_vma=False))
 
     def _price(self, cores, perm):
         o = jax.vmap(self.pricer.price_row)(cores, perm)
@@ -557,7 +555,7 @@ class ShardedSearchEngine:
         return fn
 
     def init(self, cores, perm):
-        with enable_x64():
+        with jax.enable_x64(True):
             return self._init_fn(jnp.asarray(cores, jnp.int32),
                                  jnp.asarray(perm, jnp.int32))
 
@@ -565,13 +563,13 @@ class ShardedSearchEngine:
         """One generation on every island from the stacked per-island
         ``keys`` (:func:`island_keys`); ``n_off`` is the per-island
         offspring count."""
-        with enable_x64():
+        with jax.enable_x64(True):
             return self._step_for(n_off, migrate)(state, jnp.asarray(keys))
 
     def migrate(self, state):
         """The migration collective alone (jitted) — the unit the
         multiset-preservation property test drives directly."""
-        with enable_x64():
+        with jax.enable_x64(True):
             return self._migrate_fn(state)
 
 
@@ -649,7 +647,7 @@ class _NumpyMirror:
         return state, dict(times=out["times"], energies=out["energies"])
 
     def step(self, state, key, n_off: int):
-        with enable_x64():
+        with jax.enable_x64(True):
             draws = jax.device_get(generation_draws(
                 key, n_off=n_off, n_pop=state["cores"].shape[0],
                 n_layers=self.n_layers, n_slots=self.n_slots,
@@ -744,7 +742,7 @@ class _ShardedHostMirror:
         new_blocks, offs = [], []
         n_quar = 0
         for i, blk in enumerate(self._blocks(state)):
-            with enable_x64():
+            with jax.enable_x64(True):
                 draws = jax.device_get(generation_draws(
                     jnp.asarray(keys[i]), n_off=n_off,
                     n_pop=self.local_pop, n_layers=self.base.n_layers,
@@ -767,9 +765,10 @@ class _ShardedHostMirror:
 class _ResilientEngine:
     """Graceful-degradation shell around a jitted generation engine.
 
-    A failed ``init``/``step`` (compile error, device OOM, runtime fault —
-    or an injected one at the engine's :class:`FaultPlan` site,
-    ``"device"`` or ``"sharded"``) is retried per the
+    Without ``fallback`` a failed ``init``/``step`` propagates at once (the
+    engine's :class:`FaultPlan` site, ``"device"`` or ``"sharded"``, still
+    injects).  With it, a failed call (compile error, device OOM, runtime
+    fault, or an injected one) is retried per the
     :class:`RetryPolicy`; when the retries are exhausted the engine
     demotes **permanently** to its host NumPy mirror (a failed compile
     fails again — flapping back is pointless).  The mirror consumes the
@@ -781,8 +780,9 @@ class _ResilientEngine:
     def __init__(self, primary, mirror_factory, *,
                  retry: RetryPolicy | None = None,
                  fault_plan: FaultPlan | None = None,
-                 backend: str = "device"):
+                 backend: str = "device", fallback: bool = False):
         self.engine = primary
+        self.fallback = fallback
         self._mirror_factory = mirror_factory
         self.retry = retry or RetryPolicy()
         self.fault_plan = fault_plan
@@ -791,6 +791,10 @@ class _ResilientEngine:
         self.demotions: list[Demotion] = []
 
     def _run(self, call, site: str):
+        if not self.fallback:
+            if self.fault_plan is not None:
+                self.fault_plan.check(self.backend)
+            return call(self.engine)
         while True:
             delay = self.retry.backoff_s
             last = None
@@ -876,7 +880,8 @@ def evolutionary_search_device(
     restore the engine's device state dict — resume is bit-identical
     because each generation is a pure function of ``(key, gen,
     survivors)`` under the PRNG-key contract.  A failed jitted
-    ``init``/``step`` is retried per ``retry`` and then demoted
+    ``init``/``step`` raises, unless the evaluator was built with
+    ``fallback=True``: then it is retried per ``retry`` and demoted
     permanently to the host mirror (logged; recorded in
     ``SearchResult.demotions``).  ``fault_plan`` scripts deterministic
     faults: ``fail={"device": n}`` makes the next ``n`` jitted calls
@@ -918,7 +923,8 @@ def evolutionary_search_device(
             _engine_for(net, profile, cache, tables,
                         explore_prob=explore_prob,
                         tournament_k=tournament_k),
-            _mirror, retry=retry, fault_plan=fault_plan)
+            _mirror, retry=retry, fault_plan=fault_plan,
+            fallback=getattr(evaluator, "fallback", False))
     base_key = jax.random.PRNGKey(seed)
     archive = EpsParetoArchive(pareto_eps)
 
@@ -1082,8 +1088,9 @@ def evolutionary_search_sharded(
     :func:`~repro.core.resilience.validate_resume_meta` (a checkpoint is
     only bit-identical under the configuration that wrote it).
     ``reference=True`` swaps the jitted program for
-    :class:`_ShardedHostMirror`; a failed jitted call demotes to the same
-    mirror through :class:`_ResilientEngine` (``fail={"sharded": n}`` of a
+    :class:`_ShardedHostMirror`; under an evaluator with ``fallback=True``
+    a failed jitted call demotes to the same mirror through
+    :class:`_ResilientEngine` (``fail={"sharded": n}`` of a
     :class:`FaultPlan` injects such failures).  See
     ``docs/distributed.md``.
     """
@@ -1151,7 +1158,8 @@ def evolutionary_search_sharded(
                                 local_pop=local_pop, n_migrants=n_migrants,
                                 explore_prob=explore_prob,
                                 tournament_k=tournament_k),
-            _mirror, retry=retry, fault_plan=fault_plan, backend="sharded")
+            _mirror, retry=retry, fault_plan=fault_plan, backend="sharded",
+            fallback=getattr(evaluator, "fallback", False))
     base_key = jax.random.PRNGKey(seed)
     archive = EpsParetoArchive(pareto_eps)
 

@@ -80,22 +80,25 @@ class SimEvaluator:
     engine="device")``), which prices inside its own jitted generation
     step and charges ``n_evals`` here per generation.
 
-    Population pricing degrades gracefully (``docs/robustness.md``): a
-    backend failure — compile error, device OOM, runtime fault, or an
-    injected one — is retried per ``retry`` and then demoted down the
-    ``device -> vmap -> numpy`` chain (sticky; logged; recorded in
-    :attr:`demotions`).  The backends agree at float64 roundoff, so a
+    A backend failure (compile error, device OOM, runtime fault, or an
+    injected one) propagates by default, so a run that exits cleanly ran
+    on the backend it asked for.  ``fallback=True`` opts in to graceful
+    degradation (``docs/robustness.md``): a failure is retried per
+    ``retry`` and then demoted down the ``device -> vmap -> numpy`` chain
+    (sticky; logged; recorded in :attr:`demotions`), and the device and
+    sharded search engines driven by this evaluator demote to their host
+    mirrors the same way.  The backends agree at float64 roundoff, so a
     mid-run demotion perturbs a search trajectory by at most rtol=1e-9
-    against a numpy-only run.  ``fallback=False`` restores fail-fast
-    behavior.  ``fault_plan`` is the deterministic fault-injection hook
-    (:class:`repro.core.resilience.FaultPlan`): scripted backend failures
-    and NaN pricing rows for the robustness suite.
+    against a numpy-only run.  ``fault_plan`` is the deterministic
+    fault-injection hook (:class:`repro.core.resilience.FaultPlan`):
+    scripted backend failures and NaN pricing rows for the robustness
+    suite.
     """
 
     def __init__(self, net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
                  *, engine: str | None = None, cache=None,
                  population_backend: str = "numpy", compute=None,
-                 fault_plan=None, fallback: bool = True, retry=None,
+                 fault_plan=None, fallback: bool = False, retry=None,
                  sparsity_profile=None):
         from repro.core.resilience import FallbackChain
         from repro.neuromorphic import timestep
@@ -125,6 +128,7 @@ class SimEvaluator:
                       if self.engine == "batched" else None)
         self.n_evals = 0
         self.fault_plan = fault_plan
+        self.fallback = fallback
         self._chain = (FallbackChain(population_backend, retry=retry)
                        if fallback else None)
 

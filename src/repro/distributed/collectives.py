@@ -45,8 +45,8 @@ def ring_shift(tree, *, size: int, axis_name: str = "island",
     """Rotate every leaf's shard ``shift`` positions around the mesh ring:
     island ``i`` sends its block to island ``(i + shift) % size`` and
     receives island ``(i - shift) % size``'s.  ``size`` is the static mesh
-    axis size (``ppermute`` permutations must be python data — a traced
-    ``axis_size`` cannot build them, see ``distributed.compat``).  Only
+    axis size (``ppermute`` permutations must be python data, which a
+    traced axis size cannot build).  Only
     valid inside a ``shard_map`` over ``axis_name``."""
     size = int(size)
     if size < 1:
@@ -92,11 +92,10 @@ def compressed_psum_mean(x: jax.Array, err: jax.Array, axis_names):
     q2 = jnp.clip(jnp.round(xf / gmax), -127, 127).astype(jnp.int8)
     new_err = xf - q2.astype(jnp.float32) * gmax
     total = jax.lax.psum(q2.astype(jnp.int32), axis_names)
-    from repro.distributed.compat import axis_size
     n = 1
     for a in (axis_names if isinstance(axis_names, (tuple, list))
               else (axis_names,)):
-        n *= axis_size(a)
+        n *= jax.lax.axis_size(a)
     return total.astype(jnp.float32) * gmax / n, new_err
 
 

@@ -26,7 +26,7 @@ from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.models import moe as moe_lib
 from repro.models.common import BlockCfg, ModelCfg
@@ -53,8 +53,8 @@ def island_mesh(n_islands: int | None = None, *, devices=None):
             "visible — on CPU launch via `python -m benchmarks.run "
             f"--devices {n}` (or set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count={n} before python imports jax)")
-    from repro.distributed.compat import make_mesh
-    return make_mesh((n,), ("island",), devices=devs[:n])
+    return jax.make_mesh((n,), ("island",), (AxisType.Auto,),
+                         devices=devs[:n])
 
 
 def make_ctx(mesh, *, batch_size: int | None = None) -> ShardCtx:

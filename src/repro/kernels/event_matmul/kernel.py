@@ -18,6 +18,11 @@ Grid: (M/bm, N/bn, K/bk), k innermost.  For grid step (m, n, k):
 
 Block shapes default to MXU-native 128x128x128 and must keep the last axis a
 multiple of 128 and the second-to-last a multiple of 8 (f32) for VMEM tiling.
+
+``precision`` defaults to HIGHEST: on the TPU the MXU's default single
+bf16 pass rounds f32 operands to 8 mantissa bits, which moves values by
+~3e-3 relative.  0/1 counter operands are exact in one pass and may pass
+``Precision.DEFAULT``.
 """
 
 from __future__ import annotations
@@ -29,9 +34,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _event_matmul_kernel(idx_ref, cnt_ref, x_ref, w_ref, o_ref, acc_ref, *,
-                         n_k_blocks: int, out_dtype):
+                         n_k_blocks: int, out_dtype, precision):
     m = pl.program_id(0)
     k = pl.program_id(2)
 
@@ -41,7 +48,7 @@ def _event_matmul_kernel(idx_ref, cnt_ref, x_ref, w_ref, o_ref, acc_ref, *,
 
     @pl.when(k < cnt_ref[m])
     def _accumulate():                      # skipped for event-free tiles
-        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...], precision=precision,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k_blocks - 1)
@@ -50,7 +57,7 @@ def _event_matmul_kernel(idx_ref, cnt_ref, x_ref, w_ref, o_ref, acc_ref, *,
 
 
 def _event_matmul2_kernel(idx_ref, cnt_ref, x_ref, w_ref, o_ref, acc_ref, *,
-                          n_k_blocks: int, out_dtype):
+                          n_k_blocks: int, out_dtype, precision):
     """2-D (activation x weight tile) sparsity: the compacted k list is per
     (m, n) block pair, so a grid step is skipped when EITHER the activation
     tile is event-free OR the weight tile is all-zero."""
@@ -64,7 +71,7 @@ def _event_matmul2_kernel(idx_ref, cnt_ref, x_ref, w_ref, o_ref, acc_ref, *,
 
     @pl.when(k < cnt_ref[m, n])
     def _accumulate():                      # skipped: no events or no weights
-        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...], precision=precision,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k_blocks - 1)
@@ -74,7 +81,8 @@ def _event_matmul2_kernel(idx_ref, cnt_ref, x_ref, w_ref, o_ref, acc_ref, *,
 
 def event_matmul2_pallas(x: jax.Array, w: jax.Array, idx: jax.Array,
                          cnt: jax.Array, *, bm: int, bk: int, bn: int,
-                         out_dtype=None, interpret: bool = False) -> jax.Array:
+                         out_dtype=None, interpret: bool = False,
+                         precision=HIGHEST) -> jax.Array:
     """Joint-sparsity launch.  ``idx`` (Mb, Nb, Kb) int32 holds, per (m, n)
     block pair, the compacted k-block indices live in BOTH the activation
     row (tile has an event) and the weight column (tile has a nonzero
@@ -100,7 +108,7 @@ def event_matmul2_pallas(x: jax.Array, w: jax.Array, idx: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
     kernel = functools.partial(_event_matmul2_kernel, n_k_blocks=kb,
-                               out_dtype=out_dtype)
+                               out_dtype=out_dtype, precision=precision)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -112,7 +120,8 @@ def event_matmul2_pallas(x: jax.Array, w: jax.Array, idx: jax.Array,
 
 def event_matmul_pallas(x: jax.Array, w: jax.Array, idx: jax.Array,
                         cnt: jax.Array, *, bm: int, bk: int, bn: int,
-                        out_dtype=None, interpret: bool = False) -> jax.Array:
+                        out_dtype=None, interpret: bool = False,
+                        precision=HIGHEST) -> jax.Array:
     """Launch the kernel.  ``idx`` (Mb, Kb) int32 holds, per m-block, the
     compacted active k-block indices (padding entries repeat the last active
     index); ``cnt`` (Mb,) int32 holds the active counts.  All of M, K, N must
@@ -136,7 +145,7 @@ def event_matmul_pallas(x: jax.Array, w: jax.Array, idx: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
     kernel = functools.partial(_event_matmul_kernel, n_k_blocks=kb,
-                               out_dtype=out_dtype)
+                               out_dtype=out_dtype, precision=precision)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

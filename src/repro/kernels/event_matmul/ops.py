@@ -199,16 +199,20 @@ def event_matmul_pair(x: jax.Array, m: jax.Array, w: jax.Array,
     mp, ma, mi, mc = pad_compact(m, 0.0, bm, bk)
     wp = _pad_to(w, (bk, bn))
     wmp = _pad_to(wm, (bk, bn))
+    # the counter operands are 0/1: exact in the MXU's single bf16 pass
+    one_pass = jax.lax.Precision.DEFAULT
     if w_occ is None:
         y = event_matmul_pallas(xp, wp, xi, xc, bm=bm, bk=bk, bn=bn,
                                 out_dtype=x.dtype, interpret=interpret)
         macs = event_matmul_pallas(mp, wmp, mi, mc, bm=bm, bk=bk, bn=bn,
-                                   out_dtype=m.dtype, interpret=interpret)
+                                   out_dtype=m.dtype, interpret=interpret,
+                                   precision=one_pass)
     else:
         xi2, xc2 = _compact_indices_joint(xa, w_occ)
         mi2, mc2 = _compact_indices_joint(ma, w_occ)
         y = event_matmul2_pallas(xp, wp, xi2, xc2, bm=bm, bk=bk, bn=bn,
                                  out_dtype=x.dtype, interpret=interpret)
         macs = event_matmul2_pallas(mp, wmp, mi2, mc2, bm=bm, bk=bk, bn=bn,
-                                    out_dtype=m.dtype, interpret=interpret)
+                                    out_dtype=m.dtype, interpret=interpret,
+                                    precision=one_pass)
     return y[:M, :N], macs[:M, :N]

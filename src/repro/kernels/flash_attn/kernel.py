@@ -87,7 +87,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            softcap: float | None = None,
                            bq: int = 128, bk: int = 128,
                            kv_len: int | None = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q: (B, Sq, H, hd); k/v: (B, Skv, K, hd); H % K == 0.
 
     Query heads are grouped with their KV head: grid axis 0 iterates

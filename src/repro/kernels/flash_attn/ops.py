@@ -15,12 +15,15 @@ from repro.kernels.flash_attn.kernel import flash_attention_pallas
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: int | None = None, softcap: float | None = None,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """Pads Sq/Skv to block multiples, launches the kernel, slices back.
     Pad queries produce garbage rows that are sliced off; pad KV rows are
     masked inside the kernel via ``kv_len`` (the real key count), which
     keeps non-causal attention — encoder/cross blocks lowered by the
-    model-zoo frontend — exact too."""
+    model-zoo frontend — exact too.  ``interpret`` forces Pallas interpret
+    mode (auto: on for CPU backends)."""
+    if interpret is None:
+        interpret = jax.default_backend() == "cpu"
     B, Sq, H, hd = q.shape
     Skv = k.shape[1]
     pq = (-Sq) % bq

@@ -51,7 +51,9 @@ def _window_cumsum_kernel(live_ref, x_ref, o_ref):
         r = jax.lax.broadcasted_iota(jnp.int32, (W, W), 0)
         c = jax.lax.broadcasted_iota(jnp.int32, (W, W), 1)
         tri = (r >= c).astype(jnp.float32)
-        o_ref[...] = jnp.dot(tri, x,
+        # HIGHEST: the MXU's default single bf16 pass would round the
+        # deltas to 8 mantissa bits
+        o_ref[...] = jnp.dot(tri, x, precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32
                              ).astype(o_ref.dtype)
 

@@ -1,4 +1,5 @@
-"""Production mesh builders + the pre-import host-device-count switch.
+"""Production meshes, the pre-import host-device-count switch and the
+persistent compilation cache location.
 
 ``make_production_mesh`` is a FUNCTION (not a module-level constant) so that
 importing this module never touches jax device state — and since the
@@ -31,6 +32,28 @@ import sys
 import numpy as np
 
 _FORCE_FLAG = "--xla_force_host_platform_device_count"
+
+#: fixed in-checkout cache directory, used when the environment names none
+COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at one fixed directory and
+    return it.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and no
+    other directory is set.  Otherwise the cache lives in ``.jax_cache/``
+    at the root of this checkout: the path is part of the cache key, so it
+    must not move between runs.  Call from an entry point, never at import.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def forced_host_device_count() -> int | None:
@@ -110,7 +133,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             "visible — launch via repro.launch.dryrun (it sets "
             "XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             "importing jax)")
-    return jax.make_mesh(shape, axes,
+    return jax.make_mesh(shape, axes, (jax.sharding.AxisType.Auto,) * len(shape),
                          devices=devices[:n])
 
 
@@ -123,5 +146,5 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     if len(devices) < n:
         raise RuntimeError(f"mesh {shape} needs {n} devices, "
                            f"have {len(devices)}")
-    return jax.make_mesh(shape, axes,
+    return jax.make_mesh(shape, axes, (jax.sharding.AxisType.Auto,) * len(shape),
                          devices=devices[:n])

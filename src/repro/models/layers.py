@@ -26,7 +26,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.common import BlockCfg, ModelCfg, RGLRUCfg, SSDCfg
 
@@ -98,7 +98,7 @@ class ShardCtx:
 
 def single_device_mesh() -> Mesh:
     """1-device mesh with the production axis names (for smoke tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"),
+    return jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2,
                          devices=np.array(jax.devices()[:1]))
 
 

@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 from repro.models.common import MoECfg, ModelCfg
 from repro.models.layers import ACTS, KeyGen, ShardCtx, _init
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 
 def moe_params(kg: KeyGen, cfg: ModelCfg, m: MoECfg, dtype) -> dict:

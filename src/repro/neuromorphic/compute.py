@@ -167,10 +167,13 @@ class DenseCompute(LayerCompute):
         m4 = jnp.asarray(to_nhwc(act_mask))
         wj, wmask, wones = layer._conv_kernels
 
-        conv = lambda lhs, rhs: jax.lax.conv_general_dilated(
+        conv = lambda lhs, rhs, precision=None: jax.lax.conv_general_dilated(
             lhs, rhs, window_strides=(layer.stride, layer.stride),
-            padding="SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        pre = np.asarray(conv(x4, wj))                 # (T, oh, ow, cout)
+            padding="SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+            precision=precision)
+        # values at f32 (HIGHEST): a TPU's default single bf16 pass moves
+        # them ~3e-3 relative; the 0/1 counter convs are exact in one pass
+        pre = np.asarray(conv(x4, wj, jax.lax.Precision.HIGHEST))
         macs = np.asarray(conv(m4, wmask))
         fetches = np.asarray(conv(m4, wones))
         to_flat = lambda a: np.transpose(a, (0, 3, 1, 2)).reshape(T, -1)

@@ -525,7 +525,6 @@ def attention_probe(spec: AttnSpec, *, seed: int = 0
     v = jax.random.normal(kv, (1, spec.seq, spec.kv_heads, spec.head_dim),
                           jnp.float32)
     kw = dict(causal=spec.causal, window=spec.window, softcap=spec.softcap)
-    out = np.asarray(flash_attention(q, k, v, interpret=True, **kw),
-                     np.float32)
+    out = np.asarray(flash_attention(q, k, v, **kw), np.float32)
     ref = np.asarray(flash_attention_ref(q, k, v, **kw), np.float32)
     return out, ref

@@ -83,7 +83,6 @@ from repro.neuromorphic.platform import ChipProfile
 # scoping for float64 parity with the NumPy pricing path.
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 #: Engine used when :func:`simulate` is called without an explicit
 #: ``engine=``.  ``"batched"`` is the layer-major, time-batched engine;
@@ -687,7 +686,7 @@ def layer_stage_times(net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
 #   float64 Python — the same constant folding as the NumPy path — and
 #   gathered per core through the layer-id vector.
 #
-# Arithmetic runs in float64 (``jax.experimental.enable_x64`` scoped to this
+# Arithmetic runs in float64 (``jax.enable_x64(True)`` scoped to this
 # path), with the same elementwise formulas and reduction semantics as the
 # NumPy path; XLA may reassociate/fuse (FMA), so results agree to float64
 # roundoff rather than bit-for-bit — the parity suite asserts
@@ -813,7 +812,7 @@ class _VmapPricer:
             ncost.append(p.neuron_cost(model))
             sparse_f.append(1.0 if lp.sparse else 0.0)
             e_act_c.append(p.e_act * (p.neuron_cost(model) / p.c_act))
-        with enable_x64():
+        with jax.enable_x64(True):
             self.csums = tuple(
                 jnp.asarray(np.concatenate([getattr(lp, f) for lp in
                                             cache.layers], axis=1))
@@ -905,7 +904,7 @@ class _VmapPricer:
     def price(self, batch: PopulationBatch) -> dict:
         """Run the jitted pricer; returns host NumPy arrays with a leading
         population axis."""
-        with enable_x64():
+        with jax.enable_x64(True):
             out = self._fn(jnp.asarray(batch.mask), jnp.asarray(batch.lid),
                            jnp.asarray(batch.seg_lo),
                            jnp.asarray(batch.seg_hi),
@@ -1035,7 +1034,7 @@ class DevicePopulationPricer:
         rows, cols = profile.grid
         self.cpr = max(1, profile.n_cores // (rows * cols))
         widths = np.asarray([lp.n_neurons + 1 for lp in cache.layers])
-        with enable_x64():
+        with jax.enable_x64(True):
             self.block_off = jnp.asarray(
                 np.concatenate([[0], np.cumsum(widths)])[:-1]
                 .astype(np.int32))
@@ -1094,7 +1093,7 @@ class DevicePopulationPricer:
         pricing dict on host (``device=False``, default) or device-resident
         (``device=True`` — no transfer, for callers that keep going on
         device)."""
-        with enable_x64():
+        with jax.enable_x64(True):
             out = self._fn(jnp.asarray(cores, jnp.int32),
                            jnp.asarray(perm, jnp.int32))
         return out if device else jax.device_get(out)
@@ -1174,7 +1173,6 @@ def price_population_sharded(net: SimNetwork, profile: ChipProfile,
     per-island generation step instead (``repro.core.device_search``).
     """
     from jax.sharding import PartitionSpec
-    from repro.distributed.compat import shard_map
     pricer = device_pricer(net, profile, cache)
     n_layers, n_slots = len(cache.layers), int(profile.n_cores)
     if (np.ndim(cores) != 2 or np.ndim(perm) != 2
@@ -1199,10 +1197,10 @@ def price_population_sharded(net: SimNetwork, profile: ChipProfile,
     mesh_key = (n_islands, tuple(d.id for d in mesh.devices.flat))
     if mesh_key not in fns:
         spec = PartitionSpec("island")
-        fns[mesh_key] = jax.jit(shard_map(
+        fns[mesh_key] = jax.jit(jax.shard_map(
             jax.vmap(pricer.price_row), mesh=mesh,
             in_specs=(spec, spec), out_specs=spec, check_vma=False))
-    with enable_x64():
+    with jax.enable_x64(True):
         out = jax.device_get(fns[mesh_key](jnp.asarray(cores_h),
                                            jnp.asarray(perm_h)))
     if pad:

@@ -33,7 +33,7 @@ from repro.train import checkpoint as ckpt_lib
 from repro.train import step as step_lib
 from repro.train.optim import Optimizer
 
-from repro.distributed.compat import shard_map
+from jax import shard_map
 
 
 @dataclasses.dataclass

@@ -13,6 +13,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro.distributed import collectives as C
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 @given(st.integers(0, 2**32 - 1), st.floats(0.1, 100.0))
 @settings(max_examples=25, deadline=None)
@@ -70,7 +72,7 @@ def test_compressed_dp_matches_exact():
     losses = {}
     for flag in ("0", "1"):
         r = subprocess.run([sys.executable, "-c", _DP_RUN, flag],
-                           capture_output=True, text=True, cwd="/root/repo",
+                           capture_output=True, text=True, cwd=REPO,
                            env=env, timeout=900)
         assert r.returncode == 0, r.stderr[-2000:]
         losses[flag] = float(r.stdout.split("LOSS", 1)[1])

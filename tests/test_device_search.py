@@ -26,7 +26,6 @@ from hypothesis import strategies as st
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.core.device_search import (STAGE_ID, _NumpyMirror,
                                       evolutionary_search_device,
@@ -60,8 +59,8 @@ _WORKLOAD: dict = {}
 
 def get_workload():
     """One shared (net, xs, prof, evaluator) so the device pricer/engine
-    compile once for the whole module (also usable outside fixtures — the
-    hypothesis shim cannot inject pytest fixtures)."""
+    compile once for the whole module (also usable outside fixtures:
+    hypothesis tests do not take function-scoped fixtures)."""
     if not _WORKLOAD:
         net, xs = fc_workload()
         prof = loihi2_like()
@@ -108,7 +107,7 @@ class TestFlowStructuresDevice:
             lid[:n] = np.repeat(np.arange(L), cores)
             router[:n] = phys // cpr
             alive[:n] = 1.0
-            with enable_x64():
+            with jax.enable_x64(True):
                 PL_d, ph_d, dup_d = flow_structures_rows(
                     jnp.asarray(lid), jnp.asarray(router), jnp.asarray(alive),
                     L, jnp.asarray(inc3), jnp.asarray(hops2))
@@ -121,7 +120,7 @@ class TestDecisionParity:
     """The same array program under numpy and jax.numpy: exact agreement."""
 
     def _draws(self, key, **kw):
-        with enable_x64():
+        with jax.enable_x64(True):
             return jax.device_get(generation_draws(key, **kw))
 
     @quick
@@ -144,7 +143,7 @@ class TestDecisionParity:
             c_np, p_np = mutate_rows_array(
                 np, *args, np.asarray(tables.feasible),
                 tables.n_cores_phys, 0.25)
-            with enable_x64():
+            with jax.enable_x64(True):
                 c_j, p_j = mutate_rows_array(
                     jnp, *[jnp.asarray(a) if not isinstance(a, dict) else
                            {k: jnp.asarray(v) for k, v in a.items()}
@@ -181,7 +180,7 @@ class TestDecisionParity:
         t[7], e[7] = t[1], e[1]
         ranks = pareto_ranks(t, e)
         idx_np = survival_order_array(np, cores, perm, t, e, ranks, 6)
-        with enable_x64():
+        with jax.enable_x64(True):
             ranks_j = pareto_ranks_array(jnp.asarray(t), jnp.asarray(e))
             assert np.array_equal(np.asarray(ranks_j), ranks)
             idx_j = survival_order_array(
@@ -196,7 +195,7 @@ class TestDecisionParity:
     def test_pareto_ranks_device_known_points(self):
         t = np.array([1.0, 2.0, 3.0, 2.0])
         e = np.array([3.0, 1.0, 2.0, 2.0])
-        with enable_x64():
+        with jax.enable_x64(True):
             r = np.asarray(pareto_ranks_array(jnp.asarray(t),
                                               jnp.asarray(e)))
         assert list(r) == [0, 0, 2, 1]
@@ -215,7 +214,7 @@ class TestDecisionParity:
         e = rng.integers(0, 4, k).astype(np.float64)
         full_h = pareto_ranks(t, e)
         cap_h = pareto_ranks(t, e, n_keep=cap)
-        with enable_x64():
+        with jax.enable_x64(True):
             full_d = np.asarray(pareto_ranks_array(jnp.asarray(t),
                                                    jnp.asarray(e)))
             cap_d = np.asarray(pareto_ranks_array(jnp.asarray(t),

@@ -11,6 +11,8 @@ import sys
 
 import pytest
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 CELLS = [
     ("granite-3-2b", "train_4k", "2,4"),            # dense TP
     ("kimi-k2-1t-a32b", "train_4k", "2,2,2"),       # MoE EP a2a, multipod
@@ -29,7 +31,7 @@ def test_dryrun_cell_smoke(arch, shape, mesh, tmp_path):
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
          "--shape", shape, "--smoke", "--mesh-shape", mesh, "--out", out],
-        capture_output=True, text=True, cwd="/root/repo", env=env,
+        capture_output=True, text=True, cwd=REPO, env=env,
         timeout=900)
     assert r.returncode == 0, (r.stdout[-1500:], r.stderr[-1500:])
     arts = [f for f in os.listdir(out) if f.endswith(".json")]
@@ -47,7 +49,7 @@ def test_dryrun_cell_smoke(arch, shape, mesh, tmp_path):
 def test_production_sweep_artifacts_complete():
     """The committed production sweep must cover every assigned cell on
     both meshes (skips per DESIGN.md applied)."""
-    d = "/root/repo/experiments/dryrun"
+    d = os.path.join(REPO, "experiments", "dryrun")
     if not os.path.isdir(d):
         pytest.skip("production sweep not present")
     from repro.configs import registry

@@ -169,7 +169,7 @@ class TestDegradation:
         faulty = SimEvaluator(
             net, xs, prof, cache=ev.cache, population_backend="device",
             fault_plan=FaultPlan(fail={"device": ALWAYS, "vmap": ALWAYS}),
-            retry=RetryPolicy(max_retries=1))
+            retry=RetryPolicy(max_retries=1), fallback=True)
         deg = evolutionary_search(net, prof, faulty, **kw)
         ref = evolutionary_search(
             net, prof, SimEvaluator(net, xs, prof, cache=ev.cache), **kw)
@@ -192,7 +192,8 @@ class TestDegradation:
         kw = dict(population_size=5, generations=2, seed=1)
         faulty = SimEvaluator(net, xs, prof, cache=ev.cache,
                               population_backend="vmap",
-                              fault_plan=FaultPlan(fail={"vmap": 1}))
+                              fault_plan=FaultPlan(fail={"vmap": 1}),
+                              fallback=True)
         res = evolutionary_search(net, prof, faulty, **kw)
         clean = evolutionary_search(
             net, prof, SimEvaluator(net, xs, prof, cache=ev.cache,
@@ -215,7 +216,8 @@ class TestDegradation:
             net, prof, SimEvaluator(net, xs, prof, cache=ev.cache),
             reference=True, **kw)
         deg = evolutionary_search_device(
-            net, prof, SimEvaluator(net, xs, prof, cache=ev.cache),
+            net, prof, SimEvaluator(net, xs, prof, cache=ev.cache,
+                                    fallback=True),
             fault_plan=FaultPlan(fail={"device": ALWAYS}),
             retry=RetryPolicy(max_retries=0), **kw)
         assert [(x.frm, x.to) for x in deg.demotions] == \
@@ -224,6 +226,25 @@ class TestDegradation:
         np.testing.assert_allclose(
             [g.best_time for g in deg.history],
             [g.best_time for g in full.history], rtol=1e-9)
+
+    @quick
+    @pytest.mark.parametrize("site", ["pricing", "engine"])
+    def test_default_is_fail_fast(self, site):
+        """Without ``fallback=True`` an injected device failure propagates:
+        no retry, no demotion, no silent switch to a host backend."""
+        from repro.core.device_search import evolutionary_search_device
+        net, xs, prof, ev = get_workload("fc")
+        plan = FaultPlan(fail={"device": 1})
+        kw = dict(population_size=4, generations=2, seed=0)
+        with pytest.raises(InjectedFault):
+            if site == "pricing":
+                evolutionary_search(net, prof, SimEvaluator(
+                    net, xs, prof, cache=ev.cache,
+                    population_backend="device", fault_plan=plan), **kw)
+            else:
+                evolutionary_search_device(
+                    net, prof, SimEvaluator(net, xs, prof, cache=ev.cache),
+                    fault_plan=plan, **kw)
 
     @quick
     def test_exhausted_chain_raises_last_error(self):
@@ -314,12 +335,12 @@ class TestQuarantine:
         path too (it is traced into the jitted init/step programs)."""
         import jax
         import jax.numpy as jnp
-        from repro.core.device_search import (_sorted_state, enable_x64,
+        from repro.core.device_search import (_sorted_state,
                                               pareto_ranks_array)
         K = 6
         t = np.array([30.0, np.nan, 10.0, np.inf, 20.0, 40.0])
         e = np.array([1.0, 2.0, 3.0, 4.0, 5.0, 6.0])
-        with enable_x64():
+        with jax.enable_x64(True):
             out = dict(times=jnp.asarray(t), energies=jnp.asarray(e),
                        stage=jnp.zeros(K, jnp.int32),
                        hot_mem=jnp.zeros(K, jnp.int32),

@@ -370,7 +370,8 @@ class TestDegradation:
             net, prof, SimEvaluator(net, xs, prof, cache=ev.cache),
             reference=True, **kw)
         res = evolutionary_search_sharded(
-            net, prof, SimEvaluator(net, xs, prof, cache=ev.cache),
+            net, prof, SimEvaluator(net, xs, prof, cache=ev.cache,
+                                    fallback=True),
             fault_plan=FaultPlan(fail={"sharded": ALWAYS}),
             retry=RetryPolicy(max_retries=1, backoff_s=0.0), **kw)
         assert [d.frm for d in res.demotions] == ["sharded"]

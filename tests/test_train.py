@@ -18,6 +18,8 @@ from repro.train import data as data_lib
 from repro.train import optim, schedules
 from repro.train.loop import StragglerMonitor, Trainer, TrainerConfig
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 def _mk_trainer(tmp, steps=12, resume=False, ckpt_every=4, seed=0):
     cfg = registry.get("granite-3-2b").smoke()
@@ -108,12 +110,12 @@ def test_elastic_remesh(tmp_path):
     ckpt = str(tmp_path / "ck")
     env = {**os.environ, "PYTHONPATH": "src"}
     r1 = subprocess.run([sys.executable, "-c", _ELASTIC, ckpt, "a"],
-                        capture_output=True, text=True, cwd="/root/repo",
+                        capture_output=True, text=True, cwd=REPO,
                         env=env, timeout=600)
     assert r1.returncode == 0, r1.stderr[-2000:]
     l1 = json.loads(r1.stdout.split("RESULT", 1)[1])
     r2 = subprocess.run([sys.executable, "-c", _ELASTIC, ckpt, "b"],
-                        capture_output=True, text=True, cwd="/root/repo",
+                        capture_output=True, text=True, cwd=REPO,
                         env=env, timeout=600)
     assert r2.returncode == 0, r2.stderr[-2000:]
     l2 = json.loads(r2.stdout.split("RESULT", 1)[1])
