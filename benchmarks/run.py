@@ -1,5 +1,5 @@
-"""Benchmark driver: one experiment per paper table/figure + the TPU
-roofline table + the engine/search microbenchmarks.
+"""Runs one experiment per paper table/figure + the
+engine/search microbenchmarks.
 
 ``python -m benchmarks.run [--quick] [--smoke] [--only NAME] [--engine E]
 [--compute C]``
@@ -79,9 +79,8 @@ def main(argv=None):
     from benchmarks import (act_schedules, compute_floor, iso_accuracy,
                             max_synops, model_zoo, search_mapping,
                             sim_speed, stage1_sparsity,
-                            stage2_partitioning, tpu_roofline,
-                            traffic_mapping, weight_format,
-                            weight_sparsity)
+                            stage2_partitioning, traffic_mapping,
+                            weight_format, weight_sparsity)
 
     mods = [
         ("sim_speed", sim_speed),
@@ -96,7 +95,6 @@ def main(argv=None):
         ("fig12_stage2", stage2_partitioning),
         ("iso_accuracy", iso_accuracy),
         ("search_mapping", search_mapping),
-        ("tpu_roofline", tpu_roofline),
     ]
     results = {}
     stage1_res = None

@@ -79,6 +79,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
+from repro import tracing
 from repro.core.resilience import (Demotion, FaultPlan, RetryPolicy,
                                    SearchCheckpointer, finite_mean,
                                    quarantine_rows, validate_resume_meta)
@@ -888,151 +889,162 @@ def evolutionary_search_device(
     raise, ``nan_rows`` corrupts mirror pricing rows, ``kill_after_gen``
     simulates a crash after that generation's checkpoint.
     """
-    for attr in ("net", "xs", "profile"):
-        if not hasattr(evaluator, attr):
-            raise TypeError(
-                "engine='device' needs a SimEvaluator-like evaluator "
-                f"(missing .{attr}); plain callables can only drive the "
-                "numpy engine")
-    _validate_search_args(net, profile, population_size=population_size,
-                          generations=generations,
-                          seed_candidates=seed_candidates)
-    xs = evaluator.xs
-    cache = getattr(evaluator, "cache", None) \
-        or precompute_pricing(net, xs, profile)
+    with tracing.span("search"):
+        for attr in ("net", "xs", "profile"):
+            if not hasattr(evaluator, attr):
+                raise TypeError(
+                    "engine='device' needs a SimEvaluator-like evaluator "
+                    f"(missing .{attr}); plain callables can only drive "
+                    "the numpy engine")
+        _validate_search_args(net, profile,
+                              population_size=population_size,
+                              generations=generations,
+                              seed_candidates=seed_candidates)
+        xs = evaluator.xs
+        cache = getattr(evaluator, "cache", None) \
+            or precompute_pricing(net, xs, profile)
 
-    ckpt = (SearchCheckpointer(checkpoint_dir, every=checkpoint_every,
-                               keep=checkpoint_keep)
-            if checkpoint_dir else None)
-    restored = ckpt.restore() if (ckpt is not None and resume) else None
+        ckpt = (SearchCheckpointer(checkpoint_dir, every=checkpoint_every,
+                                   keep=checkpoint_keep)
+                if checkpoint_dir else None)
+        restored = ckpt.restore() if (ckpt is not None and resume) else None
 
-    tables = move_tables(net, profile)
-    n_layers = len(cache.layers)
-    n_slots = int(profile.n_cores)
+        tables = move_tables(net, profile)
+        n_layers = len(cache.layers)
+        n_slots = int(profile.n_cores)
 
-    def _mirror():
-        return _NumpyMirror(net, xs, profile, cache, tables,
+        def _mirror():
+            return _NumpyMirror(net, xs, profile, cache, tables,
+                                explore_prob=explore_prob,
+                                tournament_k=tournament_k,
+                                fault_plan=fault_plan)
+
+        if reference:
+            engine = _mirror()
+        else:
+            engine = _ResilientEngine(
+                _engine_for(net, profile, cache, tables,
                             explore_prob=explore_prob,
-                            tournament_k=tournament_k,
-                            fault_plan=fault_plan)
+                            tournament_k=tournament_k),
+                _mirror, retry=retry, fault_plan=fault_plan,
+                fallback=getattr(evaluator, "fallback", False))
+        base_key = jax.random.PRNGKey(seed)
+        archive = EpsParetoArchive(pareto_eps)
 
-    if reference:
-        engine = _mirror()
-    else:
-        engine = _ResilientEngine(
-            _engine_for(net, profile, cache, tables,
-                        explore_prob=explore_prob,
-                        tournament_k=tournament_k),
-            _mirror, retry=retry, fault_plan=fault_plan,
-            fallback=getattr(evaluator, "fallback", False))
-    base_key = jax.random.PRNGKey(seed)
-    archive = EpsParetoArchive(pareto_eps)
+        if restored is not None:
+            arrays, gen0, meta = restored
+            validate_resume_meta(meta, engine="device",
+                                 checkpoint_dir=checkpoint_dir)
+            state = {k: np.asarray(arrays[k]) for k in _STATE_KEYS}
+            archive.load_state(arrays)
+            history = [GenStats(**h) for h in meta["history"]]
+            evals_used = int(meta["evals_used"])
+            seed_best_time = float(meta["seed_best_time"])
+            n_pop = int(state["cores"].shape[0])
+            start_gen = gen0 + 1
+        else:
+            with tracing.span("search.seed"):
+                rng = np.random.default_rng(seed)
+                cands = list(seed_candidates if seed_candidates is not None
+                             else seeded_population(net, profile,
+                                                    size=population_size,
+                                                    rng=rng, greedy=greedy))
+                if not cands:
+                    raise ValueError("empty initial population")
+                if max_evaluations is not None:
+                    cands = cands[:max(1, max_evaluations)]
+                pop = Population.from_candidates(cands)
 
-    if restored is not None:
-        arrays, gen0, meta = restored
-        validate_resume_meta(meta, engine="device",
-                             checkpoint_dir=checkpoint_dir)
-        state = {k: np.asarray(arrays[k]) for k in _STATE_KEYS}
-        archive.load_state(arrays)
-        history = [GenStats(**h) for h in meta["history"]]
-        evals_used = int(meta["evals_used"])
-        seed_best_time = float(meta["seed_best_time"])
-        n_pop = int(state["cores"].shape[0])
-        start_gen = gen0 + 1
-    else:
-        rng = np.random.default_rng(seed)
-        cands = list(seed_candidates if seed_candidates is not None else
-                     seeded_population(net, profile, size=population_size,
-                                       rng=rng, greedy=greedy))
-        if not cands:
-            raise ValueError("empty initial population")
-        if max_evaluations is not None:
-            cands = cands[:max(1, max_evaluations)]
-        pop = Population.from_candidates(cands)
+                state, init_out = engine.init(pop.cores, pop.perm)
+                evals_used = len(pop)
+                _charge(evaluator, len(pop))
+                init_host = jax.device_get(init_out)
+                # screen the raw seed objectives before they reach host
+                # stats or the archive (the archive rejects non-finite points
+                # itself; the sentinel keeps the min() below NaN-safe)
+                it, ie, _ = quarantine_rows(
+                    np, np.asarray(init_host["times"], np.float64),
+                    np.asarray(init_host["energies"], np.float64))
+                seed_best_time = float(np.min(it))
+                archive.update_batch(it, ie, pop.cores, pop.perm)
 
-        state, init_out = engine.init(pop.cores, pop.perm)
-        evals_used = len(pop)
-        _charge(evaluator, len(pop))
-        init_host = jax.device_get(init_out)
-        # screen the raw seed objectives before they reach host stats or
-        # the archive (the archive rejects non-finite points itself; the
-        # sentinel keeps the min() below NaN-safe)
-        it, ie, _ = quarantine_rows(
-            np, np.asarray(init_host["times"], np.float64),
-            np.asarray(init_host["energies"], np.float64))
-        seed_best_time = float(np.min(it))
-        archive.update_batch(it, ie, pop.cores, pop.perm)
+                first = jax.device_get({k: state[k]
+                                        for k in ("times", "energies")})
+                history = [GenStats(
+                    generation=0, best_time=float(first["times"][0]),
+                    best_energy=float(first["energies"][0]),
+                    mean_time=float(finite_mean(np, first["times"])),
+                    n_evals=evals_used, front_size=len(archive))]
+                n_pop = len(pop)
+                start_gen = 1
 
-        first = jax.device_get({k: state[k] for k in ("times", "energies")})
-        history = [GenStats(generation=0,
-                            best_time=float(first["times"][0]),
-                            best_energy=float(first["energies"][0]),
-                            mean_time=float(finite_mean(np, first["times"])),
+        def _snapshot(gen: int) -> None:
+            host_state = jax.device_get(state)
+            arrays = {k: np.asarray(host_state[k]) for k in _STATE_KEYS}
+            arrays.update(archive.state_arrays(n_layers, n_slots))
+            meta = dict(engine="device", evals_used=int(evals_used),
+                        seed_best_time=float(seed_best_time),
+                        history=[dataclasses.asdict(g) for g in history])
+            ckpt.save(gen, arrays, meta)
+
+        if restored is None:
+            if ckpt is not None:
+                _snapshot(0)
+            if fault_plan is not None:
+                fault_plan.after_generation(0)
+
+        for gen in range(start_gen, generations + 1):
+            n_off = n_pop
+            if max_evaluations is not None:
+                n_off = min(n_off, max_evaluations - evals_used)
+            if n_off <= 0:
+                break
+            key = jax.random.fold_in(base_key, gen)
+            with tracing.span("search.step"):
+                state, off, stats = engine.step(state, key, n_off)
+            evals_used += n_off
+            _charge(evaluator, n_off)
+            # the only per-generation host sync: tiny stats + the offspring
+            # batch, absorbed by the epsilon-Pareto archive in ONE vectorized
+            # update (no per-offspring host Python anywhere in this loop)
+            with tracing.span("search.sync"):
+                host = jax.device_get(dict(off=off, stats=stats))
+            off_h, stats_h = host["off"], host["stats"]
+            with tracing.span("search.archive"):
+                archive.update_batch(off_h["times"], off_h["energies"],
+                                     off_h["cores"], off_h["perm"])
+                history.append(GenStats(
+                    generation=gen,
+                    best_time=float(stats_h["best_time"]),
+                    best_energy=float(stats_h["best_energy"]),
+                    mean_time=float(stats_h["mean_time"]),
+                    n_evals=evals_used,
+                    front_size=len(archive),
+                    n_quarantined=int(stats_h.get("n_quarantined", 0))))
+            if ckpt is not None and ckpt.due(gen, generations):
+                _snapshot(gen)
+            if fault_plan is not None:
+                fault_plan.after_generation(gen)
+
+        with tracing.span("search.finish"):
+            final = jax.device_get({k: state[k] for k in ("cores", "perm")})
+            best = Candidate(tuple(int(x) for x in final["cores"][0]),
+                             tuple(int(x) for x in final["perm"][0]))
+            part, mapping = decode(best)
+            # stats-only materialization through the bit-exact path
+            # (uncharged)
+            best_report = price_candidate(net, profile, cache, part, mapping)
+            front, _ = archive.front()
+            front_reports = simulate_population(
+                net, xs, profile, [decode(c) for c in front],
+                cache=cache) if front else []
+        return SearchResult(candidate=best, partition=part, mapping=mapping,
+                            report=best_report, history=history,
                             n_evals=evals_used,
-                            front_size=len(archive))]
-        n_pop = len(pop)
-        start_gen = 1
-
-    def _snapshot(gen: int) -> None:
-        host_state = jax.device_get(state)
-        arrays = {k: np.asarray(host_state[k]) for k in _STATE_KEYS}
-        arrays.update(archive.state_arrays(n_layers, n_slots))
-        meta = dict(engine="device", evals_used=int(evals_used),
-                    seed_best_time=float(seed_best_time),
-                    history=[dataclasses.asdict(g) for g in history])
-        ckpt.save(gen, arrays, meta)
-
-    if restored is None:
-        if ckpt is not None:
-            _snapshot(0)
-        if fault_plan is not None:
-            fault_plan.after_generation(0)
-
-    for gen in range(start_gen, generations + 1):
-        n_off = n_pop
-        if max_evaluations is not None:
-            n_off = min(n_off, max_evaluations - evals_used)
-        if n_off <= 0:
-            break
-        key = jax.random.fold_in(base_key, gen)
-        state, off, stats = engine.step(state, key, n_off)
-        evals_used += n_off
-        _charge(evaluator, n_off)
-        # the only per-generation host sync: tiny stats + the offspring
-        # batch, absorbed by the epsilon-Pareto archive in ONE vectorized
-        # update (no per-offspring host Python anywhere in this loop)
-        host = jax.device_get(dict(off=off, stats=stats))
-        off_h, stats_h = host["off"], host["stats"]
-        archive.update_batch(off_h["times"], off_h["energies"],
-                             off_h["cores"], off_h["perm"])
-        history.append(GenStats(
-            generation=gen,
-            best_time=float(stats_h["best_time"]),
-            best_energy=float(stats_h["best_energy"]),
-            mean_time=float(stats_h["mean_time"]),
-            n_evals=evals_used,
-            front_size=len(archive),
-            n_quarantined=int(stats_h.get("n_quarantined", 0))))
-        if ckpt is not None and ckpt.due(gen, generations):
-            _snapshot(gen)
-        if fault_plan is not None:
-            fault_plan.after_generation(gen)
-
-    final = jax.device_get({k: state[k] for k in ("cores", "perm")})
-    best = Candidate(tuple(int(x) for x in final["cores"][0]),
-                     tuple(int(x) for x in final["perm"][0]))
-    part, mapping = decode(best)
-    # stats-only materialization through the bit-exact path (uncharged)
-    best_report = price_candidate(net, profile, cache, part, mapping)
-    front, _ = archive.front()
-    front_reports = simulate_population(net, xs, profile,
-                                        [decode(c) for c in front],
-                                        cache=cache) if front else []
-    return SearchResult(candidate=best, partition=part, mapping=mapping,
-                        report=best_report, history=history,
-                        n_evals=evals_used, seed_best_time=seed_best_time,
-                        front=front, front_reports=front_reports,
-                        demotions=list(getattr(engine, "demotions", ())))
+                            seed_best_time=seed_best_time,
+                            front=front, front_reports=front_reports,
+                            demotions=list(getattr(engine, "demotions",
+                                                   ())))
 
 
 def _charge(evaluator, n: int) -> None:

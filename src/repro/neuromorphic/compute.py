@@ -63,6 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.kernels.event_matmul.ops import (event_matmul_pair,
                                             weight_block_occupancy)
 from repro.kernels.sigma_delta.ops import window_reconstruct
@@ -72,6 +73,24 @@ from repro.kernels.sigma_delta.ops import window_reconstruct
 #: module attribute globally, the supported way to flip every simulation in
 #: a process (same contract as ``timestep.DEFAULT_ENGINE``).
 DEFAULT_COMPUTE = "dense"
+
+
+def _to_device(*arrays, dtype=None) -> tuple:
+    """``jnp.asarray`` of each array under the span ``kernel.put``,
+    counting the bytes of every host array sent as ``h2d_bytes``."""
+    with tracing.span("kernel.put"):
+        out = tuple(jnp.asarray(a, dtype) for a in arrays)
+        for a, d in zip(arrays, out):
+            if isinstance(a, np.ndarray):
+                tracing.count("h2d_bytes", d.nbytes)
+    return out
+
+
+def _to_host(*arrays) -> tuple:
+    """``np.asarray`` of each device result under the span
+    ``kernel.fetch``: where the host waits for the device."""
+    with tracing.span("kernel.fetch"):
+        return tuple(np.asarray(a) for a in arrays)
 
 
 class LayerCompute:
@@ -163,8 +182,7 @@ class DenseCompute(LayerCompute):
         cin = layer.weights.shape[2]
         to_nhwc = lambda a: np.transpose(a.reshape(T, cin, h, w),
                                          (0, 2, 3, 1))
-        x4 = jnp.asarray(to_nhwc(x_eff))
-        m4 = jnp.asarray(to_nhwc(act_mask))
+        x4, m4 = _to_device(to_nhwc(x_eff), to_nhwc(act_mask))
         wj, wmask, wones = layer._conv_kernels
 
         conv = lambda lhs, rhs, precision=None: jax.lax.conv_general_dilated(
@@ -173,9 +191,9 @@ class DenseCompute(LayerCompute):
             precision=precision)
         # values at f32 (HIGHEST): a TPU's default single bf16 pass moves
         # them ~3e-3 relative; the 0/1 counter convs are exact in one pass
-        pre = np.asarray(conv(x4, wj, jax.lax.Precision.HIGHEST))
-        macs = np.asarray(conv(m4, wmask))
-        fetches = np.asarray(conv(m4, wones))
+        pre = _to_host(conv(x4, wj, jax.lax.Precision.HIGHEST))[0]
+        macs = _to_host(conv(m4, wmask))[0]
+        fetches = _to_host(conv(m4, wones))[0]
         to_flat = lambda a: np.transpose(a, (0, 3, 1, 2)).reshape(T, -1)
         return to_flat(pre), to_flat(macs), to_flat(fetches)
 
@@ -392,11 +410,10 @@ class EventCompute(LayerCompute):
             return (self._gather_matmul(np.asarray(x, np.float32), w, wb=wb),
                     self._gather_matmul(np.asarray(m, np.float32), wm, wb=wb))
         y, macs = event_matmul_pair(
-            jnp.asarray(x, jnp.float32), jnp.asarray(m, jnp.float32),
-            jnp.asarray(w), jnp.asarray(wm),
+            *_to_device(x, m, w, wm, dtype=jnp.float32),
             wb.occ_j if wb is not None else None, threshold=self.threshold,
             bm=self.bm, bk=self.bk, bn=self.bn)
-        return np.asarray(y), np.asarray(macs)
+        return _to_host(y, macs)
 
     # ------------------------------------------------------------ layer kinds
     def fc_forward(self, layer, x_eff, act_mask, msgs_in):
@@ -506,11 +523,9 @@ class EventCompute(LayerCompute):
             return super().delta_forward(layer, x_in, in_acc, act_mask,
                                          msgs_in)
         if self._kernel_mode() == "pallas":
-            bases, xwin, new_acc = window_reconstruct(
-                jnp.asarray(x_in, jnp.float32),
-                jnp.asarray(in_acc, jnp.float32), window=window)
-            bases, xwin = np.asarray(bases), np.asarray(xwin)
-            new_acc = np.asarray(new_acc)
+            bases, xwin, new_acc = _to_host(*window_reconstruct(
+                *_to_device(x_in, in_acc, dtype=jnp.float32),
+                window=window))
         else:
             bases, xwin, new_acc = _window_reconstruct_np(x_in, in_acc,
                                                           window)

@@ -45,6 +45,7 @@ from typing import Any
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.neuromorphic import compute as _compute
 
 
@@ -254,22 +255,25 @@ class SimLayer:
         act_mask = (x_in != 0).astype(np.float32)   # events on the wire
         msgs_in = act_mask.sum(axis=1)              # (T,)
 
-        if in_acc is not None:
-            # delta reconstruction (acc_t = acc_0 + sum_{k<=t} x_k) is the
-            # backend's to own: the base implementation is the bit-exact
-            # dense time cumsum; event backends reconstruct in temporal
-            # tiles so quiet windows compact away before the matmul.
-            pre, macs, fetches_dense, new_acc = cc.delta_forward(
-                self, x_in, in_acc, act_mask, msgs_in)
-        else:
-            new_acc = None
-            pre, macs, fetches_dense = cc.forward(self, x_in, act_mask,
-                                                  msgs_in)
+        with tracing.span("sim.synaptic"):
+            if in_acc is not None:
+                # delta reconstruction (acc_t = acc_0 + sum_{k<=t} x_k) is
+                # the backend's to own: the base implementation is the
+                # bit-exact dense time cumsum; event backends reconstruct in
+                # temporal tiles so quiet windows compact away before the
+                # matmul.
+                pre, macs, fetches_dense, new_acc = cc.delta_forward(
+                    self, x_in, in_acc, act_mask, msgs_in)
+            else:
+                new_acc = None
+                pre, macs, fetches_dense = cc.forward(self, x_in, act_mask,
+                                                      msgs_in)
 
         if self.bias is not None:
             pre = pre + self.bias
 
-        y_msgs, state = self._neuron_batch(pre, state)
+        with tracing.span("sim.neuron"):
+            y_msgs, state = self._neuron_batch(pre, state)
         if self.msg_gate is not None:
             y_msgs = y_msgs * self.msg_gate
         msgs_out = (y_msgs != 0).astype(np.float32)
@@ -415,16 +419,17 @@ class SimNetwork:
         (see the module docstring) but visits each layer once with the full
         time batch instead of T times.  ``compute`` selects the synaptic
         backend for every layer (resolved once per run)."""
-        cc = _compute.get_compute(compute)
-        states, accs = self.init_states(), self.init_accs()
-        cur = np.asarray(xs, np.float32)
-        all_counters: list[BatchCounters] = []
-        for i, layer in enumerate(self.layers):
-            cur, states[i], cnt, accs[i] = layer.step_batch(
-                cur, states[i], accs[i], compute=cc)
-            all_counters.append(cnt)
-        T = xs.shape[0]
-        return np.asarray(cur).reshape(T, -1), all_counters
+        with tracing.span("sim.run_batch"):
+            cc = _compute.get_compute(compute)
+            states, accs = self.init_states(), self.init_accs()
+            cur = np.asarray(xs, np.float32)
+            all_counters: list[BatchCounters] = []
+            for i, layer in enumerate(self.layers):
+                cur, states[i], cnt, accs[i] = layer.step_batch(
+                    cur, states[i], accs[i], compute=cc)
+                all_counters.append(cnt)
+            T = xs.shape[0]
+            return np.asarray(cur).reshape(T, -1), all_counters
 
 
 # ====================================================================== builders

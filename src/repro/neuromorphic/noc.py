@@ -28,6 +28,7 @@ import numpy as np
 # (:func:`flow_structures_rows`); the host paths below stay pure NumPy.
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.neuromorphic.partition import Partition
 from repro.neuromorphic.platform import ChipProfile
 
@@ -414,14 +415,15 @@ def route_batch(part: Partition, mapping: Mapping, msgs_out: np.ndarray,
     incidence, and router loads / hop counts are one matmul each against the
     cached path incidence.  Counts are integers in float64, so the results
     are bit-identical to T :func:`route_step` calls."""
-    P, dup = _flow_matrix(part.cores, mapping.phys, profile.grid,
-                          profile.n_cores)
-    m = np.asarray(msgs_out, np.float64)
-    flow_flat = m @ P                                   # (T, R*R)
-    loads = flow_flat @ _path_incidence(profile.grid)   # (T, R)
-    hops = flow_flat @ _pair_hops(profile.grid)         # (T,)
-    return NocTrafficBatch(router_loads=loads, total_hops=hops,
-                           inject_per_core=m * dup)
+    with tracing.span("price.route"):
+        P, dup = _flow_matrix(part.cores, mapping.phys, profile.grid,
+                              profile.n_cores)
+        m = np.asarray(msgs_out, np.float64)
+        flow_flat = m @ P                                   # (T, R*R)
+        loads = flow_flat @ _path_incidence(profile.grid)   # (T, R)
+        hops = flow_flat @ _pair_hops(profile.grid)         # (T,)
+        return NocTrafficBatch(router_loads=loads, total_hops=hops,
+                               inject_per_core=m * dup)
 
 
 def route_step(part: Partition, mapping: Mapping,

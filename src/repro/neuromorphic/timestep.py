@@ -68,6 +68,7 @@ import dataclasses
 
 import numpy as np
 
+from repro import tracing
 from repro.core.metrics import LoadStats, WorkloadMetrics
 from repro.neuromorphic.network import BatchCounters, CounterMaps, SimNetwork
 from repro.neuromorphic.noc import (Mapping, NocTraffic, flow_structures_rows,
@@ -239,21 +240,23 @@ def simulate(net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
         every engine/backend parity guarantee carries over.  Mutually
         exclusive with ``precomputed`` (a functional run is net-bound).
     """
-    engine = engine or DEFAULT_ENGINE
-    if sparsity_profile is not None:
-        if precomputed is not None:
-            raise ValueError("sparsity_profile cannot be combined with "
-                             "precomputed: the cached run is bound to the "
-                             "un-profiled network")
-        net = sparsity_profile.apply(net)
-    part = part or minimal_partition(net, profile)
-    mapping = mapping or ordered_mapping(part, profile)
-    if engine == "batched":
-        return _simulate_batched(net, xs, profile, part, mapping, precomputed,
-                                 compute)
-    if engine == "reference":
-        return _simulate_reference(net, xs, profile, part, mapping, compute)
-    raise ValueError(f"unknown engine {engine!r}")
+    with tracing.span("sim.simulate"):
+        engine = engine or DEFAULT_ENGINE
+        if sparsity_profile is not None:
+            if precomputed is not None:
+                raise ValueError("sparsity_profile cannot be combined with "
+                                 "precomputed: the cached run is bound to "
+                                 "the un-profiled network")
+            net = sparsity_profile.apply(net)
+        part = part or minimal_partition(net, profile)
+        mapping = mapping or ordered_mapping(part, profile)
+        if engine == "batched":
+            return _simulate_batched(net, xs, profile, part, mapping,
+                                     precomputed, compute)
+        if engine == "reference":
+            return _simulate_reference(net, xs, profile, part, mapping,
+                                       compute)
+        raise ValueError(f"unknown engine {engine!r}")
 
 
 def _finish_report(net, part, T, times, energies, outputs, mean_synops,
@@ -355,17 +358,18 @@ def precompute_pricing(net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
         net = sparsity_profile.apply(net)
     outputs, all_counters = precomputed or net.run_batch(xs, compute=compute)
     layers = []
-    for l, counters in enumerate(all_counters):
-        acts_map = (counters.acts_evented if not profile.synchronous
-                    else np.ones_like(counters.macs))
-        layers.append(LayerPricing(
-            msgs_in=np.asarray(counters.msgs_in, np.float64),
-            csum_macs=_neuron_csum(counters.macs),
-            csum_fetches=_neuron_csum(counters.fetches_dense),
-            csum_acts=_neuron_csum(acts_map),
-            csum_msgs=_neuron_csum(counters.msgs_out),
-            n_neurons=net.layers[l].n_neurons,
-            sparse=_layer_format(net.layers[l], profile)))
+    with tracing.span("price.cumsum"):
+        for l, counters in enumerate(all_counters):
+            acts_map = (counters.acts_evented if not profile.synchronous
+                        else np.ones_like(counters.macs))
+            layers.append(LayerPricing(
+                msgs_in=np.asarray(counters.msgs_in, np.float64),
+                csum_macs=_neuron_csum(counters.macs),
+                csum_fetches=_neuron_csum(counters.fetches_dense),
+                csum_acts=_neuron_csum(acts_map),
+                csum_msgs=_neuron_csum(counters.msgs_out),
+                n_neurons=net.layers[l].n_neurons,
+                sparse=_layer_format(net.layers[l], profile)))
     return PricingCache(outputs=outputs, T=int(xs.shape[0]), layers=layers)
 
 
@@ -523,89 +527,95 @@ def price_candidate(net: SimNetwork, profile: ChipProfile,
                     *, layer_segments: list[tuple] | None = None) -> SimReport:
     """Price one (partition, mapping) candidate from a pricing cache; every
     per-step quantity is a (T, ...) array."""
-    outputs = cache.outputs
-    T = cache.T
-    n_layers = len(cache.layers)
-    n_logical = part.total_cores
+    with tracing.span("price.candidate"):
+        outputs = cache.outputs
+        T = cache.T
+        n_layers = len(cache.layers)
+        n_logical = part.total_cores
 
-    layer_cc = [_cached_layer_counters(
-                    cache.layers[l], part, l, T,
-                    layer_segments[l] if layer_segments else None)
-                for l in range(n_layers)]
+        with tracing.span("price.segments"):
+            layer_cc = [_cached_layer_counters(
+                            cache.layers[l], part, l, T,
+                            layer_segments[l] if layer_segments else None)
+                        for l in range(n_layers)]
 
-    mem_all, act_all = [], []
-    e_events = np.zeros(T, np.float64)
-    total_msgs = 0.0
-    total_neuron_steps = 0.0
-    for l, cc in enumerate(layer_cc):
-        mem, act = core_times(cc, net.layers[l].neuron_model, profile)
-        mem_all.append(mem)
-        act_all.append(act)
-        # event energies: fetch every (format-effective) synop; MAC energy
-        # only on nonzero weights (dense formats skip the multiply ->
-        # the small Fig-2 energy benefit of CNN weight sparsity)
-        e_events += (profile.e_fetch * cc.synops.sum(axis=1)
-                     + profile.e_mac * cc.macs.sum(axis=1)
-                     + (profile.e_decode * cc.synops.sum(axis=1)
-                        if cc.sparse_format else 0.0)
-                     + profile.e_act * cc.acts.sum(axis=1)
-                     * (profile.neuron_cost(net.layers[l].neuron_model)
-                        / profile.c_act))
-        total_msgs += cc.msgs_out.sum()
-        total_neuron_steps += T * cc.neurons.sum()
+        mem_all, act_all = [], []
+        e_events = np.zeros(T, np.float64)
+        total_msgs = 0.0
+        total_neuron_steps = 0.0
+        with tracing.span("price.cores"):
+            for l, cc in enumerate(layer_cc):
+                model = net.layers[l].neuron_model
+                mem, act = core_times(cc, model, profile)
+                mem_all.append(mem)
+                act_all.append(act)
+                # event energies: fetch every (format-effective) synop; MAC
+                # energy only on nonzero weights (dense formats skip the
+                # multiply -> the small Fig-2 energy benefit of CNN weight
+                # sparsity)
+                e_events += (profile.e_fetch * cc.synops.sum(axis=1)
+                             + profile.e_mac * cc.macs.sum(axis=1)
+                             + (profile.e_decode * cc.synops.sum(axis=1)
+                                if cc.sparse_format else 0.0)
+                             + profile.e_act * cc.acts.sum(axis=1)
+                             * (profile.neuron_cost(model) / profile.c_act))
+                total_msgs += cc.msgs_out.sum()
+                total_neuron_steps += T * cc.neurons.sum()
 
-    synops_all = np.concatenate([cc.synops for cc in layer_cc], axis=1)
-    acts_all = np.concatenate([cc.acts for cc in layer_cc], axis=1)
-    msgs_all = np.concatenate([cc.msgs_out for cc in layer_cc], axis=1)
+        synops_all = np.concatenate([cc.synops for cc in layer_cc], axis=1)
+        acts_all = np.concatenate([cc.acts for cc in layer_cc], axis=1)
+        msgs_all = np.concatenate([cc.msgs_out for cc in layer_cc], axis=1)
 
-    traffic = route_batch(part, mapping, msgs_all, profile)
-    mem_cat = np.concatenate(mem_all, axis=1)       # (T, n_logical)
-    act_cat = np.concatenate(act_all, axis=1)
-    core_time = np.maximum(mem_cat, act_cat) + profile.t_core_fixed
-    # Congestion: the busiest router serializes every packet touching it;
-    # cores also serialize their own (duplicated) injections.
-    max_link_steps = traffic.max_router_load        # (T,)
-    traffic_time = (profile.c_route * max_link_steps
-                    + profile.c_inject
-                    * traffic.inject_per_core.max(axis=1, initial=0.0))
+        traffic = route_batch(part, mapping, msgs_all, profile)
+        mem_cat = np.concatenate(mem_all, axis=1)       # (T, n_logical)
+        act_cat = np.concatenate(act_all, axis=1)
+        core_time = np.maximum(mem_cat, act_cat) + profile.t_core_fixed
+        # Congestion: the busiest router serializes every packet touching
+        # it; cores also serialize their own (duplicated) injections.
+        max_link_steps = traffic.max_router_load        # (T,)
+        traffic_time = (profile.c_route * max_link_steps
+                        + profile.c_inject
+                        * traffic.inject_per_core.max(axis=1, initial=0.0))
 
-    stage_votes = {"memory": 0, "compute": 0, "traffic": 0, "barrier": 0}
-    if profile.synchronous:
-        t_compute = core_time.max(axis=1, initial=0.0)
-        times = np.maximum(t_compute, traffic_time) + profile.t_barrier
-        traffic_bound = traffic_time > t_compute
-        mem_bound = (mem_cat.max(axis=1, initial=0.0)
-                     >= act_cat.max(axis=1, initial=0.0))
-        stage_votes["traffic"] = int(traffic_bound.sum())
-        stage_votes["memory"] = int((~traffic_bound & mem_bound).sum())
-        stage_votes["compute"] = int((~traffic_bound & ~mem_bound).sum())
-    else:
-        # async pipeline: sample latency = sum over layers of the layer's
-        # slowest event-driven core + NoC transit
-        times = np.zeros(T, np.float64)
-        for m, a in zip(mem_all, act_all):
-            times = times + np.maximum(m, a).max(axis=1, initial=0.0)
-        times = times + (profile.c_msg_hop * traffic.total_hops
-                         / max(part.total_cores, 1))
-        stage_votes["memory"] = T
+        stage_votes = {"memory": 0, "compute": 0, "traffic": 0,
+                       "barrier": 0}
+        if profile.synchronous:
+            t_compute = core_time.max(axis=1, initial=0.0)
+            times = np.maximum(t_compute, traffic_time) + profile.t_barrier
+            traffic_bound = traffic_time > t_compute
+            mem_bound = (mem_cat.max(axis=1, initial=0.0)
+                         >= act_cat.max(axis=1, initial=0.0))
+            stage_votes["traffic"] = int(traffic_bound.sum())
+            stage_votes["memory"] = int((~traffic_bound & mem_bound).sum())
+            stage_votes["compute"] = int((~traffic_bound & ~mem_bound).sum())
+        else:
+            # async pipeline: sample latency = sum over layers of the
+            # layer's slowest event-driven core + NoC transit
+            times = np.zeros(T, np.float64)
+            for m, a in zip(mem_all, act_all):
+                times = times + np.maximum(m, a).max(axis=1, initial=0.0)
+            times = times + (profile.c_msg_hop * traffic.total_hops
+                             / max(part.total_cores, 1))
+            stage_votes["memory"] = T
 
-    n_active = np.sum((synops_all + msgs_all) > 0, axis=1).astype(np.float64)
-    n_active[n_active == 0] = n_logical
-    e_hops = profile.e_msg_hop * traffic.total_hops
-    energies = (times * (profile.p_idle + profile.p_core * n_active)
-                + e_events + e_hops)
+        n_active = np.sum((synops_all + msgs_all) > 0,
+                          axis=1).astype(np.float64)
+        n_active[n_active == 0] = n_logical
+        e_hops = profile.e_msg_hop * traffic.total_hops
+        energies = (times * (profile.p_idle + profile.p_core * n_active)
+                    + e_events + e_hops)
 
-    mean_synops = synops_all.sum(axis=0) / T
-    mean_acts = acts_all.sum(axis=0) / T
-    mean_msgs = msgs_all.sum(axis=0) / T
-    return _finish_report(
-        net, part, T, times, energies, outputs, mean_synops, mean_acts,
-        mean_msgs,
-        max_synops_steps=synops_all.max(axis=1, initial=0.0),
-        max_acts_steps=acts_all.max(axis=1, initial=0.0),
-        max_link_steps=max_link_steps,
-        total_msgs=total_msgs, total_neuron_steps=total_neuron_steps,
-        stage_votes=stage_votes)
+        mean_synops = synops_all.sum(axis=0) / T
+        mean_acts = acts_all.sum(axis=0) / T
+        mean_msgs = msgs_all.sum(axis=0) / T
+        return _finish_report(
+            net, part, T, times, energies, outputs, mean_synops, mean_acts,
+            mean_msgs,
+            max_synops_steps=synops_all.max(axis=1, initial=0.0),
+            max_acts_steps=acts_all.max(axis=1, initial=0.0),
+            max_link_steps=max_link_steps,
+            total_msgs=total_msgs, total_neuron_steps=total_neuron_steps,
+            stage_votes=stage_votes)
 
 
 @dataclasses.dataclass(frozen=True)
