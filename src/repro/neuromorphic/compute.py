@@ -77,12 +77,15 @@ DEFAULT_COMPUTE = "dense"
 
 def _to_device(*arrays, dtype=None) -> tuple:
     """``jnp.asarray`` of each array under the span ``kernel.put``,
-    counting the bytes of every host array sent as ``h2d_bytes``."""
+    counting the bytes of every host array sent as ``h2d_bytes`` and of
+    every operand already on the device as ``h2d_reused_bytes``."""
     with tracing.span("kernel.put"):
         out = tuple(jnp.asarray(a, dtype) for a in arrays)
         for a, d in zip(arrays, out):
             if isinstance(a, np.ndarray):
                 tracing.count("h2d_bytes", d.nbytes)
+            elif isinstance(a, jax.Array):
+                tracing.count("h2d_reused_bytes", d.nbytes)
     return out
 
 
@@ -267,6 +270,18 @@ class _WeightBlocks:
         return wb
 
 
+def _device_weights(layer, w: np.ndarray, wm: np.ndarray
+                    ) -> tuple[jax.Array, jax.Array]:
+    """The event kernel's weight operands on the device, one copy per
+    weights array: ``w`` (fc weights or conv patch weights, both derived
+    from ``layer.weights``) and its nnz mask ``wm`` are copied on first use
+    and cached through :func:`derived_from_weights`, so every later call,
+    and the windowed delta path's second pass, sends only activations."""
+    return derived_from_weights(
+        layer, "_event_weights_device",
+        lambda _: _to_device(w, wm, dtype=jnp.float32))
+
+
 def _fc_weight_blocks(layer, bk: int, bn: int) -> _WeightBlocks:
     return derived_from_weights(
         layer, f"_fc_weight_blocks_{bk}x{bn}",
@@ -398,19 +413,21 @@ class EventCompute(LayerCompute):
                 out[i0:i1] = x[i0:i1, cols] @ w[cols]
         return out
 
-    def _pair(self, x: np.ndarray, m: np.ndarray, w: np.ndarray,
+    def _pair(self, layer, x: np.ndarray, m: np.ndarray, w: np.ndarray,
               wm: np.ndarray, wb: "_WeightBlocks | None" = None
               ) -> tuple[np.ndarray, np.ndarray]:
         """(pre, macs) through the selected kernel mode.  ``wb`` threads the
         layer's block-CSR weight structure into both contractions: ``wm`` is
         the nnz mask of ``w``, so the two share one occupancy map and skip
         exactly the same tiles — which is what keeps the counter matmul
-        bit-identical to the dense reference under weight skipping."""
+        bit-identical to the dense reference under weight skipping.  The
+        kernel takes ``layer``'s device copy of ``(w, wm)``."""
         if self._kernel_mode() == "gather":
             return (self._gather_matmul(np.asarray(x, np.float32), w, wb=wb),
                     self._gather_matmul(np.asarray(m, np.float32), wm, wb=wb))
         y, macs = event_matmul_pair(
-            *_to_device(x, m, w, wm, dtype=jnp.float32),
+            *_to_device(x, m, *_device_weights(layer, w, wm),
+                        dtype=jnp.float32),
             wb.occ_j if wb is not None else None, threshold=self.threshold,
             bm=self.bm, bk=self.bk, bn=self.bn)
         return _to_host(y, macs)
@@ -418,8 +435,8 @@ class EventCompute(LayerCompute):
     # ------------------------------------------------------------ layer kinds
     def fc_forward(self, layer, x_eff, act_mask, msgs_in):
         wb = _fc_weight_blocks(layer, self.bk, self.bn)
-        pre, macs = self._pair(x_eff, act_mask, layer.weights, layer.w_mask,
-                               wb)
+        pre, macs = self._pair(layer, x_eff, act_mask, layer.weights,
+                               layer.w_mask, wb)
         fetches = np.broadcast_to(msgs_in[:, None].astype(np.float32),
                                   macs.shape)
         return pre, macs, fetches
@@ -490,7 +507,7 @@ class EventCompute(LayerCompute):
         else:
             xpat = _im2col(x4, kh, kw, layer.stride, oh, ow)
             mpat = _im2col(m4, kh, kw, layer.stride, oh, ow)
-            pre, macs = self._pair(xpat, mpat, wf, wfm,
+            pre, macs = self._pair(layer, xpat, mpat, wf, wfm,
                                    _conv_weight_blocks(layer, self.bk,
                                                        self.bn))
             fetch_rows = mpat.sum(axis=1, dtype=np.float32)

@@ -258,8 +258,57 @@ def test_h2d_bytes_of_the_windowed_delta_path():
     k0, k1, n1 = sizes
     layer0 = 4 * (2 * T * k0 + 2 * k0 * k1)
     # the delta stream and its accumulator, then one kernel pass over the
-    # within-window sums and one over the window bases
+    # within-window sums and one over the window bases; the second pass
+    # reuses the weights the first one left on the device
     nwin = T // window
     layer1 = 4 * ((T * k1 + k1) + (2 * T * k1 + 2 * k1 * n1)
-                  + (2 * nwin * k1 + 2 * k1 * n1))
+                  + 2 * nwin * k1)
     assert _h2d(spans) == layer0 + layer1
+
+
+def _reused(spans) -> int:
+    return sum(s.counts.get("h2d_reused_bytes", 0) for s in spans
+               if s.name == "kernel.put")
+
+
+def _same_counters(a, b):
+    for ca, cb in zip(a[1], b[1], strict=True):
+        for f in dataclasses.fields(ca):
+            np.testing.assert_array_equal(getattr(ca, f.name),
+                                          getattr(cb, f.name), f.name)
+
+
+def _same_run(a, b):
+    np.testing.assert_array_equal(a[0], b[0])
+    _same_counters(a, b)
+
+
+def test_a_second_event_run_copies_only_activations():
+    sizes, T = [24, 40, 16], 6
+    net = fc_network(sizes, seed=0, neuron_model="ssm")
+    xs = make_inputs(sizes[0], 0.3, T, seed=3)
+    cc = EventCompute(mode="pallas")
+    first, _ = _traced(lambda: net.run_batch(xs, compute=cc))
+    second, spans = _traced(lambda: net.run_batch(xs, compute=cc))
+    pairs = list(zip(sizes[:-1], sizes[1:]))
+    assert _h2d(spans) == sum(4 * 2 * T * k for k, _ in pairs)
+    assert _reused(spans) == sum(4 * 2 * k * n for k, n in pairs)
+    _same_run(first, second)
+    dense = net.run_batch(xs, compute="dense")
+    _same_counters(second, dense)
+    np.testing.assert_array_equal(second[0], dense[0])
+
+
+def test_rebound_weights_are_copied_again_and_used():
+    sizes, T = [24, 40, 16], 6
+    net = fc_network(sizes, seed=0, neuron_model="ssm")
+    xs = make_inputs(sizes[0], 0.3, T, seed=3)
+    cc = EventCompute(mode="pallas")
+    net.run_batch(xs, compute=cc)             # weights resident
+    net.layers[0].weights = -net.layers[0].weights
+    out, spans = _traced(lambda: net.run_batch(xs, compute=cc))
+    k0, k1, n1 = sizes
+    assert _h2d(spans) == 4 * (2 * T * k0 + 2 * k0 * k1 + 2 * T * k1)
+    fresh = fc_network(sizes, seed=0, neuron_model="ssm")
+    fresh.layers[0].weights = -fresh.layers[0].weights
+    _same_run(out, fresh.run_batch(xs, compute=cc))

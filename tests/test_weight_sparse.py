@@ -293,6 +293,33 @@ class TestDerivedWeightCaches:
         assert len(calls) == 2                      # rebuilt on rebind
 
     @quick
+    @pytest.mark.parametrize("index", [0, 2], ids=["conv", "fc"])
+    def test_device_weight_pair_invalidates_on_rebind(self, index):
+        """The event kernel's device copy of (weights, nnz mask) — conv
+        patch weights or fc weights — is kept while ``layer.weights`` is
+        the same array and copied anew after a rebind."""
+        net = conv_stack(seed=5)
+        layer = net.layers[index]
+        xs = make_inputs(net.in_size, 0.4, 4, seed=6)
+        cc = EventCompute(mode="pallas")
+
+        def pair():
+            net.run_batch(xs, compute=cc)
+            host = (_patch_weights(layer)[:2] if layer.kind == "conv"
+                    else (layer.weights, layer.w_mask))
+            dev = layer.__dict__["_event_weights_device"][1]
+            for h, d in zip(host, dev, strict=True):
+                np.testing.assert_array_equal(np.asarray(d), h)
+            return dev
+
+        first = pair()
+        assert pair() is first                      # kept while same array
+        rng = np.random.default_rng(0)
+        layer.weights = layer.weights * _exact_density_mask(
+            layer.weights.shape, 0.5, rng)
+        assert pair() is not first                  # copied anew on rebind
+
+    @quick
     def test_patch_weights_staleness_regression(self):
         """The PR-10 satellite bug: run a conv forward (populating the
         patch-weight cache), then rewrite the weights in place as
