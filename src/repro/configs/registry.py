@@ -11,9 +11,9 @@ import dataclasses
 from typing import Callable
 
 from repro.configs import (gemma2_2b, granite_3_2b, kimi_k2_1t_a32b,
-                           mamba2_1_3b, minicpm_2b, olmoe_1b_7b,
-                           phi3_medium_14b, pixtral_12b, recurrentgemma_2b,
-                           whisper_base)
+                           mamba2_1_3b, minicpm_2b, nemotron3_nano_30b_a3b,
+                           olmoe_1b_7b, phi3_medium_14b, pixtral_12b,
+                           recurrentgemma_2b, whisper_base)
 from repro.configs.shapes import (ShapeSpec, encdec_input_specs,
                                   lm_input_specs)
 from repro.models.encdec import EncDecCfg
@@ -43,7 +43,7 @@ _MODULES = {
     "moe": [kimi_k2_1t_a32b, olmoe_1b_7b],
     "audio": [whisper_base],
     "ssm": [mamba2_1_3b],
-    "hybrid": [recurrentgemma_2b],
+    "hybrid": [recurrentgemma_2b, nemotron3_nano_30b_a3b],
 }
 
 REGISTRY: dict[str, ArchEntry] = {}

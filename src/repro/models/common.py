@@ -29,8 +29,20 @@ class MoECfg:
     capacity_factor: float = 1.25
     decode_capacity_factor: float = 4.0   # routing variance matters more at tiny T
     n_shared_experts: int = 0       # always-on experts (kimi-k2 style)
+    glu: bool = True                # gated (wi, wg, wo) experts; False:
+                                    # non-gated (wi, wo), e.g. relu^2
+    #: None: a softmax router.  A number: Nemotron-H's router, sigmoid
+    #: scores whose top_k are renormalised and multiplied by this factor;
+    #: the neuromorphic lowering then routes every step (a ``Router``)
+    #: instead of keeping a fixed top_k experts live.
+    routed_scale: Optional[float] = None
     router_aux_weight: float = 0.01  # load-balance loss (Switch-style)
     router_z_weight: float = 1e-3
+
+    @property
+    def n_matrices(self) -> int:
+        """Weight matrices per expert: wi, wg and wo, or wi and wo."""
+        return 3 if self.glu else 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,7 +94,7 @@ class ModelCfg:
     n_repeats: int = 0
     suffix: tuple[BlockCfg, ...] = ()
 
-    act_fn: str = "silu"            # "silu" | "gelu" | "relu"
+    act_fn: str = "silu"            # "silu" | "gelu" | "relu" | "relu2"
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-6
     attn_softcap: Optional[float] = None     # gemma-2 logit soft-capping
@@ -150,8 +162,8 @@ class ModelCfg:
             if blk.moe is not None:
                 m = blk.moe
                 total += d * m.n_experts        # router
-                total += m.n_experts * 3 * d * m.d_ff
-                total += m.n_shared_experts * 3 * d * m.d_ff
+                total += (m.n_experts + m.n_shared_experts) \
+                    * m.n_matrices * d * m.d_ff
             elif blk.d_ff:
                 total += 3 * d * blk.d_ff       # SwiGLU wi/wg/wo
         return total
@@ -163,7 +175,7 @@ class ModelCfg:
             if blk.moe is not None:
                 m = blk.moe
                 inactive = m.n_experts - m.top_k
-                total -= inactive * 3 * self.d_model * m.d_ff
+                total -= inactive * m.n_matrices * self.d_model * m.d_ff
         return total
 
 

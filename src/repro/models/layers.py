@@ -161,6 +161,7 @@ def softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
 ACTS: dict[str, Callable] = {
     "silu": jax.nn.silu, "gelu": functools.partial(jax.nn.gelu, approximate=True),
     "relu": jax.nn.relu,
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),   # squared ReLU
 }
 
 

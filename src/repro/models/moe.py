@@ -45,13 +45,15 @@ def moe_params(kg: KeyGen, cfg: ModelCfg, m: MoECfg, dtype) -> dict:
     p = {
         "router": _init(kg(), (d, m.n_experts), d, jnp.float32),
         "wi": _init(kg(), (m.n_experts, d, m.d_ff), d, dtype),
-        "wg": _init(kg(), (m.n_experts, d, m.d_ff), d, dtype),
-        "wo": _init(kg(), (m.n_experts, m.d_ff, d), m.d_ff, dtype),
     }
+    if m.glu:
+        p["wg"] = _init(kg(), (m.n_experts, d, m.d_ff), d, dtype)
+    p["wo"] = _init(kg(), (m.n_experts, m.d_ff, d), m.d_ff, dtype)
     if m.n_shared_experts:
         ffs = m.d_ff * m.n_shared_experts
         p["s_wi"] = _init(kg(), (d, ffs), d, dtype)
-        p["s_wg"] = _init(kg(), (d, ffs), d, dtype)
+        if m.glu:
+            p["s_wg"] = _init(kg(), (d, ffs), d, dtype)
         p["s_wo"] = _init(kg(), (ffs, d), ffs, dtype)
     return p
 
@@ -68,6 +70,9 @@ def moe_param_specs(cfg: ModelCfg, m: MoECfg, ctx: ShardCtx) -> dict:
     if m.n_shared_experts:
         specs.update({"s_wi": P(None, tp), "s_wg": P(None, tp),
                       "s_wo": P(tp, None)})
+    if not m.glu:                   # non-gated experts carry no wg
+        del specs["wg"]
+        specs.pop("s_wg", None)
     return specs
 
 
@@ -139,8 +144,11 @@ def _local_moe(x, p, *, m: MoECfg, cfg: ModelCfg, ep: int, tp_name: str,
 
     # ---- expert FFN (ff sharded over `model`) -----------------------------
     h = jnp.einsum("ecd,edf->ecf", xe, p["wi"])
-    g = jnp.einsum("ecd,edf->ecf", xe, p["wg"])
-    ye = jnp.einsum("ecf,efd->ecd", act(g) * h, p["wo"])
+    if m.glu:
+        h = act(jnp.einsum("ecd,edf->ecf", xe, p["wg"])) * h
+    else:
+        h = act(h)
+    ye = jnp.einsum("ecf,efd->ecd", h, p["wo"])
     if sp_dispatch:
         # reduce-scatter instead of all-reduce: each TP shard directly owns
         # the d/tp slice it will ship on the return all_to_all.
@@ -166,7 +174,8 @@ def _local_moe(x, p, *, m: MoECfg, cfg: ModelCfg, ep: int, tp_name: str,
 
     # ---- shared (always-on) experts ---------------------------------------
     if m.n_shared_experts:
-        hs = act(xf @ p["s_wg"]) * (xf @ p["s_wi"])
+        hs = (act(xf @ p["s_wg"]) * (xf @ p["s_wi"]) if m.glu
+              else act(xf @ p["s_wi"]))
         ys = jax.lax.psum(hs @ p["s_wo"], tp_name)
         y = y + ys
 

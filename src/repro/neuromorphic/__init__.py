@@ -18,8 +18,8 @@ from repro.neuromorphic.frontend import (AttnSpec, CompiledNetwork,
                                          LayerSpec, attention_probe,
                                          compile_network, excluded_params,
                                          lowering_spec)
-from repro.neuromorphic.network import (BatchCounters, SimLayer, SimNetwork,
-                                        fc_network, make_inputs,
+from repro.neuromorphic.network import (BatchCounters, Router, SimLayer,
+                                        SimNetwork, fc_network, make_inputs,
                                         programmed_fc_network)
 from repro.neuromorphic.partition import Partition, minimal_partition
 from repro.neuromorphic.noc import (Mapping, flow_matrix_population,
@@ -45,7 +45,8 @@ __all__ = [
     "register_compute",
     "AttnSpec", "CompiledNetwork", "LayerSpec", "attention_probe",
     "compile_network", "excluded_params", "lowering_spec",
-    "BatchCounters", "SimLayer", "SimNetwork", "fc_network", "make_inputs",
+    "BatchCounters", "Router", "SimLayer", "SimNetwork", "fc_network",
+    "make_inputs",
     "programmed_fc_network",
     "Partition", "minimal_partition",
     "Mapping", "flow_matrix_population", "flow_structures_rows",

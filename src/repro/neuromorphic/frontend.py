@@ -46,15 +46,35 @@ with the state layer's fanin wired per head/group (x channel + B/C group
 taps + dt), ``2*d_state + 2`` synapses per state neuron.
 
 **MoE** blocks emit each expert as a contiguous column block (a natural
-partition unit) plus ``n_experts`` router-logit columns; a static
-``msg_gate`` keeps exactly ``top_k + n_shared`` expert blocks messaging, so
-the router's top-k drives per-expert activation density and the down
-projection's event-driven MACs are ``(top_k + n_shared) * d_ff * d`` —
-:meth:`ModelCfg.active_param_count` arithmetic, produced by counters.
+partition unit) plus ``n_experts`` router-logit columns.  With a softmax
+router (``MoECfg.routed_scale`` None) a static ``msg_gate`` keeps exactly
+``top_k + n_shared`` expert blocks messaging, so the down projection's
+event-driven MACs are ``(top_k + n_shared) * d_ff * d`` —
+:meth:`ModelCfg.active_param_count` arithmetic, produced by counters.  With
+Nemotron-H's sigmoid router the up-projection carries a
+:class:`~repro.neuromorphic.network.Router`: at every step its router
+neurons pick the top-k experts, whose messages are scaled by their
+renormalised weights, and every other expert is silent.  Non-gated experts
+(``MoECfg.glu`` False, relu^2) are one ``d_ff`` block each; relu^2 runs as
+the ``relu`` neuron model, which messages on the same set.
 
-All emitted layers are ``kind="fc"`` with static gates, so the compiled
-network inherits every existing guarantee unchanged: bit-identical counters
-across the two engines (batched/reference) and compute backends
+**Shares.**  ``share=(index, n)`` lowers what partition ``index`` of ``n``
+holds of one period of the layer pattern (``cfg.pattern`` once) when every
+layer is divided ``n`` ways: its Mamba-2 heads (with their group's B/C
+taps, computed alike by every partition of the group), its query heads with
+their KV heads, and its routed experts, beside the router at its full width
+and the shared experts, which every partition holds.  Out- and
+down-projections give partial sums, and those go on to the next layer; the
+rest of the model (other periods, embedding, head) and the other
+partitions lie elsewhere and are not stood in for.  Each layer of a share
+is a submatrix (``LayerSpec.rows`` / ``cols``) of the same layer cut
+``(0, 1)``, and its weights are drawn per synapse from the seed and the
+synapse's indices in that uncut layer, so the shares of a layer add up to
+it.
+
+Emitted layers are ``kind="fc"`` with static gates or routers, so the
+compiled network inherits every existing guarantee unchanged: bit-identical
+counters across the two engines (batched/reference) and compute backends
 (dense/event), pricing caches, population backends and the evolutionary
 search all accept it like any hand-built network.
 """
@@ -67,7 +87,8 @@ import numpy as np
 
 from repro.models.common import BlockCfg, ModelCfg, MoECfg, RGLRUCfg, SSDCfg
 from repro.models.encdec import EncDecCfg
-from repro.neuromorphic.network import SimLayer, SimNetwork, make_inputs
+from repro.neuromorphic.network import (Router, SimLayer, SimNetwork,
+                                        make_inputs)
 
 DEFAULT_SEQ_LEN = 16        # steady-state decode context for smoke pricing
 _RECURRENT_NEURONS = ("ssm", "sd_relu")
@@ -98,7 +119,11 @@ class LayerSpec:
     not from built weights); compile asserts the built mask reproduces them
     and the property suite asserts the simulator's counters do too.
     ``macs_per_token`` assumes the dense-activity token pipeline (every
-    ungated neuron messaging, the compile default).
+    ungated neuron messaging, the compile default); it is None behind a
+    router that holds only some of the experts, where it depends on the
+    routing.  ``rows`` / ``cols`` are the ``[start, stop)`` ranges of the
+    uncut layer's fan-in and neurons that this layer holds (see
+    ``share=``).
     """
 
     name: str
@@ -108,9 +133,12 @@ class LayerSpec:
     role: str                       # "param" | "kv" | "state" | "head"
     nnz: int                        # structural nonzero synapses
     param_nnz: int                  # contribution to cfg.param_count()
-    macs_per_token: int             # exact MACs per timestep
+    macs_per_token: int | None      # exact MACs per timestep
     neuron_model: str = "relu"
-    gate: tuple | None = None       # ("moe", E, shared, top_k, d_ff)
+    gate: tuple | None = None       # ("moe", E, shared, top_k, d_ff, block)
+    router: Router | None = None
+    rows: tuple = ()
+    cols: tuple = ()
 
 
 # ----------------------------------------------------------- mask structures
@@ -125,7 +153,7 @@ def _structure_nnz(structure: tuple, fanin: int, width: int) -> int:
         _, heads, seq, head_dim = structure
         return heads * seq * head_dim
     if kind == "moe_down":
-        _, n_experts_total, n_router, d_ff = structure
+        _, n_experts_total, _, d_ff, _ = structure
         return n_experts_total * d_ff * width
     if kind == "ssd_state":
         _, d_inner, head_dim, n_groups, d_state = structure
@@ -152,11 +180,12 @@ def _structure_mask(spec: LayerSpec) -> np.ndarray:
         for h in range(heads):
             m[h * seq:(h + 1) * seq, h * hd:(h + 1) * hd] = 1.0
     elif kind == "moe_down":
-        # fanin layout [expert 0 (wi|wg) .. expert n-1 (wi|wg) | router];
-        # only the wi half of each expert projects down
-        _, n_tot, n_router, f = spec.structure
+        # fanin layout [expert 0 .. expert n-1 | router], ``block`` neurons
+        # an expert (wi|wg, or wi alone for non-gated experts); only the wi
+        # part of each expert projects down
+        _, n_tot, n_router, f, block = spec.structure
         for e in range(n_tot):
-            m[e * 2 * f: e * 2 * f + f, :] = 1.0
+            m[e * block: e * block + f, :] = 1.0
     elif kind == "ssd_state":
         # fanin layout [x (di) | z (di) | B (G*st) | C (G*st) | dt (h)]
         _, di, hd, groups, st = spec.structure
@@ -180,13 +209,13 @@ def _structure_gate(spec: LayerSpec) -> np.ndarray | None:
     """Static per-neuron message gate (MoE expert activation)."""
     if spec.gate is None:
         return None
-    tag, n_experts, n_shared, top_k, f = spec.gate
+    tag, n_experts, n_shared, top_k, f, block = spec.gate
     assert tag == "moe"
     g = np.zeros(spec.width, np.float32)
     for e in range(top_k):                       # routed experts kept live
-        g[e * 2 * f:(e + 1) * 2 * f] = 1.0
+        g[e * block:(e + 1) * block] = 1.0
     for e in range(n_experts, n_experts + n_shared):   # always-on experts
-        g[e * 2 * f:(e + 1) * 2 * f] = 1.0
+        g[e * block:(e + 1) * block] = 1.0
     g[-n_experts:] = 1.0                         # router logits always emit
     return g
 
@@ -195,36 +224,63 @@ def _structure_gate(spec: LayerSpec) -> np.ndarray | None:
 
 class _Lowering:
     """Accumulates LayerSpecs; tracks the previous layer's gate so per-token
-    MAC arithmetic stays exact across gated boundaries."""
+    MAC arithmetic stays exact across gated boundaries.  With ``share``,
+    each block keeps partition ``share[0]`` of ``share[1]``'s units."""
 
-    def __init__(self, seq_len: int, recurrent_neuron: str):
+    def __init__(self, seq_len: int, recurrent_neuron: str,
+                 share: tuple[int, int] | None = None):
         if recurrent_neuron not in _RECURRENT_NEURONS:
             raise ValueError(f"recurrent_neuron must be one of "
                              f"{_RECURRENT_NEURONS}, got {recurrent_neuron!r}")
         self.seq_len = seq_len
         self.recurrent_neuron = recurrent_neuron
+        self.share = share
         self.specs: list[LayerSpec] = []
         self.attn_specs: list[AttnSpec] = []
         self._prev_gate: tuple | None = None
+        self._prev_router: Router | None = None
+
+    def _held(self, count: int, what: str) -> tuple[int, int]:
+        """``[start, stop)`` of the ``count`` units this partition holds."""
+        if self.share is None:
+            return 0, count
+        index, n = self.share
+        if count % n:
+            raise ValueError(f"{what}: {count} do not divide {n} ways")
+        k = count // n
+        return index * k, (index + 1) * k
+
+    def _whole(self, what: str) -> None:
+        if self.share is not None:
+            raise ValueError(f"a share of {what} is not lowered")
 
     def add(self, name: str, fanin: int, width: int, structure: tuple,
             role: str, *, param_nnz: int = 0, neuron_model: str = "relu",
-            gate: tuple | None = None) -> None:
+            gate: tuple | None = None, router: Router | None = None,
+            rows: tuple = (), cols: tuple = ()) -> None:
         nnz = _structure_nnz(structure, fanin, width)
-        if self._prev_gate is None:
+        if self._prev_gate is None and self._prev_router is None:
             macs = nnz                       # dense input activity
         else:
-            # Input messages are gated by the previous layer's static MoE
-            # gate: only live expert blocks' wi rows reach nonzero weights.
-            tag, n_experts, n_shared, top_k, f = self._prev_gate
+            # Input messages are gated by the previous layer's MoE gate:
+            # only live expert blocks' wi rows reach nonzero weights.
             assert structure[0] == "moe_down", \
                 "only moe_up -> moe_down gating is lowered"
-            macs = (top_k + n_shared) * f * width
+            if self._prev_gate is not None:
+                _, n_experts, n_shared, top_k, f, _ = self._prev_gate
+                macs = (top_k + n_shared) * f * width
+            else:
+                r = self._prev_router
+                macs = ((r.top_k + r.n_shared) * structure[3] * width
+                        if len(r.held) == r.n_experts else None)
         self.specs.append(LayerSpec(
             name=name, fanin=fanin, width=width, structure=structure,
             role=role, nnz=nnz, param_nnz=param_nnz,
-            macs_per_token=macs, neuron_model=neuron_model, gate=gate))
+            macs_per_token=macs, neuron_model=neuron_model, gate=gate,
+            router=router, rows=rows or ((0, fanin),),
+            cols=cols or ((0, width),)))
         self._prev_gate = gate
+        self._prev_router = router
 
     # -------------------------------------------------------------- blocks
     def attn(self, prefix: str, d: int, heads: int, kv_heads: int,
@@ -232,20 +288,32 @@ class _Lowering:
              window: int | None = None, softcap: float | None = None,
              cross: bool = False) -> None:
         q, kv = heads * head_dim, kv_heads * head_dim
-        self.add(f"{prefix}.qkv", d, q + 2 * kv, ("dense",), "param",
-                 param_nnz=d * (q + 2 * kv))
-        self.add(f"{prefix}.scores", q + 2 * kv, heads * seq,
-                 ("attn_scores", heads, seq, head_dim), "kv")
-        self.add(f"{prefix}.values", heads * seq, q,
-                 ("attn_values", heads, seq, head_dim), "kv")
-        self.add(f"{prefix}.out", q, d, ("dense",), "param",
-                 param_nnz=q * d)
+        h0, h1 = self._held(heads, f"{prefix} query heads")
+        per_kv = heads // kv_heads
+        k0, k1 = h0 // per_kv, (h1 - 1) // per_kv + 1
+        hq, hk = h1 - h0, k1 - k0
+        ql, kvl = hq * head_dim, hk * head_dim
+        lanes = (h0 * head_dim, h1 * head_dim)
+        qkv = (lanes, (q + k0 * head_dim, q + k1 * head_dim),
+               (q + kv + k0 * head_dim, q + kv + k1 * head_dim))
+        scores = ((h0 * seq, h1 * seq),)
+        self.add(f"{prefix}.qkv", d, ql + 2 * kvl, ("dense",), "param",
+                 param_nnz=d * (ql + 2 * kvl), cols=qkv)
+        self.add(f"{prefix}.scores", ql + 2 * kvl, hq * seq,
+                 ("attn_scores", hq, seq, head_dim), "kv", rows=qkv,
+                 cols=scores)
+        self.add(f"{prefix}.values", hq * seq, ql,
+                 ("attn_values", hq, seq, head_dim), "kv", rows=scores,
+                 cols=(lanes,))
+        self.add(f"{prefix}.out", ql, d, ("dense",), "param",
+                 param_nnz=ql * d, rows=(lanes,))
         self.attn_specs.append(AttnSpec(
-            name=prefix, heads=heads, kv_heads=kv_heads, head_dim=head_dim,
+            name=prefix, heads=hq, kv_heads=hk, head_dim=head_dim,
             seq=seq, causal=causal, window=window, softcap=softcap,
             cross=cross))
 
     def mlp(self, prefix: str, d: int, d_ff: int) -> None:
+        self._whole("a dense MLP")
         # SwiGLU/GeGLU: wi|wg fused up, gate half carries no down weights
         self.add(f"{prefix}.in", d, 2 * d_ff, ("dense",), "param",
                  param_nnz=2 * d * d_ff)
@@ -253,29 +321,60 @@ class _Lowering:
                  "param", param_nnz=d_ff * d)
 
     def moe(self, prefix: str, d: int, m: MoECfg) -> None:
-        n_tot = m.n_experts + m.n_shared_experts
-        f = m.d_ff
-        width = n_tot * 2 * f + m.n_experts
-        self.add(f"{prefix}.experts_up", d, width, ("dense",), "param",
-                 param_nnz=d * width,
-                 gate=("moe", m.n_experts, m.n_shared_experts, m.top_k, f))
-        self.add(f"{prefix}.experts_down", width, d,
-                 ("moe_down", n_tot, m.n_experts, f), "param",
-                 param_nnz=n_tot * f * d)
+        E, f, n_sh = m.n_experts, m.d_ff, m.n_shared_experts
+        block = 2 * f if m.glu else f        # wi|wg, or wi alone
+        if m.routed_scale is None:
+            self._whole("a softmax-routed MoE")
+            n_tot = E + n_sh
+            width = n_tot * block + E
+            self.add(f"{prefix}.experts_up", d, width, ("dense",), "param",
+                     param_nnz=d * width,
+                     gate=("moe", E, n_sh, m.top_k, f, block))
+            self.add(f"{prefix}.experts_down", width, d,
+                     ("moe_down", n_tot, E, f, block), "param",
+                     param_nnz=n_tot * f * d)
+            return
+        e0, e1 = self._held(E, f"{prefix} routed experts")
+        router = Router(n_experts=E, top_k=m.top_k, width=block,
+                        held=tuple(range(e0, e1)), n_shared=n_sh,
+                        scale=m.routed_scale)
+        n_tot = e1 - e0 + n_sh
+        up = ((e0 * block, e1 * block), (E * block, (E + n_sh) * block),
+              ((E + n_sh) * block, (E + n_sh) * block + E))
+        self.add(f"{prefix}.experts_up", d, router.n_neurons, ("dense",),
+                 "param", param_nnz=d * router.n_neurons, router=router,
+                 cols=up)
+        self.add(f"{prefix}.experts_down", router.n_neurons, d,
+                 ("moe_down", n_tot, E, f, block), "param",
+                 param_nnz=n_tot * f * d, rows=up)
 
     def ssd(self, prefix: str, d: int, s: SSDCfg) -> None:
         di, st, groups = s.d_inner, s.d_state, s.n_groups
         n_heads = di // s.head_dim
-        fan = 2 * di + 2 * groups * st + n_heads
+        per_group = n_heads // groups
+        h0, h1 = self._held(n_heads, f"{prefix} Mamba heads")
+        if (h1 - h0) % per_group and per_group % (h1 - h0):
+            raise ValueError(f"{prefix}: {h1 - h0} heads a partition cut "
+                             f"groups of {per_group} unevenly")
+        g0, g1 = h0 // per_group, (h1 - 1) // per_group + 1
+        dl, gl, hd = (h1 - h0) * s.head_dim, g1 - g0, s.head_dim
+        b0, c0, t0 = 2 * di, 2 * di + groups * st, 2 * di + 2 * groups * st
+        x = (h0 * hd, h1 * hd)
+        fan_cols = (x, (di + h0 * hd, di + h1 * hd),
+                    (b0 + g0 * st, b0 + g1 * st), (c0 + g0 * st, c0 + g1 * st),
+                    (t0 + h0, t0 + h1))
+        fan = 2 * dl + 2 * gl * st + (h1 - h0)
         self.add(f"{prefix}.in", d, fan, ("dense",), "param",
-                 param_nnz=d * fan)
-        self.add(f"{prefix}.state", fan, di,
-                 ("ssd_state", di, s.head_dim, groups, st), "state",
-                 neuron_model=self.recurrent_neuron)
-        self.add(f"{prefix}.out", di, d, ("dense",), "param",
-                 param_nnz=di * d)
+                 param_nnz=d * fan, cols=fan_cols)
+        self.add(f"{prefix}.state", fan, dl,
+                 ("ssd_state", dl, hd, gl, st), "state",
+                 neuron_model=self.recurrent_neuron, rows=fan_cols,
+                 cols=(x,))
+        self.add(f"{prefix}.out", dl, d, ("dense",), "param",
+                 param_nnz=dl * d, rows=(x,))
 
     def rglru(self, prefix: str, d: int, r: RGLRUCfg) -> None:
+        self._whole("an RG-LRU mixer")
         dr = r.d_rnn
         self.add(f"{prefix}.in", d, 2 * dr, ("dense",), "param",
                  param_nnz=2 * d * dr)
@@ -287,6 +386,7 @@ class _Lowering:
                  param_nnz=dr * d)
 
     def head(self, d: int, vocab: int) -> None:
+        self._whole("the LM head")
         self.add("head", d, vocab, ("dense",), "head", param_nnz=vocab * d)
 
 
@@ -295,10 +395,19 @@ def _attn_context(window: int | None, seq_len: int) -> int:
 
 
 def lowering_spec(cfg, *, seq_len: int = DEFAULT_SEQ_LEN,
-                  recurrent_neuron: str = "ssm"
+                  recurrent_neuron: str = "ssm",
+                  share: tuple[int, int] | None = None
                   ) -> tuple[list[LayerSpec], list[AttnSpec]]:
-    """Pure-arithmetic lowering plan for ``cfg`` (no weights built)."""
-    lo = _Lowering(seq_len, recurrent_neuron)
+    """Pure-arithmetic lowering plan for ``cfg`` (no weights built).
+    ``share=(index, n)``: partition ``index`` of ``n`` of one period of the
+    layer pattern (see the module docstring)."""
+    if share is not None:
+        index, n = share
+        if not (isinstance(cfg, ModelCfg) and cfg.pattern
+                and 0 <= index < n):
+            raise ValueError(f"share {share} needs a ModelCfg with a "
+                             "pattern and 0 <= index < n")
+    lo = _Lowering(seq_len, recurrent_neuron, share)
     if isinstance(cfg, EncDecCfg):
         d, H, K, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         for i in range(cfg.n_enc_layers):
@@ -317,7 +426,8 @@ def lowering_spec(cfg, *, seq_len: int = DEFAULT_SEQ_LEN,
         raise TypeError(f"cannot lower {type(cfg).__name__}; expected "
                         "ModelCfg, EncDecCfg, or a registry arch id")
     d = cfg.d_model
-    for bi, blk in enumerate(cfg.all_blocks()):
+    blocks = cfg.pattern if share is not None else cfg.all_blocks()
+    for bi, blk in enumerate(blocks):
         prefix = f"b{bi}"
         if blk.kind == "attn":
             lo.attn(f"{prefix}.attn", d, cfg.n_heads, cfg.n_kv_heads,
@@ -333,7 +443,8 @@ def lowering_spec(cfg, *, seq_len: int = DEFAULT_SEQ_LEN,
             lo.moe(f"{prefix}.moe", d, blk.moe)
         elif blk.d_ff:
             lo.mlp(f"{prefix}.mlp", d, blk.d_ff)
-    lo.head(d, cfg.vocab_size)
+    if share is None:
+        lo.head(d, cfg.vocab_size)
     return lo.specs, lo.attn_specs
 
 
@@ -385,6 +496,7 @@ class CompiledNetwork:
     seq_len: int
     specs: list[LayerSpec]
     attn_specs: list[AttnSpec]
+    share: tuple[int, int] | None = None
 
     @property
     def d_model(self) -> int:
@@ -396,6 +508,9 @@ class CompiledNetwork:
 
     def macs_per_token(self) -> int:
         """Exact per-timestep MAC total of the dense-activity pipeline."""
+        if any(s.macs_per_token is None for s in self.specs):
+            raise ValueError("the MACs behind a router that holds some of "
+                             "the experts depend on the routing")
         return sum(s.macs_per_token for s in self.specs)
 
     def inputs(self, steps: int, *, density: float = 1.0,
@@ -438,16 +553,55 @@ def _resolve_densities(act_density, n_layers: int) -> list[float | None]:
     return [float(d) for d in np.interp(dst, src, seq)]
 
 
+def _ranges(ranges: tuple) -> np.ndarray:
+    return np.concatenate([np.arange(a, b) for a, b in ranges])
+
+
+def _uniform(seed: int, layer: int, rows: np.ndarray,
+             cols: np.ndarray) -> np.ndarray:
+    """(len(rows), len(cols)) float64 uniforms in [0, 1), one per synapse,
+    a function of the seed, the layer and the synapse's (row, col) alone:
+    SplitMix64 of their combination."""
+    u64 = np.uint64
+    with np.errstate(over="ignore"):
+        key = (u64(seed % 2**64) * u64(0x9E3779B97F4A7C15)
+               + u64(layer) * u64(0xD1B54A32D192ED03))
+        z = (key + (rows.astype(u64)[:, None] << u64(32))
+             + cols.astype(u64)[None, :] + u64(0x9E3779B97F4A7C15))
+        z = (z ^ (z >> u64(30))) * u64(0xBF58476D1CE4E5B9)
+        z = (z ^ (z >> u64(27))) * u64(0x94D049BB133111EB)
+        z = z ^ (z >> u64(31))
+    return (z >> u64(11)).astype(np.float64) * 2.0 ** -53
+
+
+def _share_weights(spec: LayerSpec, uncut: LayerSpec, seed: int,
+                   layer: int) -> np.ndarray:
+    """A share's weights: each synapse drawn from its indices in the uncut
+    layer, so that every share of the layer holds the same values there."""
+    rows, cols = _ranges(spec.rows), _ranges(spec.cols)
+    scale = 0.5 / np.sqrt(max(1.0, uncut.nnz / uncut.width))
+    w = np.empty((spec.fanin, spec.width), np.float32)
+    for r0 in range(0, spec.fanin, 256):
+        v = 2.0 * _uniform(seed, layer, rows[r0:r0 + 256], cols) - 1.0
+        w[r0:r0 + 256] = np.where(v >= 0, 1.0, -1.0) * (0.5 + np.abs(v)) \
+            * scale
+    return w * _structure_mask(spec)
+
+
 def _build_layer(spec: LayerSpec, rng: np.random.Generator,
-                 act_density: float | None) -> SimLayer:
-    mask = _structure_mask(spec)
-    # weight magnitudes bounded away from zero so nnz (hence every counter)
-    # is exactly the structural count; scale keeps the forced-active
-    # message magnitudes stable across deep stacks
-    scale = 0.5 / np.sqrt(max(1.0, spec.nnz / spec.width))
-    vals = rng.normal(0.0, 1.0, (spec.fanin, spec.width))
-    w = np.where(vals >= 0, 1.0, -1.0) * (0.5 + np.abs(vals)) * scale
-    w = (w * mask).astype(np.float32)
+                 act_density: float | None,
+                 weights: np.ndarray | None = None) -> SimLayer:
+    if weights is not None:
+        w = weights
+    else:
+        mask = _structure_mask(spec)
+        # weight magnitudes bounded away from zero so nnz (hence every
+        # counter) is exactly the structural count; scale keeps the
+        # forced-active message magnitudes stable across deep stacks
+        scale = 0.5 / np.sqrt(max(1.0, spec.nnz / spec.width))
+        vals = rng.normal(0.0, 1.0, (spec.fanin, spec.width))
+        w = np.where(vals >= 0, 1.0, -1.0) * (0.5 + np.abs(vals)) * scale
+        w = (w * mask).astype(np.float32)
     gate = _structure_gate(spec)
     if act_density is not None:
         live = np.nonzero(gate)[0] if gate is not None \
@@ -462,14 +616,15 @@ def _build_layer(spec: LayerSpec, rng: np.random.Generator,
         name=spec.name, kind="fc", weights=w,
         neuron_model=spec.neuron_model, msg_gate=gate,
         force_active=not sd, decay=0.5,
-        threshold=0.05 if sd else 0.0, sends_deltas=sd)
+        threshold=0.05 if sd else 0.0, sends_deltas=sd, router=spec.router)
 
 
 def compile_network(arch, *, seq_len: int = DEFAULT_SEQ_LEN,
                     smoke: bool = True, seed: int = 0,
                     act_density=None,
                     recurrent_neuron: str = "ssm",
-                    verify_attention: bool = False) -> CompiledNetwork:
+                    verify_attention: bool = False,
+                    share: tuple[int, int] | None = None) -> CompiledNetwork:
     """Compile a registry arch id (or raw config) into a CompiledNetwork.
 
     ``arch``: a ``repro.configs.registry`` id (``smoke=True`` selects the
@@ -484,18 +639,25 @@ def compile_network(arch, *, seq_len: int = DEFAULT_SEQ_LEN,
     (its measured densities drive the lowered layers — the trained
     replacement for synthetic schedules).  ``verify_attention`` runs
     the real flash_attn kernel against its oracle at every lowered
-    attention shape before returning.
+    attention shape before returning.  ``share=(index, n)`` compiles what
+    partition ``index`` of ``n`` holds of one period (module docstring);
+    its weights are those of the same synapses in ``share=(0, 1)``.
     """
     cfg, name, arch_id, family = _resolve(arch, smoke)
-    specs, attn_specs = lowering_spec(cfg, seq_len=seq_len,
-                                      recurrent_neuron=recurrent_neuron)
+    kw = dict(seq_len=seq_len, recurrent_neuron=recurrent_neuron)
+    specs, attn_specs = lowering_spec(cfg, share=share, **kw)
     rng = np.random.default_rng(seed)
     dens = _resolve_densities(act_density, len(specs))
-    layers = [_build_layer(s, rng, d) for s, d in zip(specs, dens)]
+    if share is None:
+        layers = [_build_layer(s, rng, d) for s, d in zip(specs, dens)]
+    else:
+        uncut = lowering_spec(cfg, share=(0, 1), **kw)[0]
+        layers = [_build_layer(s, rng, d, _share_weights(s, u, seed, i))
+                  for i, (s, u, d) in enumerate(zip(specs, uncut, dens))]
     net = SimNetwork(layers=layers, in_size=cfg.d_model)
     compiled = CompiledNetwork(
         net=net, cfg=cfg, name=name, arch_id=arch_id, family=family,
-        seq_len=seq_len, specs=specs, attn_specs=attn_specs)
+        seq_len=seq_len, specs=specs, attn_specs=attn_specs, share=share)
     if verify_attention:
         for spec in attn_specs:
             out, ref = attention_probe(spec, seed=seed)

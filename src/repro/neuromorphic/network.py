@@ -4,7 +4,9 @@ A :class:`SimNetwork` is a feed-forward stack of :class:`SimLayer` s.  Each
 layer owns its synaptic weights, neuron model (ReLU / IF-spiking / sigma-delta
 ReLU / SSM state), optional message gate (used to *program* exact activation
 sparsity, as the paper does in §V-A by "explicitly toggling neuron activation
-messaging on and off"), and weight format (dense/sparse, Fig. 4).
+messaging on and off"), optional :class:`Router` (a routed-expert gate that
+its own router neurons set at every step), and weight format (dense/sparse,
+Fig. 4).
 
 Two execution engines produce identical event counts:
 
@@ -87,6 +89,71 @@ class BatchCounters:
             acts_evented=self.acts_evented[t])
 
 
+@dataclasses.dataclass(frozen=True)
+class Router:
+    """A routed-expert message gate over an fc layer's own neurons.
+
+    The layer's neurons are laid out ``[held experts | shared experts |
+    router]``: ``len(held)`` blocks of ``width`` neurons for the routed
+    experts held here (expert ids ``held``, in that order), ``n_shared``
+    blocks of ``width`` for the always-on experts, and ``n_experts`` router
+    neurons.  At every step the router neurons' pre-activations ``r`` give
+    sigmoid scores; the ``top_k`` largest of all ``n_experts`` (ties to the
+    lower id) are renormalised to sum to one and multiplied by ``scale``.
+    A held expert's messages are scaled by its weight, or silenced when it
+    is not among the top ``top_k``; shared experts message unscaled; the
+    router neurons never message (the gate consumes them where they are).
+    Scaling an up-projection's messages is exact for the linear
+    down-projection that reads them.
+    """
+
+    n_experts: int
+    top_k: int
+    width: int
+    held: tuple[int, ...]
+    n_shared: int = 0
+    scale: float = 1.0
+
+    @property
+    def n_neurons(self) -> int:
+        return (len(self.held) + self.n_shared) * self.width + self.n_experts
+
+    def expert_weights(self, pre: np.ndarray) -> np.ndarray:
+        """(T, n_experts) float32 routing weights: zero off the top_k."""
+        r = np.asarray(pre, np.float32)[:, -self.n_experts:]
+        scores = (1.0 / (1.0 + np.exp(-r.astype(np.float64)))
+                  ).astype(np.float32)
+        top = np.argsort(-scores, axis=1, kind="stable")[:, :self.top_k]
+        w = np.take_along_axis(scores, top, axis=1)
+        total = w[:, 0]
+        for j in range(1, self.top_k):          # one fixed summation order
+            total = total + w[:, j]
+        out = np.zeros_like(scores)
+        np.put_along_axis(out, top,
+                          w / total[:, None] * np.float32(self.scale), axis=1)
+        return out
+
+    def gate(self, pre: np.ndarray) -> np.ndarray:
+        """(T, n_neurons) float32 per-neuron message scale of a step block
+        of the layer's pre-activations."""
+        T = pre.shape[0]
+        held = self.expert_weights(pre)[:, list(self.held)]
+        return np.concatenate(
+            [np.repeat(held, self.width, axis=1),
+             np.ones((T, self.n_shared * self.width), np.float32),
+             np.zeros((T, self.n_experts), np.float32)], axis=1)
+
+    def apply(self, pre: np.ndarray, y: np.ndarray) -> np.ndarray:
+        """``y`` gated, under the span ``sim.moe.gate`` with the count of
+        held-expert messages let through as ``expert_msgs``."""
+        with tracing.span("sim.moe.gate"):
+            y = y * self.gate(pre)
+            n_routed = len(self.held) * self.width
+            tracing.count("expert_msgs",
+                          int(np.count_nonzero(y[:, :n_routed])))
+            return y
+
+
 @dataclasses.dataclass
 class SimLayer:
     """One layer mapped onto one-or-more neurocores."""
@@ -104,6 +171,14 @@ class SimLayer:
     in_hw: tuple[int, int] | None = None   # conv only: input spatial dims
     force_active: bool = False      # characterization mode: all neurons emit
     sends_deltas: bool = False      # sigma-delta layers emit deltas
+    router: Router | None = None    # fc only: routed-expert message gate
+
+    def __post_init__(self):
+        if self.router is not None and (
+                self.kind != "fc" or self.router.n_neurons != self.n_neurons):
+            raise ValueError(
+                f"layer {self.name}: a router of {self.router.n_neurons} "
+                f"neurons needs an fc layer of that width")
 
     # ------------------------------------------------------------------ sizes
     @property
@@ -216,6 +291,8 @@ class SimLayer:
         y_msgs, state = self._neuron(pre, state)
         if self.msg_gate is not None:
             y_msgs = y_msgs * self.msg_gate
+        if self.router is not None:
+            y_msgs = self.router.apply(pre[None, :], y_msgs[None, :])[0]
         msgs_out = (y_msgs != 0).astype(np.float32)
 
         counters = CounterMaps(
@@ -276,6 +353,8 @@ class SimLayer:
             y_msgs, state = self._neuron_batch(pre, state)
         if self.msg_gate is not None:
             y_msgs = y_msgs * self.msg_gate
+        if self.router is not None:
+            y_msgs = self.router.apply(pre, y_msgs)
         msgs_out = (y_msgs != 0).astype(np.float32)
 
         counters = BatchCounters(

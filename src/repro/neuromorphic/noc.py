@@ -11,8 +11,18 @@ Messages from every core of layer l are duplicated (unicast per destination)
 to every core of layer l+1 (broadcast, §III-C); the last layer's outputs
 route to the chip I/O port at router 0.  Router load counts injections,
 transits, and deliveries; dimension-ordered (X-then-Y) routing on the router
-grid.  Per-pair router path incidence is precomputed per profile so a step's
-congestion is two small matmuls.
+grid.
+
+:func:`route_batch` / :func:`route_step` price one candidate without any
+per-core or per-router-pair table: a layer's messages are summed by source
+router, its next layer's cores counted by destination router, and the
+X-then-Y paths of all (source, destination) pairs folded into two small
+coverage tables per layer, a row table over the grid's columns and a
+column table over its rows, so memory grows with the grid and not with its
+square.  The population pricers (:func:`flow_matrix_population`,
+:func:`router_incidence_population`, :func:`flow_structures_rows`) keep
+per-pair path incidence tables of ``R**2`` rows, which the grids of today's
+searches hold.
 """
 
 from __future__ import annotations
@@ -85,10 +95,6 @@ def cores_per_router(profile: ChipProfile) -> int:
 def n_router_tiles(profile: ChipProfile) -> int:
     rows, cols = profile.grid
     return rows * cols
-
-
-def core_router(core: int, profile: ChipProfile) -> int:
-    return core // cores_per_router(profile)
 
 
 def _router_slot_to_core(order_idx: int, profile: ChipProfile) -> int:
@@ -357,7 +363,7 @@ def incidence_tables(grid: tuple[int, int]) -> tuple[np.ndarray, np.ndarray]:
     """Per-grid routing geometry in the shapes the device path consumes:
     ``inc3[src, dst, node]`` is the (R, R, R) path-incidence tensor and
     ``hops2[src, dst]`` the (R, R) Manhattan hop matrix — float64 reshaped
-    views of the lru-cached flat tables shared with :func:`route_batch`."""
+    views of the lru-cached flat tables of the population pricers."""
     rows, cols = grid
     R = rows * cols
     inc3 = _path_incidence(grid).astype(np.float64).reshape(R, R, R)
@@ -407,23 +413,109 @@ def flow_structures_rows(lid, router, alive, n_layers: int, inc3, hops2):
     return PL, ph, dup
 
 
+@dataclasses.dataclass(frozen=True)
+class _LayerRoutes:
+    """One layer's routing structure under one (partition, mapping)."""
+
+    gather: np.ndarray       # (cores, S) 0/1: core -> its router
+    rows: np.ndarray         # (S,) grid row of each source router
+    cols: np.ndarray         # (S,) grid column of each source router
+    row_cover: np.ndarray    # (cols, cols): [c1, c] loads on row r1
+    col_cover: np.ndarray    # (rows, rows, cols): [r1, r, c2]
+    hops: np.ndarray         # (S,) link traversals per source message
+    dup: int                 # destination cores per message
+
+
+def _layer_routes(src: np.ndarray, dest: np.ndarray,
+                  grid: tuple[int, int]) -> _LayerRoutes:
+    """Fold the X-then-Y paths from each router of ``src`` (one per core)
+    to every core of ``dest`` (destination routers, one per core) into
+    coverage tables.  A path from (r1, c1) to (r2, c2) touches row r1 from
+    c1 to c2 and then column c2 from beyond r1 to r2, so summed over the
+    destinations, router (r1, c) carries what every destination column at
+    or past c (from c1) sends, and router (r, c2) off row r1 what the
+    destinations of column c2 at or past r (from r1) receive."""
+    rows, cols = grid
+    cnt = np.zeros((rows, cols), np.float64)
+    np.add.at(cnt, (dest // cols, dest % cols), 1.0)
+    col_cnt, row_cnt = cnt.sum(axis=0), cnt.sum(axis=1)
+    ci, ri = np.arange(cols), np.arange(rows)
+    # row coverage: c > c1 takes the columns >= c, c < c1 those <= c
+    c_ge = np.cumsum(col_cnt[::-1])[::-1]
+    c_le = np.cumsum(col_cnt)
+    row_cover = np.where(ci[None, :] > ci[:, None], c_ge[None, :],
+                         np.where(ci[None, :] < ci[:, None], c_le[None, :],
+                                  col_cnt.sum()))
+    # column coverage off the source row, per destination column
+    r_ge = np.cumsum(cnt[::-1], axis=0)[::-1]
+    r_le = np.cumsum(cnt, axis=0)
+    above = ri[None, :, None] > ri[:, None, None]
+    below = ri[None, :, None] < ri[:, None, None]
+    col_cover = (np.where(above, r_ge[None], 0.0)
+                 + np.where(below, r_le[None], 0.0))
+    uniq, inv = np.unique(src, return_inverse=True)
+    gather = np.zeros((src.size, uniq.size), np.float64)
+    gather[np.arange(src.size), inv] = 1.0
+    r1, c1 = uniq // cols, uniq % cols
+    hops = (np.abs(r1[:, None] - ri[None, :]) @ row_cnt
+            + np.abs(c1[:, None] - ci[None, :]) @ col_cnt)
+    return _LayerRoutes(gather=gather, rows=r1, cols=c1,
+                        row_cover=row_cover, col_cover=col_cover,
+                        hops=hops, dup=int(dest.size))
+
+
+@functools.lru_cache(maxsize=64)
+def _routes(cores: tuple[int, ...], phys: tuple[int, ...],
+            grid: tuple[int, int], n_cores_phys: int) -> tuple:
+    """Per-(partition, mapping) :class:`_LayerRoutes` of every layer; the
+    last layer's messages go to the chip I/O port at router 0."""
+    cpr = max(1, n_cores_phys // (grid[0] * grid[1]))
+    routers = np.asarray(phys, np.int64) // cpr
+    off = np.concatenate([[0], np.cumsum(cores)]).astype(int)
+    out = []
+    for l in range(len(cores)):
+        dest = (routers[off[l + 1]:off[l + 2]] if l + 1 < len(cores)
+                else np.zeros(1, np.int64))
+        out.append(_layer_routes(routers[off[l]:off[l + 1]], dest, grid))
+    return tuple(out)
+
+
+def _route(part: Partition, mapping: Mapping, msgs: np.ndarray,
+           profile: ChipProfile) -> NocTrafficBatch:
+    """Route a (T, n_logical) message-count matrix.  Counts are integers
+    in float64, so every sum is exact whatever its order."""
+    rows, cols = profile.grid
+    m = np.asarray(msgs, np.float64)
+    T = m.shape[0]
+    loads = np.zeros((T, rows, cols), np.float64)
+    hops = np.zeros(T, np.float64)
+    inject = np.empty_like(m)
+    off = 0
+    for lr in _routes(part.cores, mapping.phys, profile.grid,
+                      profile.n_cores):
+        m_l = m[:, off:off + lr.gather.shape[0]]
+        inject[:, off:off + lr.gather.shape[0]] = m_l * lr.dup
+        off += lr.gather.shape[0]
+        by_src = m_l @ lr.gather                         # (T, S)
+        on_grid = np.zeros((T, rows, cols), np.float64)
+        on_grid[:, lr.rows, lr.cols] = by_src
+        loads += on_grid @ lr.row_cover                  # along source rows
+        loads += np.einsum("ta,arc->trc", on_grid.sum(axis=2),
+                           lr.col_cover)                 # down columns
+        hops += by_src @ lr.hops
+    return NocTrafficBatch(router_loads=loads.reshape(T, rows * cols),
+                           total_hops=hops, inject_per_core=inject)
+
+
 def route_batch(part: Partition, mapping: Mapping, msgs_out: np.ndarray,
                 profile: ChipProfile) -> NocTrafficBatch:
     """Route every timestep's messages at once.  ``msgs_out`` is the
-    (T, n_logical) per-core message-count matrix in logical core order; the
-    (T, R, R) flow tensor is one matmul against the cached per-core flow
-    incidence, and router loads / hop counts are one matmul each against the
-    cached path incidence.  Counts are integers in float64, so the results
-    are bit-identical to T :func:`route_step` calls."""
+    (T, n_logical) per-core message-count matrix in logical core order;
+    each message is unicast to every core of the next layer, the last
+    layer's to router 0.  Results are bit-identical to T
+    :func:`route_step` calls."""
     with tracing.span("price.route"):
-        P, dup = _flow_matrix(part.cores, mapping.phys, profile.grid,
-                              profile.n_cores)
-        m = np.asarray(msgs_out, np.float64)
-        flow_flat = m @ P                                   # (T, R*R)
-        loads = flow_flat @ _path_incidence(profile.grid)   # (T, R)
-        hops = flow_flat @ _pair_hops(profile.grid)         # (T,)
-        return NocTrafficBatch(router_loads=loads, total_hops=hops,
-                               inject_per_core=m * dup)
+        return _route(part, mapping, msgs_out, profile)
 
 
 def route_step(part: Partition, mapping: Mapping,
@@ -432,31 +524,9 @@ def route_step(part: Partition, mapping: Mapping,
     """Route one timestep's messages.  ``msgs_out_per_core[l]`` holds message
     counts per core of layer l; each message is unicast-duplicated to every
     core of layer l+1; the final layer exits at router 0."""
-    grid = profile.grid
-    R = n_router_tiles(profile)
-    flow = np.zeros((R, R), np.float64)          # router -> router packets
-    n_logical = part.total_cores
-    inject = np.zeros(n_logical, np.float64)
-    offsets = np.concatenate([[0], np.cumsum(part.cores)]).astype(int)
-    routers = np.asarray([core_router(p, profile) for p in mapping.phys])
-
-    n_layers = len(part.cores)
-    for l in range(n_layers):
-        src_idx = np.arange(offsets[l], offsets[l + 1])
-        msgs = np.asarray(msgs_out_per_core[l], np.float64)
-        if l + 1 < n_layers:
-            dst_routers = routers[offsets[l + 1]:offsets[l + 2]]
-        else:
-            dst_routers = np.asarray([0])        # chip I/O port
-        inject[src_idx] += msgs * len(dst_routers)
-        src_routers = routers[src_idx]
-        np.add.at(flow, (src_routers[:, None].repeat(len(dst_routers), 1),
-                         np.broadcast_to(dst_routers, (len(src_idx),
-                                                       len(dst_routers)))),
-                  msgs[:, None])
-
-    inc = _path_incidence(grid)
-    loads = flow.reshape(-1) @ inc
-    hops = float(flow.reshape(-1) @ _pair_hops(grid))
-    return NocTraffic(router_loads=np.asarray(loads), total_hops=hops,
-                      inject_per_core=inject)
+    m = np.concatenate([np.asarray(a, np.float64).reshape(-1)
+                        for a in msgs_out_per_core])[None, :]
+    b = _route(part, mapping, m, profile)
+    return NocTraffic(router_loads=b.router_loads[0],
+                      total_hops=float(b.total_hops[0]),
+                      inject_per_core=b.inject_per_core[0])

@@ -37,6 +37,12 @@ T = chip_smoke.STEPS
 #: (rows, fan-in, fan-out) of the S5 stack's fc layers
 FC_SHAPES = [(T, a, b) for a, b in zip(chip_smoke.S5_SIZES,
                                        chip_smoke.S5_SIZES[1:])]
+#: (steps, fan-in, width) of the distinct layers of the Nemotron 3 Nano
+#: share at published widths (Mamba in / state, MoE up / down, attention
+#: qkv / scores / values, out-projections)
+NEMOTRON_SHAPES = [(64, 2688, 772), (64, 772, 256), (64, 256, 2688),
+                   (64, 2688, 18688), (64, 18688, 2688), (64, 2688, 512),
+                   (64, 512, 16384), (64, 16384, 256)]
 
 
 def _conv_shapes():
@@ -101,6 +107,7 @@ def _pair_shapes(m, k, n, occ):
     *[(m, k, n, True) for m, k, n in FC_SHAPES],
     (*FC_SHAPES[1], False),
     *[(m, k, n, True) for m, k, n in _conv_shapes()],
+    *[(m, k, n, True) for m, k, n in NEMOTRON_SHAPES],
 ])
 def test_event_matmul_pair_compiles(one_chip, m, k, n, occ):
     _assert_mosaic(_compile(one_chip, event_matmul_pair,
