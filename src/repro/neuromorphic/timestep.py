@@ -303,7 +303,8 @@ class LayerPricing:
     msgs_in: np.ndarray        # (T,) float64
     csum_macs: np.ndarray      # (T, n_neurons + 1) float64
     csum_fetches: np.ndarray   # (T, n_neurons + 1)
-    csum_acts: np.ndarray      # (T, n_neurons + 1) of the profile's acts map
+    csum_acts: np.ndarray      # (T, n_neurons + 1) of the profile's acts map;
+                               # on a synchronous profile a read-only view
     csum_msgs: np.ndarray      # (T, n_neurons + 1)
     n_neurons: int
     sparse: bool
@@ -333,11 +334,37 @@ class PricingCache:
 
 
 def _neuron_csum(per_neuron: np.ndarray) -> np.ndarray:
-    """(T, n) -> (T, n+1) cumulative sum with a leading zero column; paired
-    with :func:`_seg` it is the batched analog of :func:`_segment_sums`."""
-    a = np.asarray(per_neuron, np.float64)
-    return np.concatenate([np.zeros((a.shape[0], 1)),
-                           np.cumsum(a, axis=1)], axis=1)
+    """(T, n) -> (T, n+1) float64 cumulative sum with a leading zero column;
+    paired with :func:`_seg` it is the batched analog of
+    :func:`_segment_sums`.  One allocation: the map is cast into it (a
+    float32 -> float64 cast is exact) and summed there in place, which on
+    wide rows is faster than a float64 ``cumsum`` read straight from the
+    float32 map."""
+    a = np.asarray(per_neuron)
+    out = np.empty((a.shape[0], a.shape[1] + 1))
+    out[:, 0] = 0.0
+    out[:, 1:] = a
+    np.cumsum(out[:, 1:], axis=1, out=out[:, 1:])
+    return out
+
+
+def _row_constant_csum(per_neuron: np.ndarray) -> np.ndarray | None:
+    """:func:`_neuron_csum` in closed form, row ``t`` being
+    ``v[t] * arange(n + 1)``, for a map that is a zero-stride view of one
+    integral value ``v[t]`` per row (the fc fetch map); None for any other.
+    Every partial sum is then an integer below 2**53, so the product is the
+    sequential sum's exact bits."""
+    a = np.asarray(per_neuron)
+    n = a.shape[1]
+    if n == 0 or a.strides[1] != 0:
+        return None
+    v = a[:, 0].astype(np.float64)
+    if not (np.all(v == np.floor(v))
+            and np.abs(v).max(initial=0.0) * n < 2.0 ** 53):
+        return None
+    out = np.multiply.outer(v, np.arange(n + 1, dtype=np.float64))
+    out[:, 0] = 0.0             # the sum's +0.0, where v[t] is negative
+    return out
 
 
 def precompute_pricing(net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
@@ -358,18 +385,31 @@ def precompute_pricing(net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
         net = sparsity_profile.apply(net)
     outputs, all_counters = precomputed or net.run_batch(xs, compute=compute)
     layers = []
+    closed = 0
     with tracing.span("price.cumsum"):
         for l, counters in enumerate(all_counters):
-            acts_map = (counters.acts_evented if not profile.synchronous
-                        else np.ones_like(counters.macs))
+            T, n = counters.macs.shape
+            if profile.synchronous:     # every neuron updates every step
+                csum_acts = np.broadcast_to(
+                    np.arange(n + 1, dtype=np.float64), (T, n + 1))
+                closed += 1
+            else:
+                csum_acts = _neuron_csum(counters.acts_evented)
+            csum_fetches = _row_constant_csum(counters.fetches_dense)
+            if csum_fetches is None:
+                csum_fetches = _neuron_csum(counters.fetches_dense)
+            else:
+                closed += 1
             layers.append(LayerPricing(
                 msgs_in=np.asarray(counters.msgs_in, np.float64),
                 csum_macs=_neuron_csum(counters.macs),
-                csum_fetches=_neuron_csum(counters.fetches_dense),
-                csum_acts=_neuron_csum(acts_map),
+                csum_fetches=csum_fetches,
+                csum_acts=csum_acts,
                 csum_msgs=_neuron_csum(counters.msgs_out),
                 n_neurons=net.layers[l].n_neurons,
                 sparse=_layer_format(net.layers[l], profile)))
+        tracing.count("csum_closed", closed)
+        tracing.count("csum_summed", 4 * len(layers) - closed)
     return PricingCache(outputs=outputs, T=int(xs.shape[0]), layers=layers)
 
 

@@ -191,6 +191,21 @@ def test_simulate_is_bit_identical_with_tracing_and_names_every_span(case):
         == len(net.layers)
 
 
+def test_price_cumsum_counts_the_maps_in_closed_form_and_summed():
+    # an s5.sim-style request: an fc SSM stack on the event backend, on a
+    # synchronous chip, so each layer's fetch and activity maps are closed
+    sizes = [32, 48, 48, 16]
+    net = fc_network(sizes, seed=0, neuron_model="ssm")
+    xs = make_inputs(sizes[0], 0.1, 8, seed=3)
+    _, spans = _traced(lambda: simulate(net, xs, loihi2_like(),
+                                        compute="event"))
+    (cumsum,) = [s for s in spans if s.name == "price.cumsum"]
+    closed, summed = (cumsum.counts["csum_closed"],
+                      cumsum.counts["csum_summed"])
+    assert closed + summed == 4 * len(net.layers)
+    assert closed == summed == 2 * len(net.layers)
+
+
 def test_device_search_is_identical_with_tracing_and_names_every_span():
     net = fc_network([24, 40, 16], seed=0, neuron_model="relu")
     prof = loihi2_like()
