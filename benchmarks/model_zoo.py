@@ -65,11 +65,11 @@ def _backend_parity(compiled, prof, xs) -> dict:
 
 
 def _pricing_parity(compiled, prof, xs) -> dict:
-    """numpy/vmap/device population backends price identically."""
+    """numpy/device population backends price identically."""
     p0 = minimal_partition(compiled.net, prof)
     cands = [(p0, ordered_mapping(p0, prof)), (p0, strided_mapping(p0, prof))]
     rows = {}
-    for backend in ("numpy", "vmap", "device"):
+    for backend in ("numpy", "device"):
         t0 = time.perf_counter()
         reps = simulate_population(compiled.net, xs, prof, cands,
                                    backend=backend)
@@ -79,8 +79,7 @@ def _pricing_parity(compiled, prof, xs) -> dict:
     base = rows["numpy"]["time_per_step"]
     rows["max_rel_err"] = max(
         abs(a - b) / abs(b)
-        for k in ("vmap", "device")
-        for a, b in zip(rows[k]["time_per_step"], base))
+        for a, b in zip(rows["device"]["time_per_step"], base))
     return rows
 
 
@@ -103,7 +102,7 @@ def _one_arch(arch_id: str, *, steps: int, generations: int,
                         "n_points": len(pts)}
     row["backend_parity"] = _backend_parity(compiled, prof, xs)
 
-    ev = SimEvaluator(compiled.net, xs, prof, population_backend="vmap")
+    ev = SimEvaluator(compiled.net, xs, prof, population_backend="device")
     t0 = time.perf_counter()
     res = evolutionary_search(compiled.net, prof, ev,
                               population_size=pop, generations=generations,

@@ -13,15 +13,13 @@ Writes ``BENCH_search.json`` at the repo root: best time/energy per
 optimizer at iso-evaluations, the evolutionary front's knee point, plus two
 throughput microbenchmarks:
 
-* ``pricing`` — evals/s of the three population-pricing backends
-  (``numpy`` / ``vmap`` / ``device``) repricing one fixed population;
-* ``generation`` — FULL-generation throughput of the three search engines
-  at population >= 256: the host loop pricing through numpy, the host loop
-  pricing through the jitted vmap backend ("vmap-pricing-only" — mutation,
-  selection and survival still per-offspring Python), and the
+* ``pricing`` — evals/s of the two population-pricing backends
+  (``numpy`` / ``device``) repricing one fixed population;
+* ``generation`` — FULL-generation throughput of the two search engines
+  at population >= 256: the host loop pricing through numpy, and the
   device-resident engine whose whole generation step is one jitted program
   (``repro.core.search``, ``engine="device"``).  The headline number is
-  ``device_speedup_vs_vmap``.
+  ``device_speedup_vs_numpy``.
 
 Plus the multi-device section (``sharded``): the island-model
 ``engine="sharded"`` vs the single-device engine at EQUAL total
@@ -66,8 +64,8 @@ def _pricing_throughput(net, xs, prof, *, pop: int, repeats: int,
     pairs = [decode(c) for c in seeded_population(net, prof, size=pop,
                                                   rng=rng)]
     out = {"pop_size": len(pairs)}
-    backends = ("numpy", "vmap", "device")
-    # warm every path (vmap/device: jit compile; numpy: flow-matrix caches)
+    backends = ("numpy", "device")
+    # warm every path (device: jit compile; numpy: routing caches)
     for backend in backends:
         simulate_population(net, xs, prof, pairs, cache=cache,
                             backend=backend)
@@ -78,8 +76,6 @@ def _pricing_throughput(net, xs, prof, *, pop: int, repeats: int,
                                 backend=backend)
         dt = time.perf_counter() - t0
         out[f"{backend}_evals_per_sec"] = repeats * len(pairs) / max(dt, 1e-9)
-    out["vmap_speedup"] = (out["vmap_evals_per_sec"]
-                           / out["numpy_evals_per_sec"])
     out["device_speedup"] = (out["device_evals_per_sec"]
                              / out["numpy_evals_per_sec"])
     return out
@@ -88,11 +84,10 @@ def _pricing_throughput(net, xs, prof, *, pop: int, repeats: int,
 def _generation_throughput(net, xs, prof, *, pop: int, gens: int,
                            seed: int = 0,
                            device_pops: tuple = ()) -> dict:
-    """Full-generation throughput of the three search engines on one seeded
-    population: numpy engine + numpy pricing, numpy engine + vmap pricing
-    (the "vmap-pricing-only" arm — the generation loop is still
-    per-offspring host Python), and the device-resident engine.  Each arm
-    runs once to warm jit/flow caches, then is timed over a ``gens``-
+    """Full-generation throughput of the two search engines on one seeded
+    population: numpy engine + numpy pricing, and the device-resident
+    engine.  Each arm runs once to warm jit/routing caches, then is timed
+    over a ``gens``-
     generation search; throughput counts generations (and offspring
     pricings) per second.
 
@@ -106,8 +101,7 @@ def _generation_throughput(net, xs, prof, *, pop: int, gens: int,
     seeds = seeded_population(net, prof, size=pop, rng=rng)
     out = {"pop_size": len(seeds), "generations": gens}
     arms = (("numpy", "numpy", "numpy"),
-            ("vmap", "numpy", "vmap"),
-            ("device", "device", "vmap"))
+            ("device", "device", "device"))
     for name, engine, backend in arms:
         def run_once(n_gens):
             ev = SimEvaluator(net, xs, prof, cache=shared.cache,
@@ -115,15 +109,13 @@ def _generation_throughput(net, xs, prof, *, pop: int, gens: int,
             return evolutionary_search(
                 net, prof, ev, population_size=len(seeds), generations=n_gens,
                 seed=seed, seed_candidates=list(seeds), engine=engine)
-        run_once(1)                       # warm jit / flow caches
+        run_once(1)                       # warm jit / routing caches
         t0 = time.perf_counter()
         res = run_once(gens)
         dt = time.perf_counter() - t0
         out[f"{name}_gens_per_sec"] = gens / max(dt, 1e-9)
         out[f"{name}_evals_per_sec"] = res.n_evals / max(dt, 1e-9)
         out[f"{name}_best_time"] = res.report.time_per_step
-    out["device_speedup_vs_vmap"] = (out["device_gens_per_sec"]
-                                     / out["vmap_gens_per_sec"])
     out["device_speedup_vs_numpy"] = (out["device_gens_per_sec"]
                                       / out["numpy_gens_per_sec"])
     for big in device_pops:
@@ -131,7 +123,7 @@ def _generation_throughput(net, xs, prof, *, pop: int, gens: int,
                                       rng=np.random.default_rng(seed + 1))
         def run_big(n_gens):
             ev = SimEvaluator(net, xs, prof, cache=shared.cache,
-                              population_backend="vmap")
+                              population_backend="device")
             return evolutionary_search(
                 net, prof, ev, population_size=len(big_seeds),
                 generations=n_gens, seed=seed,
@@ -167,7 +159,7 @@ def _sharded_throughput(net, xs, prof, *, pop: int, gens: int,
     for name, engine, kw in arms:
         def run_once(n_gens, _engine=engine, _kw=kw):
             ev = SimEvaluator(net, xs, prof, cache=shared.cache,
-                              population_backend="vmap")
+                              population_backend="device")
             return evolutionary_search(
                 net, prof, ev, population_size=len(seeds),
                 generations=n_gens, seed=seed, seed_candidates=list(seeds),
@@ -331,21 +323,18 @@ def report(res: dict) -> str:
                 f"energy={r['knee_energy']:.1f})")
         pr = r.get("pricing")
         if pr:
-            dev = (f", device {pr['device_evals_per_sec']:8.1f} evals/s"
-                   if "device_evals_per_sec" in pr else "")
             lines.append(
                 f"  {'':8s} population pricing @ pop={pr['pop_size']}: "
                 f"numpy {pr['numpy_evals_per_sec']:8.1f} evals/s, "
-                f"vmap {pr['vmap_evals_per_sec']:8.1f} evals/s{dev} "
-                f"-> {pr['vmap_speedup']:.2f}x")
+                f"device {pr['device_evals_per_sec']:8.1f} evals/s "
+                f"-> {pr['device_speedup']:.2f}x")
         ge = r.get("generation")
         if ge:
             lines.append(
                 f"  {'':8s} full generations @ pop={ge['pop_size']}: "
                 f"numpy {ge['numpy_gens_per_sec']:6.2f} gen/s, "
-                f"vmap {ge['vmap_gens_per_sec']:6.2f} gen/s, "
                 f"device {ge['device_gens_per_sec']:6.2f} gen/s "
-                f"-> device {ge['device_speedup_vs_vmap']:.2f}x vs vmap")
+                f"-> device {ge['device_speedup_vs_numpy']:.2f}x vs numpy")
             for key in ge:
                 if key.startswith("device_pop") and key.endswith(
                         "_gens_per_sec"):

@@ -95,7 +95,7 @@ from repro.neuromorphic.timestep import (device_pricer, precompute_pricing,
 log = logging.getLogger("repro.resilience")
 
 #: bottleneck-stage ids, in the (first-max-wins) vote order shared with
-#: ``SimReport.bottleneck_stage`` / ``_VmapPricer`` votes
+#: ``SimReport.bottleneck_stage`` / ``DevicePopulationPricer`` votes
 STAGE_ID = {"memory": 0, "compute": 1, "traffic": 2, "barrier": 3}
 
 

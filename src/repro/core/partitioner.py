@@ -65,15 +65,15 @@ class SimEvaluator:
     auditing the cache path at small scale.
 
     ``population_backend`` selects how :meth:`evaluate_population` prices a
-    generation — one of the three population backends of
+    generation — one of the population backends of
     :func:`~repro.neuromorphic.timestep.simulate_population`: ``"numpy"``
     (stacked gathers + per-candidate NumPy math, bit-identical to
-    ``simulate`` — the reference), ``"vmap"`` (one jitted ``jax.vmap`` over
-    the padded population axis, host-built batch structures,
-    float64-roundoff-identical, several times the pricing throughput at
-    population >= 64), or ``"device"`` (the genome rows are the program
-    input and structure construction runs on device too — same parity as
-    vmap; see ``BENCH_search.json`` and ``docs/simulator.md``).
+    ``simulate`` — the reference), ``"device"`` (the genome rows are the
+    input of one jitted ``jax.vmap`` over the padded population axis that
+    builds every per-core structure and prices on device,
+    float64-roundoff-identical; see ``docs/simulator.md``), or
+    ``"sharded"`` (the device program with the population axis sharded
+    over a device mesh).
 
     The evaluator is also the pricing-cache and evaluation-ledger host for
     the device-resident search (``evolutionary_search(...,
@@ -84,7 +84,7 @@ class SimEvaluator:
     injected one) propagates by default, so a run that exits cleanly ran
     on the backend it asked for.  ``fallback=True`` opts in to graceful
     degradation (``docs/robustness.md``): a failure is retried per
-    ``retry`` and then demoted down the ``device -> vmap -> numpy`` chain
+    ``retry`` and then demoted down the ``device -> numpy`` chain
     (sticky; logged; recorded in :attr:`demotions`), and the device and
     sharded search engines driven by this evaluator demote to their host
     mirrors the same way.  The backends agree at float64 roundoff, so a
@@ -155,8 +155,8 @@ class SimEvaluator:
 
     def evaluate_population(self, candidates) -> list[SimReport]:
         """Price a list of (partition, mapping) pairs; one stacked gather
-        per layer (or one jitted program — ``population_backend="vmap"`` /
-        ``"device"``) when the pricing cache is live.  Backend failures
+        per layer (or one jitted program — ``population_backend="device"``
+        / ``"sharded"``) when the pricing cache is live.  Backend failures
         retry, then demote down the fallback chain (see the class
         docstring); scripted :class:`FaultPlan` faults inject here."""
         cands = list(candidates)

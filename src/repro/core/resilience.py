@@ -17,16 +17,16 @@ primitives fix that, shared by both generation engines
   the ``meta.json`` replace can never pair new arrays with stale meta.
   Resume is bit-identical to the uninterrupted run (``docs/robustness.md``).
 * :class:`FallbackChain` — graceful pricing degradation
-  ``device -> vmap -> numpy`` with structured retry/backoff.  The three
-  population backends agree at float64 roundoff, so a mid-run demotion
-  changes the trajectory by at most rtol=1e-9 against a numpy-only run.
+  ``device -> numpy`` with structured retry/backoff.  The two population
+  backends agree at float64 roundoff, so a mid-run demotion changes the
+  trajectory by at most rtol=1e-9 against a numpy-only run.
 * :func:`quarantine_rows` — non-finite screening: NaN/inf (time, energy)
   rows get sentinel-worst ``+inf`` fitness, so they lose tournaments and
   survival deterministically; finite rows keep their exact values and
   relative order.
 * :class:`FaultPlan` — the deterministic fault-injection harness: scripted
-  exception throws per backend site (``"device"`` / ``"vmap"`` /
-  ``"numpy"`` for pricing, ``"device"`` / ``"sharded"`` for the jitted
+  exception throws per backend site (``"device"`` / ``"numpy"`` for
+  pricing, ``"device"`` / ``"sharded"`` for the jitted
   generation engines), scripted NaN pricing rows, and a simulated kill
   after generation ``g`` (:class:`SimulatedCrash`), raised only after the
   generation's checkpoint landed — the crash model the resume tests replay.
@@ -82,8 +82,7 @@ class FaultPlan:
     """Deterministic, scripted fault schedule for one search run.
 
     ``fail`` maps a *site* (a pricing-backend name — ``"device"`` /
-    ``"vmap"`` / ``"numpy"`` — or the device engine's step, also
-    ``"device"``) to a count: the first that-many :meth:`check` calls at
+    ``"numpy"`` — or the device engine's step, also ``"device"``) to a count: the first that-many :meth:`check` calls at
     the site raise :class:`InjectedFault` (use :data:`ALWAYS` for a
     permanent outage).  ``nan_rows`` maps a global pricing-call index
     (0-based, counted by :meth:`corrupt` over successful population
@@ -164,7 +163,7 @@ class Demotion:
 
 
 class FallbackChain:
-    """Sticky pricing-backend degradation ``device -> vmap -> numpy``.
+    """Sticky pricing-backend degradation ``device -> numpy``.
 
     :meth:`run` calls ``attempt(backend)`` with the current backend,
     retrying per the :class:`RetryPolicy`; when a backend's retries are
@@ -174,7 +173,7 @@ class FallbackChain:
     link; its failure propagates.  :class:`SimulatedCrash` is never
     absorbed (it models ``kill -9``)."""
 
-    CHAIN = ("device", "vmap", "numpy")
+    CHAIN = ("device", "numpy")
 
     def __init__(self, backend: str = "numpy",
                  retry: RetryPolicy | None = None):

@@ -12,8 +12,8 @@ engine's pricing split: one functional run + per-layer counter cumsums
 (:func:`repro.neuromorphic.timestep.precompute_pricing`) price an entire
 generation with one stacked gather per layer
 (:func:`repro.neuromorphic.timestep.simulate_population`) — or, with the
-``vmap`` backend, as one jitted ``jax.vmap`` over the padded population axis
-(:func:`repro.neuromorphic.timestep.price_population_vmap`).
+``device`` backend, as one jitted ``jax.vmap`` over the padded population
+axis (:func:`repro.neuromorphic.timestep.price_population_device`).
 
 The genome representation is **tensor-first**: a generation lives in a
 :class:`Population` — a ``(K, n_layers)`` core-count matrix plus a
@@ -684,7 +684,7 @@ def evolutionary_search(
     when it exposes ``evaluate_population`` (:class:`SimEvaluator` does)
     each generation is priced with the stacked population path of
     :func:`repro.neuromorphic.timestep.simulate_population` (or its jitted
-    ``vmap`` backend).  ``max_evaluations`` caps total candidate pricings
+    ``device`` backend).  ``max_evaluations`` caps total candidate pricings
     (iso-evaluation comparisons against the greedy walk); ``greedy`` feeds
     the accepted §VI-B moves into the initial population; ``pareto_eps``
     sets the epsilon-dominance grid of the (time, energy) archive returned

@@ -22,21 +22,17 @@ from repro.neuromorphic.network import (BatchCounters, Router, SimLayer,
                                         SimNetwork, fc_network, make_inputs,
                                         programmed_fc_network)
 from repro.neuromorphic.partition import Partition, minimal_partition
-from repro.neuromorphic.noc import (Mapping, flow_matrix_population,
-                                    flow_structures_rows, incidence_tables,
-                                    ordered_mapping, random_mapping,
-                                    route_batch,
-                                    router_incidence_population,
+from repro.neuromorphic.noc import (Mapping, flow_structures_rows,
+                                    incidence_tables, ordered_mapping,
+                                    random_mapping, route_batch,
                                     strided_mapping)
 from repro.neuromorphic.timestep import (DevicePopulationPricer,
-                                         LayerStageTimes,
-                                         PopulationBatch, PricingCache,
-                                         SimReport, build_population_batch,
-                                         device_pricer, layer_stage_times,
+                                         LayerStageTimes, PricingCache,
+                                         SimReport, device_pricer,
+                                         layer_stage_times,
                                          precompute_pricing,
                                          price_candidate,
-                                         price_population_device,
-                                         price_population_vmap, simulate,
+                                         price_population_device, simulate,
                                          simulate_population)
 
 __all__ = [
@@ -49,13 +45,10 @@ __all__ = [
     "make_inputs",
     "programmed_fc_network",
     "Partition", "minimal_partition",
-    "Mapping", "flow_matrix_population", "flow_structures_rows",
-    "incidence_tables", "ordered_mapping", "random_mapping",
-    "route_batch", "router_incidence_population", "strided_mapping",
-    "DevicePopulationPricer", "LayerStageTimes", "PopulationBatch",
-    "PricingCache", "SimReport",
-    "build_population_batch", "device_pricer", "layer_stage_times",
-    "precompute_pricing",
-    "price_candidate", "price_population_device", "price_population_vmap",
+    "Mapping", "flow_structures_rows", "incidence_tables",
+    "ordered_mapping", "random_mapping", "route_batch", "strided_mapping",
+    "DevicePopulationPricer", "LayerStageTimes", "PricingCache",
+    "SimReport", "device_pricer", "layer_stage_times",
+    "precompute_pricing", "price_candidate", "price_population_device",
     "simulate", "simulate_population",
 ]

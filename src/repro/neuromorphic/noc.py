@@ -19,18 +19,17 @@ router, its next layer's cores counted by destination router, and the
 X-then-Y paths of all (source, destination) pairs folded into two small
 coverage tables per layer, a row table over the grid's columns and a
 column table over its rows, so memory grows with the grid and not with its
-square.  The population pricers (:func:`flow_matrix_population`,
-:func:`router_incidence_population`, :func:`flow_structures_rows`) keep
-per-pair path incidence tables of ``R**2`` rows, which the grids of today's
-searches hold.
+square.  A population is routed on the device by
+:func:`flow_structures_rows`, one candidate at a time inside the jitted
+population pricer; it folds per-layer destination counts through the
+``(R, R, R)`` path-incidence tensor of :func:`incidence_tables`, which the
+grids of today's searches hold.
 """
 
 from __future__ import annotations
 
-import collections
 import dataclasses
 import functools
-import threading
 
 import numpy as np
 
@@ -164,206 +163,13 @@ class NocTrafficBatch:
         return self.router_loads.max(axis=1, initial=0.0)
 
 
-@functools.lru_cache(maxsize=64)
-def _flow_matrix(cores: tuple[int, ...], phys: tuple[int, ...],
-                 grid: tuple[int, int],
-                 n_cores_phys: int) -> tuple[np.ndarray, np.ndarray]:
-    """Per-(partition, mapping) routing structure, independent of the
-    per-step message counts.
-
-    Returns ``(P, dup)`` where ``P`` is an (n_logical, R*R) matrix such that
-    ``msgs @ P`` is the flattened router->router flow tensor (entry
-    ``[core, src*R+dst]`` counts how many destination cores of the next
-    layer sit on router ``dst``), and ``dup`` is the per-core unicast
-    duplication factor (number of destination cores)."""
-    rows, cols = grid
-    R = rows * cols
-    cpr = max(1, n_cores_phys // R)
-    routers = np.asarray([p // cpr for p in phys])
-    n_logical = int(sum(cores))
-    P = np.zeros((n_logical, R * R), np.float64)
-    dup = np.zeros(n_logical, np.float64)
-    offsets = np.concatenate([[0], np.cumsum(cores)]).astype(int)
-    n_layers = len(cores)
-    for l in range(n_layers):
-        src_idx = np.arange(offsets[l], offsets[l + 1])
-        if l + 1 < n_layers:
-            dst_routers = routers[offsets[l + 1]:offsets[l + 2]]
-        else:
-            dst_routers = np.asarray([0])        # chip I/O port
-        dup[src_idx] = len(dst_routers)
-        for g in src_idx:
-            np.add.at(P[g], routers[g] * R + dst_routers, 1.0)
-    return P, dup
-
-
-# ---------------------------------------------------------------- population
-
-#: Bytes-keyed LRU of per-candidate ``(P, dup)`` routing structures.  The
-#: evolutionary search carries survivors between generations, so most of a
-#: generation's genomes were already routed; keying by the raw genome bytes
-#: (core counts + expressed physical slots) lets :func:`flow_matrix_population`
-#: skip their scatter entirely.  Guarded by a lock so population pricing can
-#: be driven from worker threads.
-_FLOW_CACHE: collections.OrderedDict = collections.OrderedDict()
-_FLOW_CACHE_MAX = 4096
-_FLOW_CACHE_LOCK = threading.Lock()
-
-
-def flow_cache_clear() -> None:
-    """Drop the population flow-matrix cache (tests / memory pressure)."""
-    with _FLOW_CACHE_LOCK:
-        _FLOW_CACHE.clear()
-
-
-def flow_matrix_population(cores_rows, phys_rows, grid: tuple[int, int],
-                           n_cores_phys: int, n_pad: int, *,
-                           cache: bool = True,
-                           ) -> tuple[np.ndarray, np.ndarray]:
-    """Batched :func:`_flow_matrix`: all candidates' routing structures in
-    one shot.
-
-    Args:
-      cores_rows: per-candidate layer core counts — sequence of K int
-        sequences, each of length ``n_layers``.
-      phys_rows: per-candidate *expressed* physical slot assignments —
-        sequence of K int sequences, row k of length ``sum(cores_rows[k])``.
-      n_pad: logical-core padding width (>= every candidate's total cores).
-
-    Returns ``(P_stack, dup_stack)``: a ``(K, n_pad, R*R)`` float32 tensor
-    whose k-th leading slice equals ``_flow_matrix``'s ``P`` for candidate k
-    (zero rows beyond its ``n_logical``), and the ``(K, n_pad)`` float64
-    duplication factors (zero on padding).  Cache misses are built with a
-    single ``np.add.at`` scatter over the stacked tensor; hits are pasted
-    from the bytes-keyed LRU.  Entries are exact small-integer counts, so
-    float32 storage is lossless.  ``cache=False`` skips storing the raw
-    matrices (:func:`router_incidence_population` only ever re-reads the
-    much smaller folded form, so caching the dense ``P`` for it would
-    waste most of the LRU's memory on dead entries).
-    """
-    rows, cols = grid
-    R = rows * cols
-    cpr = max(1, n_cores_phys // R)
-    cores_rows = [np.asarray(c, np.int32) for c in cores_rows]
-    phys_rows = [np.asarray(p, np.int32) for p in phys_rows]
-    K = len(cores_rows)
-    if K != len(phys_rows):
-        raise ValueError("cores_rows and phys_rows disagree on K")
-
-    P_stack = np.zeros((K, n_pad, R * R), np.float32)
-    dup_stack = np.zeros((K, n_pad), np.float64)
-    keys = []
-    misses = []
-    with _FLOW_CACHE_LOCK:
-        for k, (cores, phys) in enumerate(zip(cores_rows, phys_rows)):
-            key = (grid, n_cores_phys, cores.tobytes(), phys.tobytes())
-            keys.append(key)
-            hit = _FLOW_CACHE.get(key)
-            if hit is not None:
-                _FLOW_CACHE.move_to_end(key)
-                P_k, dup_k = hit
-                P_stack[k, :P_k.shape[0]] = P_k
-                dup_stack[k, :dup_k.shape[0]] = dup_k
-            else:
-                misses.append(k)
-
-    if misses:
-        k_idx, core_idx, flat_idx = [], [], []
-        for k in misses:
-            cores, phys = cores_rows[k], phys_rows[k]
-            routers = phys // cpr
-            off = np.concatenate([[0], np.cumsum(cores)]).astype(int)
-            n_layers = len(cores)
-            for l in range(n_layers):
-                src = np.arange(off[l], off[l + 1])
-                if l + 1 < n_layers:
-                    dst_r = routers[off[l + 1]:off[l + 2]]
-                else:
-                    dst_r = np.zeros(1, np.int32)     # chip I/O port
-                dup_stack[k, off[l]:off[l + 1]] = len(dst_r)
-                k_idx.append(np.full(src.size * dst_r.size, k, np.intp))
-                core_idx.append(np.repeat(src, dst_r.size))
-                flat_idx.append((routers[src][:, None] * R
-                                 + dst_r[None, :]).reshape(-1))
-        np.add.at(P_stack,
-                  (np.concatenate(k_idx), np.concatenate(core_idx),
-                   np.concatenate(flat_idx)), 1.0)
-        if cache:
-            with _FLOW_CACHE_LOCK:
-                for k in misses:
-                    n_logical = int(cores_rows[k].sum())
-                    _FLOW_CACHE[keys[k]] = (P_stack[k, :n_logical].copy(),
-                                            dup_stack[k, :n_logical].copy())
-                    _FLOW_CACHE.move_to_end(keys[k])
-                while len(_FLOW_CACHE) > _FLOW_CACHE_MAX:
-                    _FLOW_CACHE.popitem(last=False)
-    return P_stack, dup_stack
-
-
-def router_incidence_population(cores_rows, phys_rows, grid: tuple[int, int],
-                                n_cores_phys: int, n_pad: int,
-                                ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Path-incidence-folded :func:`flow_matrix_population`.
-
-    Returns ``(PL, ph, dup)``: ``PL`` is ``(K, n_pad, R)`` float64 with
-    ``PL = P @ path_incidence`` (so a candidate's per-router loads are
-    ``msgs @ PL`` — the ``(T, R*R)`` flow tensor never materializes), ``ph``
-    is ``(K, n_pad)`` float64 with ``ph = P @ pair_hops`` (total hops are
-    ``msgs @ ph``), and ``dup`` the duplication factors.  Because every
-    entry of ``P``, the incidence, and the hop vector is a small exact
-    integer, the fold is exact: ``msgs @ (P @ inc) == (msgs @ P) @ inc``
-    bit-for-bit in float64.  Folded rows are LRU-cached by genome bytes
-    alongside the raw flow matrices.
-    """
-    rows, cols = grid
-    R = rows * cols
-    cores_rows = [np.asarray(c, np.int32) for c in cores_rows]
-    phys_rows = [np.asarray(p, np.int32) for p in phys_rows]
-    K = len(cores_rows)
-    PL = np.zeros((K, n_pad, R), np.float64)
-    ph = np.zeros((K, n_pad), np.float64)
-    dup = np.zeros((K, n_pad), np.float64)
-    keys, misses = [], []
-    with _FLOW_CACHE_LOCK:
-        for k, (cores, phys) in enumerate(zip(cores_rows, phys_rows)):
-            key = ("fold", grid, n_cores_phys, cores.tobytes(),
-                   phys.tobytes())
-            keys.append(key)
-            hit = _FLOW_CACHE.get(key)
-            if hit is not None:
-                _FLOW_CACHE.move_to_end(key)
-                PL_k, ph_k, dup_k = hit
-                n = PL_k.shape[0]
-                PL[k, :n], ph[k, :n], dup[k, :n] = PL_k, ph_k, dup_k
-            else:
-                misses.append(k)
-    if misses:
-        P_m, dup_m = flow_matrix_population(
-            [cores_rows[k] for k in misses], [phys_rows[k] for k in misses],
-            grid, n_cores_phys, n_pad, cache=False)
-        inc = _path_incidence(grid).astype(np.float64)
-        hops_vec = _pair_hops(grid).astype(np.float64)
-        PL_m = P_m.astype(np.float64) @ inc           # (M, n_pad, R)
-        ph_m = P_m.astype(np.float64) @ hops_vec      # (M, n_pad)
-        with _FLOW_CACHE_LOCK:
-            for j, k in enumerate(misses):
-                n = int(cores_rows[k].sum())
-                PL[k], ph[k], dup[k] = PL_m[j], ph_m[j], dup_m[j]
-                _FLOW_CACHE[keys[k]] = (PL_m[j, :n].copy(),
-                                        ph_m[j, :n].copy(),
-                                        dup_m[j, :n].copy())
-                _FLOW_CACHE.move_to_end(keys[k])
-            while len(_FLOW_CACHE) > _FLOW_CACHE_MAX:
-                _FLOW_CACHE.popitem(last=False)
-    return PL, ph, dup
-
-
 @functools.lru_cache(maxsize=16)
 def incidence_tables(grid: tuple[int, int]) -> tuple[np.ndarray, np.ndarray]:
     """Per-grid routing geometry in the shapes the device path consumes:
     ``inc3[src, dst, node]`` is the (R, R, R) path-incidence tensor and
     ``hops2[src, dst]`` the (R, R) Manhattan hop matrix — float64 reshaped
-    views of the lru-cached flat tables of the population pricers."""
+    copies of the lru-cached flat tables of :func:`_path_incidence` and
+    :func:`_pair_hops`."""
     rows, cols = grid
     R = rows * cols
     inc3 = _path_incidence(grid).astype(np.float64).reshape(R, R, R)
@@ -374,21 +180,21 @@ def incidence_tables(grid: tuple[int, int]) -> tuple[np.ndarray, np.ndarray]:
 def flow_structures_rows(lid, router, alive, n_layers: int, inc3, hops2):
     """ONE candidate's routing structures, built entirely on device.
 
-    The array-native analog of :func:`router_incidence_population` for a
-    genome that never leaves the accelerator: given the candidate's padded
-    per-core layer ids ``lid`` (Ncap,), router ids ``router`` (Ncap,), and
-    float live-core mask ``alive`` (Ncap,), returns the same
-    ``(PL, ph, dup)`` triple — per-core router-load incidence ``msgs @ PL``,
-    hop factors ``msgs @ ph``, unicast duplication — as ``(Ncap, R)`` /
-    ``(Ncap,)`` / ``(Ncap,)`` jnp arrays.  Pure jnp and shape-static, so it
-    traces into the jitted population pricer and the device generation step
-    (no host round-trip, no byte-keyed cache).
+    The population routing, for a genome that never leaves the
+    accelerator: given the candidate's padded per-core layer ids ``lid``
+    (Ncap,), router ids ``router`` (Ncap,), and float live-core mask
+    ``alive`` (Ncap,), returns the ``(PL, ph, dup)`` triple — per-core
+    router-load incidence ``msgs @ PL``, hop factors ``msgs @ ph``, unicast
+    duplication — as ``(Ncap, R)`` / ``(Ncap,)`` / ``(Ncap,)`` jnp arrays.
+    Pure jnp and shape-static, so it traces into the jitted population
+    pricer and the device generation step (no host round-trip).
 
     Every intermediate is an exact small-integer count in float64 (layer
     destination-router counts folded through the integer incidence/hop
-    tables), so the results are bit-identical to the host-built structures
-    of :func:`router_incidence_population` — asserted by
-    ``tests/test_device_search.py``.
+    tables), so the results are bit-identical to a per-core
+    ``(n_logical, R*R)`` flow matrix folded through the path incidence and
+    hop tables — asserted against a frozen copy of that routing by
+    ``tests/test_noc_routing.py``.
 
     ``n_layers`` is static; ``inc3``/``hops2`` come from
     :func:`incidence_tables` (callers pass them so they become jit

@@ -54,12 +54,13 @@ work across many candidates:
 
 Population pricing itself comes in three backends (``backend=`` on
 :func:`simulate_population`): ``"numpy"`` — the bit-exact reference above;
-``"vmap"`` — one jitted ``jax.vmap`` over the padded population axis with
-host-assembled batch structures (:func:`price_population_vmap`); and
-``"device"`` — the genome arrays are the program input and batch-structure
-construction itself runs on device (:class:`DevicePopulationPricer`,
+``"device"`` — the genome arrays are the program input and one jitted
+``jax.vmap`` over the padded population axis derives every per-core
+structure and prices it on device (:class:`DevicePopulationPricer`,
 :func:`price_population_device`), which is what lets the evolutionary
-search's ``engine="device"`` generation loop stay accelerator-resident.
+search's ``engine="device"`` generation loop stay accelerator-resident;
+and ``"sharded"`` — the same program with the population axis sharded
+over a device mesh (:func:`price_population_sharded`).
 """
 
 from __future__ import annotations
@@ -73,14 +74,13 @@ from repro.core.metrics import LoadStats, WorkloadMetrics
 from repro.neuromorphic.network import BatchCounters, CounterMaps, SimNetwork
 from repro.neuromorphic.noc import (Mapping, NocTraffic, flow_structures_rows,
                                     incidence_tables, ordered_mapping,
-                                    route_batch, route_step,
-                                    router_incidence_population)
+                                    route_batch, route_step)
 from repro.neuromorphic.partition import (Partition, max_cores_for_layer,
                                           minimal_partition)
 from repro.neuromorphic.platform import ChipProfile
 
 # jax is a hard dependency of the functional engine (repro.neuromorphic.
-# network) already; the vmap population backend additionally needs x64
+# network) already; the device population backend additionally needs x64
 # scoping for float64 parity with the NumPy pricing path.
 import jax
 import jax.numpy as jnp
@@ -314,23 +314,16 @@ class LayerPricing:
 class PricingCache:
     """Everything :func:`price_candidate` needs that does not depend on the
     candidate: the functional outputs plus per-layer :class:`LayerPricing`.
-    ``vmap_pricer`` lazily holds the compiled population pricer for the
-    ``backend="vmap"`` path (one per cache — a cache is bound to one
-    (net, xs, profile) workload)."""
+    A cache is bound to one (net, xs, profile) workload."""
 
     outputs: np.ndarray
     T: int
     layers: list[LayerPricing]
-    vmap_pricer: object = dataclasses.field(default=None, repr=False,
-                                            compare=False)
-    #: lazily-built :class:`DevicePopulationPricer` for the ``device``
-    #: backend / the device-resident search engine (one per cache)
+    #: lazily-built :class:`DevicePopulationPricer` for the ``device`` and
+    #: ``sharded`` backends and the device-resident search engines (one
+    #: per cache)
     device_pricer_obj: object = dataclasses.field(default=None, repr=False,
                                                   compare=False)
-    #: per-partition padded index rows, keyed by the cores tuple (see
-    #: :func:`build_population_batch`)
-    row_cache: dict = dataclasses.field(default_factory=dict, repr=False,
-                                        compare=False)
 
 
 def _neuron_csum(per_neuron: np.ndarray) -> np.ndarray:
@@ -480,18 +473,15 @@ def simulate_population(net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
       and the same float op order runs on the gathered segments (asserted
       by ``tests/test_search.py``).  The reference the other two are
       checked against.
-    * ``backend="vmap"`` — one jitted ``jax.vmap`` over the padded
-      population axis (:func:`price_population_vmap`); the padded batch
-      structures are still assembled on host.  Agrees with the NumPy path
-      within float64 roundoff.
     * ``backend="device"`` — the genome rows themselves are the program
       input: candidates are encoded to stacked ``(K, n_layers)`` /
       ``(K, n_slots)`` arrays and everything downstream — segment
       boundaries, NoC flow structures, pricing — runs inside one jitted
-      program (:func:`price_population_device`).  Same float64-roundoff
-      parity as ``vmap``; this is the pricer the device-resident search
-      engine (``repro.core.search``, ``engine="device"``) keeps entirely
-      on the accelerator.
+      ``jax.vmap`` over the population axis
+      (:func:`price_population_device`).  Agrees with the NumPy path
+      within float64 roundoff; this is the pricer the device-resident
+      search engine (``repro.core.search``, ``engine="device"``) keeps
+      entirely on the accelerator.
     * ``backend="sharded"`` — the device path with the K axis sharded over
       a 1-D ``("island",)`` device mesh (:func:`price_population_sharded`;
       every visible device prices its own block of rows).  Per-row parity
@@ -523,8 +513,6 @@ def simulate_population(net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
     cache = cache or precompute_pricing(net, xs, profile,
                                         precomputed=precomputed,
                                         compute=compute)
-    if backend == "vmap":
-        return price_population_vmap(net, profile, cache, cands)
     if backend == "device":
         cores, perm = _pairs_to_rows(cands, len(cache.layers),
                                      profile.n_cores)
@@ -719,11 +707,20 @@ def layer_stage_times(net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
     return out
 
 
-# --------------------------------------------------------------- vmap backend
+# ------------------------------------------------------------- device backend
 #
-# The array-native population pricer: every candidate's (T, cores) stage
-# reductions and NoC matmuls run as ONE jitted ``jax.vmap`` over the padded
-# population axis.  Padding/masking contract:
+# The population pricer: every candidate's (T, cores) stage reductions and
+# NoC matmuls run as ONE jitted ``jax.vmap`` over the population axis, and
+# the program input is the raw genome arrays — (K, n_layers) core counts +
+# (K, n_slots) slot permutations.  Everything else is derived on device:
+# per-core layer ids and cumsum gather indices from an integer decode of the
+# core-count rows, and the NoC (PL, ph, dup) structures from a pure-jnp
+# scatter/fold (:func:`repro.neuromorphic.noc.flow_structures_rows`).
+# Because the decode is shape-static it traces into larger jitted programs
+# — the device-resident evolutionary search keeps survivor genomes on the
+# accelerator across generations and re-prices them without any host sync.
+#
+# Padding/masking contract:
 #
 # * logical cores are padded to a fixed width ``Ncap`` (the workload's
 #   maximum feasible total cores, capped at ``profile.n_cores``) so the
@@ -731,36 +728,21 @@ def layer_stage_times(net: SimNetwork, xs: np.ndarray, profile: ChipProfile,
 # * a padded core has ``seg_lo == seg_hi == 0`` (its cumsum gather is an
 #   empty segment -> exact 0 counters), ``mask == 0`` (its broadcast
 #   ``msgs_in`` and fixed core overhead are zeroed before any max/sum), and
-#   all-zero flow-matrix rows (it injects nothing into the NoC);
+#   all-zero ``PL`` rows (it injects nothing into the NoC);
 # * per-layer cost constants are folded into per-layer coefficient vectors in
 #   float64 Python — the same constant folding as the NumPy path — and
 #   gathered per core through the layer-id vector.
+#
+# Boundary parity: ``Partition.boundaries`` is ``np.linspace(0, n, c+1)
+# .astype(int)`` = ``int(i * (n/c))`` with the endpoint pinned to ``n``;
+# the decode reproduces exactly that float64 arithmetic, so the gathered
+# cumsum indices are identical to the NumPy path's.
 #
 # Arithmetic runs in float64 (``jax.enable_x64(True)`` scoped to this
 # path), with the same elementwise formulas and reduction semantics as the
 # NumPy path; XLA may reassociate/fuse (FMA), so results agree to float64
 # roundoff rather than bit-for-bit — the parity suite asserts
 # ``rtol=1e-9`` (``tests/test_population_pricing.py``).
-
-
-@dataclasses.dataclass
-class PopulationBatch:
-    """Padded, stacked pricing inputs for one candidate population (the
-    array-native genome view consumed by the jitted pricer).  ``PL``/``ph``
-    carry the path-incidence-folded routing structures of
-    :func:`repro.neuromorphic.noc.router_incidence_population`, so the NoC
-    term is two tiny (T, cores) matmuls per candidate instead of a dense
-    (T, R*R) flow-tensor build."""
-
-    mask: np.ndarray       # (K, Ncap) float64; 1.0 on live cores
-    lid: np.ndarray        # (K, Ncap) int32 layer id per core (0 on padding)
-    seg_lo: np.ndarray     # (K, Ncap) int32 into the concatenated cumsums
-    seg_hi: np.ndarray     # (K, Ncap) int32
-    neurons: np.ndarray    # (K, Ncap) float64 neurons per core
-    PL: np.ndarray         # (K, Ncap, R) float64 router-load incidence
-    ph: np.ndarray         # (K, Ncap) float64 per-core hop factors
-    dup: np.ndarray        # (K, Ncap) float64 unicast duplication factors
-    n_logical: np.ndarray  # (K,) int
 
 
 def population_pad_width(net: SimNetwork, profile: ChipProfile) -> int:
@@ -771,71 +753,76 @@ def population_pad_width(net: SimNetwork, profile: ChipProfile) -> int:
     return min(cap, profile.n_cores)
 
 
-#: Per-partition index rows (seg_lo/seg_hi/lid/neurons) are mapping- and
-#: population-independent; survivors carried between generations reuse them.
-_ROW_CACHE_MAX = 8192
+def _assemble_reports(out, n_logical, cache: PricingCache,
+                      w_density: float) -> list[SimReport]:
+    """Host-side :class:`SimReport` assembly shared by the device and
+    sharded population backends: ``out`` is the pricer's host dict with a
+    leading population axis, ``n_logical`` the (K,) live-core counts."""
+    T = cache.T
+    outputs = cache.outputs
+    stage_names = ("memory", "compute", "traffic", "barrier")
+    reports = []
+    for k in range(len(n_logical)):
+        n = int(n_logical[k])
+        votes = out["votes"][k]
+
+        def _stats(total, mx, n_act):
+            total, mx, n_act = float(total), float(mx), int(n_act)
+            mean = total / max(n_act, 1)
+            return LoadStats(total=total, max=mx, mean=mean,
+                             imbalance=(mx / mean) if mean > 0 else 1.0,
+                             n_units=int(n), n_active=n_act)
+
+        link_mean = float(out["max_link_load"][k])
+        total_msgs = float(out["total_msgs"][k])
+        metrics = WorkloadMetrics(
+            synops=_stats(out["syn_total"][k], out["syn_max"][k],
+                          out["syn_nact"][k]),
+            acts=_stats(out["act_total"][k], out["act_max"][k],
+                        out["act_nact"][k]),
+            traffic=LoadStats(
+                total=link_mean, max=link_mean,
+                mean=link_mean if link_mean > 0 else 0.0, imbalance=1.0,
+                n_units=1, n_active=int(link_mean > 0)),
+            msgs_total=total_msgs / T,
+            weight_density=w_density,
+            act_density=(total_msgs
+                         / max(float(out["total_neuron_steps"][k]), 1.0)),
+        )
+        reports.append(SimReport(
+            time_per_step=float(out["time_per_step"][k]),
+            energy_per_step=float(out["energy_per_step"][k]),
+            times=out["times"][k], energies=out["energies"][k],
+            metrics=metrics,
+            max_synops=float(out["max_synops"][k]),
+            max_acts=float(out["max_acts"][k]),
+            max_link_load=link_mean,
+            n_cores_active=n,
+            outputs=outputs,
+            per_core_synops=out["mean_synops"][k, :n],
+            per_core_acts=out["mean_acts"][k, :n],
+            per_core_msgs_out=out["mean_msgs"][k, :n],
+            bottleneck_stage=stage_names[int(np.argmax(votes))],
+        ))
+    return reports
 
 
-def build_population_batch(cache: PricingCache, net: SimNetwork,
-                           profile: ChipProfile, pairs,
-                           n_pad: int | None = None) -> PopulationBatch:
-    """(Partition, Mapping) pairs -> padded stacked arrays.  Boundaries come
-    from the same :meth:`Partition.boundaries` the scalar path uses, so the
-    gathered segments index identical cumsum entries."""
-    pairs = list(pairs)
-    K = len(pairs)
-    n_pad = n_pad or population_pad_width(net, profile)
-    lo = np.zeros((K, n_pad), np.int32)
-    hi = np.zeros((K, n_pad), np.int32)
-    lid = np.zeros((K, n_pad), np.int32)
-    mask = np.zeros((K, n_pad), np.float64)
-    neurons = np.zeros((K, n_pad), np.float64)
-    n_logical = np.zeros(K, int)
-    # offsets of each layer's (n_neurons + 1)-wide cumsum block in the
-    # concatenated cumsum arrays
-    widths = [lp.n_neurons + 1 for lp in cache.layers]
-    block_off = np.concatenate([[0], np.cumsum(widths)]).astype(np.int32)
-    rows = cache.row_cache
-    for k, (part, _) in enumerate(pairs):
-        if part.total_cores > n_pad:
-            raise ValueError(
-                f"candidate uses {part.total_cores} cores > pad width {n_pad}")
-        hit = rows.get(part.cores)
-        if hit is None:
-            lo_k, hi_k, lid_k, neu_k = [], [], [], []
-            for l, lp in enumerate(cache.layers):
-                b = part.boundaries(l, lp.n_neurons).astype(np.int32)
-                lo_k.append(block_off[l] + b[:-1])
-                hi_k.append(block_off[l] + b[1:])
-                lid_k.append(np.full(len(b) - 1, l, np.int32))
-                neu_k.append(np.diff(b).astype(np.float64))
-            hit = (np.concatenate(lo_k), np.concatenate(hi_k),
-                   np.concatenate(lid_k), np.concatenate(neu_k))
-            if len(rows) >= _ROW_CACHE_MAX:
-                rows.clear()
-            rows[part.cores] = hit
-        n = hit[0].shape[0]
-        lo[k, :n], hi[k, :n], lid[k, :n], neurons[k, :n] = hit
-        mask[k, :n] = 1.0
-        n_logical[k] = n
-    PL, ph, dup = router_incidence_population(
-        [p.cores for p, _ in pairs],
-        [m.phys[:p.total_cores] for p, m in pairs],
-        profile.grid, profile.n_cores, n_pad)
-    return PopulationBatch(mask=mask, lid=lid, seg_lo=lo, seg_hi=hi,
-                           neurons=neurons, PL=PL, ph=ph, dup=dup,
-                           n_logical=n_logical)
-
-
-class _VmapPricer:
-    """Compiled population pricer bound to one :class:`PricingCache`.
+class DevicePopulationPricer:
+    """Genome-array population pricer bound to one :class:`PricingCache`.
 
     Holds the device-resident workload constants (concatenated counter
-    cumsums, per-layer coefficient vectors, NoC path incidence — reusing the
-    per-grid lru caches of :mod:`repro.neuromorphic.noc`) and the jitted
-    vmapped pricing function.  Shapes are fixed by ``Ncap``; the population
+    cumsums, per-layer coefficient vectors, NoC path incidence) and the
+    jitted vmapped pricer.  ``price(cores, perm)`` accepts already-on-device
+    (or host) stacked genome rows and returns the pricing dict;
+    :meth:`price_row` is the traced single-genome program for composition
+    into larger jitted functions (the device search engine vmaps it inside
+    its generation step).  Shapes are fixed by ``Ncap``; the population
     axis K is the vmap axis, so a new population size only re-traces, it
-    does not rebuild the constants.
+    does not rebuild the constants.  Besides the report fields, a row's
+    output carries the mutation-policy fields the search consumes on
+    device: ``stage`` (argmax of the bottleneck votes,
+    memory/compute/traffic/barrier order) and ``hot_mem``/``hot_act``
+    (layer of the max-loaded core).
     """
 
     def __init__(self, net: SimNetwork, profile: ChipProfile,
@@ -862,6 +849,10 @@ class _VmapPricer:
             ncost.append(p.neuron_cost(model))
             sparse_f.append(1.0 if lp.sparse else 0.0)
             e_act_c.append(p.e_act * (p.neuron_cost(model) / p.c_act))
+        self.n_pad = population_pad_width(net, profile)
+        rows, cols = profile.grid
+        self.cpr = max(1, profile.n_cores // (rows * cols))
+        widths = np.asarray([lp.n_neurons + 1 for lp in cache.layers])
         with jax.enable_x64(True):
             self.csums = tuple(
                 jnp.asarray(np.concatenate([getattr(lp, f) for lp in
@@ -873,11 +864,51 @@ class _VmapPricer:
             self.coefs = tuple(jnp.asarray(np.asarray(v, np.float64))
                                for v in (mem_msg, mem_syn, ncost, sparse_f,
                                          e_act_c))
-        self._fn = jax.jit(jax.vmap(
-            self._price_one, in_axes=(0, 0, 0, 0, 0, 0, 0, 0)))
+            self.block_off = jnp.asarray(
+                np.concatenate([[0], np.cumsum(widths)])[:-1]
+                .astype(np.int32))
+            self.n_neurons_vec = jnp.asarray(
+                np.asarray([lp.n_neurons for lp in cache.layers], np.int32))
+            inc3, hops2 = incidence_tables(profile.grid)
+            self.inc3 = jnp.asarray(inc3)
+            self.hops2 = jnp.asarray(hops2)
+        self._fn = jax.jit(jax.vmap(self.price_row))
 
-    # ---- the per-candidate pricing program (vmapped over axis 0) --------
+    def structures_row(self, cores_row, perm_row):
+        """(n_layers,) cores + (n_slots,) perm -> the padded per-core
+        pricing structures ``(mask, lid, seg_lo, seg_hi, neurons, PL, ph,
+        dup)``, each with a leading ``Ncap`` axis, all on device."""
+        L, ncap = self.n_layers, self.n_pad
+        csum = jnp.cumsum(cores_row)                        # (L,)
+        total = csum[-1]
+        j = jnp.arange(ncap)
+        alive = j < total
+        lid = jnp.minimum(jnp.searchsorted(csum, j, side="right"),
+                          L - 1).astype(jnp.int32)
+        within = j - (csum - cores_row)[lid]                # index in layer
+        n_l = self.n_neurons_vec[lid]
+        c_l = cores_row[lid]
+        # same float64 arithmetic as np.linspace(0, n, c+1).astype(int)
+        step = n_l.astype(jnp.float64) / c_l.astype(jnp.float64)
+        lo_loc = (within.astype(jnp.float64) * step).astype(jnp.int32)
+        hi_loc = jnp.where(within + 1 == c_l, n_l,
+                           ((within + 1).astype(jnp.float64) * step)
+                           .astype(jnp.int32))
+        lid = jnp.where(alive, lid, 0)
+        seg_lo = jnp.where(alive, self.block_off[lid] + lo_loc, 0) \
+            .astype(jnp.int32)
+        seg_hi = jnp.where(alive, self.block_off[lid] + hi_loc, 0) \
+            .astype(jnp.int32)
+        neurons = jnp.where(alive, hi_loc - lo_loc, 0).astype(jnp.float64)
+        mask = alive.astype(jnp.float64)
+        router = jnp.where(alive, perm_row[:ncap] // self.cpr, 0) \
+            .astype(jnp.int32)
+        PL, ph, dup = flow_structures_rows(lid, router, mask, L,
+                                           self.inc3, self.hops2)
+        return mask, lid, seg_lo, seg_hi, neurons, PL, ph, dup
+
     def _price_one(self, mask, lid, seg_lo, seg_hi, neurons, PL, ph, dup):
+        """One candidate's pricing from its padded per-core structures."""
         p = self.profile
         T = self.T
         csum_macs, csum_fetches, csum_acts, csum_msgs = self.csums
@@ -951,188 +982,11 @@ class _VmapPricer:
             total_neuron_steps=T * neurons.sum(),
         )
 
-    def price(self, batch: PopulationBatch) -> dict:
-        """Run the jitted pricer; returns host NumPy arrays with a leading
-        population axis."""
-        with jax.enable_x64(True):
-            out = self._fn(jnp.asarray(batch.mask), jnp.asarray(batch.lid),
-                           jnp.asarray(batch.seg_lo),
-                           jnp.asarray(batch.seg_hi),
-                           jnp.asarray(batch.neurons), jnp.asarray(batch.PL),
-                           jnp.asarray(batch.ph), jnp.asarray(batch.dup))
-        return jax.device_get(out)
-
-
-def price_population_vmap(net: SimNetwork, profile: ChipProfile,
-                          cache: PricingCache, pairs) -> list[SimReport]:
-    """Price a candidate population with the jitted ``jax.vmap`` pipeline.
-
-    Functionally equivalent to the NumPy :func:`simulate_population` path
-    (same cumsums, same boundaries, same cost formulas) within float64
-    roundoff; ~an order of magnitude higher pricing throughput at
-    population >= 64 because the per-candidate Python/NumPy dispatch
-    collapses into one compiled program (``BENCH_search.json``).
-    """
-    pairs = list(pairs)
-    if not pairs:
-        return []
-    if cache.vmap_pricer is None:
-        cache.vmap_pricer = _VmapPricer(net, profile, cache)
-    pricer: _VmapPricer = cache.vmap_pricer
-    batch = build_population_batch(cache, net, profile, pairs)
-    out = pricer.price(batch)
-    return _assemble_reports(out, batch.n_logical, cache,
-                             pricer.weight_density)
-
-
-def _assemble_reports(out, n_logical, cache: PricingCache,
-                      w_density: float) -> list[SimReport]:
-    """Host-side :class:`SimReport` assembly shared by the vmap and device
-    population backends: ``out`` is the pricer's host dict with a leading
-    population axis, ``n_logical`` the (K,) live-core counts."""
-    T = cache.T
-    outputs = cache.outputs
-    stage_names = ("memory", "compute", "traffic", "barrier")
-    reports = []
-    for k in range(len(n_logical)):
-        n = int(n_logical[k])
-        votes = out["votes"][k]
-
-        def _stats(total, mx, n_act):
-            total, mx, n_act = float(total), float(mx), int(n_act)
-            mean = total / max(n_act, 1)
-            return LoadStats(total=total, max=mx, mean=mean,
-                             imbalance=(mx / mean) if mean > 0 else 1.0,
-                             n_units=int(n), n_active=n_act)
-
-        link_mean = float(out["max_link_load"][k])
-        total_msgs = float(out["total_msgs"][k])
-        metrics = WorkloadMetrics(
-            synops=_stats(out["syn_total"][k], out["syn_max"][k],
-                          out["syn_nact"][k]),
-            acts=_stats(out["act_total"][k], out["act_max"][k],
-                        out["act_nact"][k]),
-            traffic=LoadStats(
-                total=link_mean, max=link_mean,
-                mean=link_mean if link_mean > 0 else 0.0, imbalance=1.0,
-                n_units=1, n_active=int(link_mean > 0)),
-            msgs_total=total_msgs / T,
-            weight_density=w_density,
-            act_density=(total_msgs
-                         / max(float(out["total_neuron_steps"][k]), 1.0)),
-        )
-        reports.append(SimReport(
-            time_per_step=float(out["time_per_step"][k]),
-            energy_per_step=float(out["energy_per_step"][k]),
-            times=out["times"][k], energies=out["energies"][k],
-            metrics=metrics,
-            max_synops=float(out["max_synops"][k]),
-            max_acts=float(out["max_acts"][k]),
-            max_link_load=link_mean,
-            n_cores_active=n,
-            outputs=outputs,
-            per_core_synops=out["mean_synops"][k, :n],
-            per_core_acts=out["mean_acts"][k, :n],
-            per_core_msgs_out=out["mean_msgs"][k, :n],
-            bottleneck_stage=stage_names[int(np.argmax(votes))],
-        ))
-    return reports
-
-
-# ------------------------------------------------------------- device backend
-#
-# The device-resident population pricer: where the vmap backend still
-# assembles its padded batch structures (segment boundaries, flow matrices)
-# on host per generation, this path takes the raw genome arrays —
-# (K, n_layers) core counts + (K, n_slots) slot permutations — as the
-# program input and derives EVERYTHING on device: per-core layer ids and
-# cumsum gather indices from an integer decode of the core-count rows, and
-# the NoC (PL, ph, dup) structures from a pure-jnp scatter/fold
-# (:func:`repro.neuromorphic.noc.flow_structures_rows`).  Because the
-# decode is shape-static it traces into larger jitted programs — the
-# device-resident evolutionary search keeps survivor genomes on the
-# accelerator across generations and re-prices them without any host sync.
-#
-# Boundary parity: ``Partition.boundaries`` is ``np.linspace(0, n, c+1)
-# .astype(int)`` = ``int(i * (n/c))`` with the endpoint pinned to ``n``;
-# the decode reproduces exactly that float64 arithmetic, so the gathered
-# cumsum indices are identical to the host paths' and pricing agrees with
-# the vmap backend bit-for-bit (and with NumPy to float64 roundoff).
-
-
-class DevicePopulationPricer:
-    """Genome-array population pricer bound to one :class:`PricingCache`.
-
-    ``price(cores, perm)`` accepts already-on-device (or host) stacked
-    genome rows and returns the pricing dict; :meth:`price_row` is the
-    traced single-genome program for composition into larger jitted
-    functions (the device search engine vmaps it inside its generation
-    step).  Beyond the :class:`_VmapPricer` outputs it adds the
-    mutation-policy fields the search consumes on device: ``stage``
-    (argmax of the bottleneck votes, memory/compute/traffic/barrier order)
-    and ``hot_mem``/``hot_act`` (layer of the max-loaded core).
-    """
-
-    def __init__(self, net: SimNetwork, profile: ChipProfile,
-                 cache: PricingCache):
-        if cache.vmap_pricer is None:
-            cache.vmap_pricer = _VmapPricer(net, profile, cache)
-        self.base: _VmapPricer = cache.vmap_pricer
-        self.profile = profile
-        self.n_layers = len(cache.layers)
-        self.n_pad = population_pad_width(net, profile)
-        rows, cols = profile.grid
-        self.cpr = max(1, profile.n_cores // (rows * cols))
-        widths = np.asarray([lp.n_neurons + 1 for lp in cache.layers])
-        with jax.enable_x64(True):
-            self.block_off = jnp.asarray(
-                np.concatenate([[0], np.cumsum(widths)])[:-1]
-                .astype(np.int32))
-            self.n_neurons_vec = jnp.asarray(
-                np.asarray([lp.n_neurons for lp in cache.layers], np.int32))
-            inc3, hops2 = incidence_tables(profile.grid)
-            self.inc3 = jnp.asarray(inc3)
-            self.hops2 = jnp.asarray(hops2)
-        self._fn = jax.jit(jax.vmap(self.price_row))
-
-    def structures_row(self, cores_row, perm_row):
-        """(n_layers,) cores + (n_slots,) perm -> the padded per-core
-        pricing structures of :class:`PopulationBatch`, all on device."""
-        L, ncap = self.n_layers, self.n_pad
-        csum = jnp.cumsum(cores_row)                        # (L,)
-        total = csum[-1]
-        j = jnp.arange(ncap)
-        alive = j < total
-        lid = jnp.minimum(jnp.searchsorted(csum, j, side="right"),
-                          L - 1).astype(jnp.int32)
-        within = j - (csum - cores_row)[lid]                # index in layer
-        n_l = self.n_neurons_vec[lid]
-        c_l = cores_row[lid]
-        # same float64 arithmetic as np.linspace(0, n, c+1).astype(int)
-        step = n_l.astype(jnp.float64) / c_l.astype(jnp.float64)
-        lo_loc = (within.astype(jnp.float64) * step).astype(jnp.int32)
-        hi_loc = jnp.where(within + 1 == c_l, n_l,
-                           ((within + 1).astype(jnp.float64) * step)
-                           .astype(jnp.int32))
-        lid = jnp.where(alive, lid, 0)
-        seg_lo = jnp.where(alive, self.block_off[lid] + lo_loc, 0) \
-            .astype(jnp.int32)
-        seg_hi = jnp.where(alive, self.block_off[lid] + hi_loc, 0) \
-            .astype(jnp.int32)
-        neurons = jnp.where(alive, hi_loc - lo_loc, 0).astype(jnp.float64)
-        mask = alive.astype(jnp.float64)
-        router = jnp.where(alive, perm_row[:ncap] // self.cpr, 0) \
-            .astype(jnp.int32)
-        PL, ph, dup = flow_structures_rows(lid, router, mask, L,
-                                           self.inc3, self.hops2)
-        return mask, lid, seg_lo, seg_hi, neurons, PL, ph, dup
-
     def price_row(self, cores_row, perm_row):
         """The traced per-genome pricing program (vmap/jit composable)."""
         mask, lid, seg_lo, seg_hi, neurons, PL, ph, dup = \
             self.structures_row(cores_row, perm_row)
-        out = self.base._price_one(mask, lid, seg_lo, seg_hi, neurons,
-                                   PL, ph, dup)
+        out = self._price_one(mask, lid, seg_lo, seg_hi, neurons, PL, ph, dup)
         out["stage"] = jnp.argmax(out["votes"]).astype(jnp.int32)
         out["hot_mem"] = lid[jnp.argmax(out["mean_synops"])]
         out["hot_act"] = lid[jnp.argmax(out["mean_acts"])]
@@ -1200,7 +1054,7 @@ def price_population_device(net: SimNetwork, profile: ChipProfile,
     out = pricer.price(cores, perm)
     n_logical = np.asarray(jax.device_get(cores), np.int64).sum(axis=1)
     return _assemble_reports(out, n_logical, cache,
-                             pricer.base.weight_density)
+                             pricer.weight_density)
 
 
 def price_population_sharded(net: SimNetwork, profile: ChipProfile,
@@ -1257,7 +1111,7 @@ def price_population_sharded(net: SimNetwork, profile: ChipProfile,
         out = {k: v[:K] for k, v in out.items()}
     n_logical = cores_h[:K].astype(np.int64).sum(axis=1)
     return _assemble_reports(out, n_logical, cache,
-                             pricer.base.weight_density)
+                             pricer.weight_density)
 
 
 def _simulate_reference(net: SimNetwork, xs: np.ndarray,

@@ -18,8 +18,8 @@ Two consumption modes:
   profile's densities onto an arbitrary ``SimNetwork`` (msg gates + exact
   weight masks), and ``compile_network(..., act_density=profile)`` injects
   them at model-zoo lowering time.  Because injection only rewrites the
-  *network* (never the pricing math), every pricing backend — numpy / vmap /
-  device population — prices a profiled workload with its usual parity
+  *network* (never the pricing math), every pricing backend — numpy / device
+  / sharded population — prices a profiled workload with its usual parity
   guarantees.
 
 Profiles serialize to a single ``.npz`` (arrays + a JSON header), atomically.

@@ -1,11 +1,7 @@
 """Device-resident evolutionary engine tests (:mod:`repro.core.device_search`).
 
-Three layers of guarantees:
+Two layers of guarantees:
 
-* **structure parity** — the on-device NoC flow structures
-  (:func:`repro.neuromorphic.noc.flow_structures_rows`) are bit-identical
-  to the host-built :func:`router_incidence_population` (integer counts in
-  float64);
 * **decision parity** — selection, mutation, and survival are the same
   array program under ``xp=numpy`` and ``xp=jax.numpy``; given the shared
   PRNG-key draws they must agree EXACTLY (integer genome ops);
@@ -39,8 +35,6 @@ from repro.core.search import (Population, decode, encode,
 from repro.neuromorphic import (loihi2_like, make_inputs, minimal_partition,
                                 programmed_fc_network, random_mapping,
                                 strided_mapping)
-from repro.neuromorphic.noc import (flow_structures_rows, incidence_tables,
-                                    router_incidence_population)
 from repro.neuromorphic.partition import validate_partition
 
 quick = pytest.mark.quick
@@ -78,42 +72,6 @@ def _seed_rows(net, prof, n, seed=0):
     pop = Population.from_candidates(
         seeded_population(net, prof, size=n, rng=rng))
     return pop.cores, pop.perm
-
-
-class TestFlowStructuresDevice:
-    @quick
-    def test_bitwise_matches_host_fold(self):
-        """flow_structures_rows == router_incidence_population, bit for
-        bit, across random genomes (incl. a single-layer genome whose only
-        destination is the I/O router)."""
-        prof = loihi2_like()
-        rng = np.random.default_rng(3)
-        rows, cols = prof.grid
-        cpr = max(1, prof.n_cores // (rows * cols))
-        genomes = [((3, 2, 4), None), ((1,), None), ((2, 2), None)]
-        genomes = [(np.asarray(c, np.int32),
-                    rng.permutation(prof.n_cores)[:sum(c)].astype(np.int32))
-                   for c, _ in genomes]
-        n_pad = 12
-        inc3, hops2 = incidence_tables(prof.grid)
-        for cores, phys in genomes:
-            L = len(cores)
-            PL_h, ph_h, dup_h = router_incidence_population(
-                [cores], [phys], prof.grid, prof.n_cores, n_pad)
-            n = int(cores.sum())
-            lid = np.zeros(n_pad, np.int32)
-            router = np.zeros(n_pad, np.int32)
-            alive = np.zeros(n_pad, np.float64)
-            lid[:n] = np.repeat(np.arange(L), cores)
-            router[:n] = phys // cpr
-            alive[:n] = 1.0
-            with jax.enable_x64(True):
-                PL_d, ph_d, dup_d = flow_structures_rows(
-                    jnp.asarray(lid), jnp.asarray(router), jnp.asarray(alive),
-                    L, jnp.asarray(inc3), jnp.asarray(hops2))
-            assert np.array_equal(np.asarray(PL_d), PL_h[0])
-            assert np.array_equal(np.asarray(ph_d), ph_h[0])
-            assert np.array_equal(np.asarray(dup_d), dup_h[0])
 
 
 class TestDecisionParity:

@@ -7,16 +7,24 @@ against an ``(R*R, R)`` path incidence — and the two must agree bit for bit
 wherever the old tables fit.  On a 48-chip Loihi 2-class mesh (30 x 48
 routers) the old flow matrix of 5,400 cores would take 84 GB; the new
 routing prices it in bounded memory.
+
+The population routing on the device, ``flow_structures_rows``, is held to
+the same frozen flow matrix folded through the frozen path incidence and
+hop tables, bit for bit.
 """
 
 import dataclasses
 import tracemalloc
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.neuromorphic.noc import (Mapping, ordered_mapping, random_mapping,
-                                    route_batch, route_step, strided_mapping)
+from repro.neuromorphic.noc import (Mapping, flow_structures_rows,
+                                    incidence_tables, ordered_mapping,
+                                    random_mapping, route_batch, route_step,
+                                    strided_mapping)
 from repro.neuromorphic.partition import Partition
 from repro.neuromorphic.platform import loihi2_like
 
@@ -50,7 +58,7 @@ def _old_pair_hops(grid):
             + np.abs(cc[:, None] - cc[None, :])).astype(np.float32).reshape(-1)
 
 
-def _old_flow_matrix(cores, phys, grid, n_cores_phys):
+def _old_core_flows(cores, phys, grid, n_cores_phys):
     rows, cols = grid
     R = rows * cols
     cpr = max(1, n_cores_phys // R)
@@ -70,7 +78,7 @@ def _old_flow_matrix(cores, phys, grid, n_cores_phys):
 
 
 def _old_route_batch(part, mapping, msgs, prof):
-    P, dup = _old_flow_matrix(part.cores, mapping.phys, prof.grid,
+    P, dup = _old_core_flows(part.cores, mapping.phys, prof.grid,
                               prof.n_cores)
     m = np.asarray(msgs, np.float64)
     flow = m @ P
@@ -150,6 +158,44 @@ def test_route_step_is_bit_identical_to_the_frozen_routing(grid, n_cores):
             assert np.array_equal(got.router_loads, loads)
             assert got.total_hops == hops
             assert np.array_equal(got.inject_per_core, inject)
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("grid,n_cores", GRIDS)
+def test_flow_structures_rows_are_bit_identical_to_the_frozen_fold(grid,
+                                                                   n_cores):
+    """The device population routing equals the frozen flow matrix folded
+    through the frozen path incidence and hop tables: ``PL = P @ inc``,
+    ``ph = P @ hops``, and the duplication factors, with zero rows on the
+    padded cores."""
+    rows, cols = grid
+    cpr = max(1, n_cores // (rows * cols))
+    inc = _old_path_incidence(grid).astype(np.float64)
+    hops = _old_pair_hops(grid).astype(np.float64)
+    inc3, hops2 = incidence_tables(grid)
+    for seed in (0, 11):                # seed 11 is a single-layer genome
+        _, part, maps, _ = _cases(grid, n_cores, seed)
+        n = part.total_cores
+        n_pad = n + 3
+        L = len(part.cores)
+        lid = np.zeros(n_pad, np.int32)
+        lid[:n] = np.repeat(np.arange(L), part.cores)
+        alive = np.zeros(n_pad, np.float64)
+        alive[:n] = 1.0
+        for mapping in maps:
+            P, dup = _old_core_flows(part.cores, mapping.phys, grid,
+                                      n_cores)
+            router = np.zeros(n_pad, np.int32)
+            router[:n] = np.asarray(mapping.phys) // cpr
+            with jax.enable_x64(True):
+                PL, ph, dup_d = (np.asarray(a) for a in flow_structures_rows(
+                    jnp.asarray(lid), jnp.asarray(router),
+                    jnp.asarray(alive), L, jnp.asarray(inc3),
+                    jnp.asarray(hops2)))
+            assert np.array_equal(PL[:n], P @ inc)
+            assert np.array_equal(ph[:n], P @ hops)
+            assert np.array_equal(dup_d[:n], dup)
+            assert not (PL[n:].any() or ph[n:].any() or dup_d[n:].any())
 
 
 @pytest.mark.quick

@@ -1,12 +1,13 @@
-"""Array-native population-pricing pipeline tests: batched flow-matrix
-construction (:func:`repro.neuromorphic.noc.flow_matrix_population`), the
-padded population batch contract, and the jitted ``jax.vmap`` pricing
-backend (:func:`repro.neuromorphic.timestep.price_population_vmap`).
+"""Population-pricing tests: the device pricer's padding and masking
+contract (:meth:`repro.neuromorphic.timestep.DevicePopulationPricer.structures_row`)
+and the two jitted population backends, ``device``
+(:func:`repro.neuromorphic.timestep.price_population_device`) and
+``sharded`` (:func:`repro.neuromorphic.timestep.price_population_sharded`).
 
 Parity contract (``docs/simulator.md``): the NumPy population path is
-bit-identical to per-candidate ``simulate``; the vmap path runs the same
-float64 formulas under XLA (which may reassociate/fuse), so it is asserted
-to ``rtol=1e-9`` instead.
+bit-identical to per-candidate ``simulate``; the jitted backends run the
+same float64 formulas under XLA (which may reassociate/fuse), so they are
+asserted to ``rtol=1e-9`` instead.
 """
 
 import numpy as np
@@ -24,11 +25,7 @@ from repro.neuromorphic import (Partition, SimLayer, SimNetwork, fc_network,
                                 random_mapping, simulate, simulate_population,
                                 speck_like, strided_mapping)
 from repro.neuromorphic.network import _exact_density_mask
-from repro.neuromorphic.noc import (_flow_matrix, _pair_hops, _path_incidence,
-                                    flow_cache_clear, flow_matrix_population,
-                                    router_incidence_population)
-from repro.neuromorphic.timestep import (build_population_batch,
-                                         population_pad_width,
+from repro.neuromorphic.timestep import (device_pricer, population_pad_width,
                                          precompute_pricing,
                                          price_population_device)
 
@@ -62,80 +59,12 @@ def conv_workload(steps=3):
     return net, make_inputs(net.in_size, 0.4, steps, seed=3)
 
 
-def random_genomes(rng, n_cores_phys, n=8):
-    """Random (cores, phys) genome rows of varying layer counts/sizes."""
-    rows = []
-    for _ in range(n):
-        n_layers = int(rng.integers(2, 6))
-        cores = rng.integers(1, 5, size=n_layers)
-        phys = rng.permutation(n_cores_phys)[:int(cores.sum())]
-        rows.append((tuple(int(c) for c in cores),
-                     tuple(int(p) for p in phys)))
-    return rows
-
-
-class TestFlowMatrixPopulation:
-    @quick
-    def test_matches_per_candidate_flow_matrix(self):
-        prof = loihi2_like()
-        rng = np.random.default_rng(0)
-        rows = random_genomes(rng, prof.n_cores, n=10)
-        n_pad = max(sum(c) for c, _ in rows) + 2
-        flow_cache_clear()
-        P, dup = flow_matrix_population([c for c, _ in rows],
-                                        [p for _, p in rows],
-                                        prof.grid, prof.n_cores, n_pad)
-        for k, (cores, phys) in enumerate(rows):
-            P1, d1 = _flow_matrix(cores, phys, prof.grid, prof.n_cores)
-            n = P1.shape[0]
-            assert np.array_equal(P[k, :n], P1)
-            assert np.array_equal(dup[k, :n], d1)
-            # padding contract: no flow, no duplication beyond n_logical
-            assert not P[k, n:].any()
-            assert not dup[k, n:].any()
-
-    @quick
-    def test_cache_hits_reproduce_scatter(self):
-        prof = loihi2_like()
-        rng = np.random.default_rng(1)
-        rows = random_genomes(rng, prof.n_cores, n=6)
-        n_pad = max(sum(c) for c, _ in rows) + 1
-        flow_cache_clear()
-        first = flow_matrix_population([c for c, _ in rows],
-                                       [p for _, p in rows],
-                                       prof.grid, prof.n_cores, n_pad)
-        again = flow_matrix_population([c for c, _ in rows],
-                                       [p for _, p in rows],
-                                       prof.grid, prof.n_cores, n_pad)
-        assert np.array_equal(first[0], again[0])
-        assert np.array_equal(first[1], again[1])
-
-    @quick
-    def test_router_incidence_fold_is_exact(self):
-        """msgs @ (P @ inc) == (msgs @ P) @ inc: integer counts make the
-        reassociation lossless, so the folded structures must equal the
-        explicit product bit-for-bit."""
-        prof = loihi2_like()
-        rng = np.random.default_rng(2)
-        rows = random_genomes(rng, prof.n_cores, n=6)
-        n_pad = max(sum(c) for c, _ in rows)
-        flow_cache_clear()
-        P, dup = flow_matrix_population([c for c, _ in rows],
-                                        [p for _, p in rows],
-                                        prof.grid, prof.n_cores, n_pad)
-        PL, ph, dup2 = router_incidence_population(
-            [c for c, _ in rows], [p for _, p in rows],
-            prof.grid, prof.n_cores, n_pad)
-        inc = _path_incidence(prof.grid).astype(np.float64)
-        hops = _pair_hops(prof.grid).astype(np.float64)
-        assert np.array_equal(PL, P.astype(np.float64) @ inc)
-        assert np.array_equal(ph, P.astype(np.float64) @ hops)
-        assert np.array_equal(dup, dup2)
-
-
-class TestPopulationBatch:
+class TestStructuresRow:
     @quick
     def test_padding_and_masking_contract(self):
+        import jax
+        import jax.numpy as jnp
+
         net, xs = fc_workload()
         prof = loihi2_like()
         cache = precompute_pricing(net, xs, prof)
@@ -143,21 +72,25 @@ class TestPopulationBatch:
         pairs = [(p0, ordered_mapping(p0, prof)),
                  (p0.split(0).split(1), strided_mapping(p0.split(0).split(1),
                                                         prof))]
-        batch = build_population_batch(cache, net, prof, pairs)
+        pop = Population.from_candidates(
+            [encode(p, m, prof.n_cores) for p, m in pairs])
+        pricer = device_pricer(net, prof, cache)
         n_pad = population_pad_width(net, prof)
-        assert batch.mask.shape == (2, n_pad)
         for k, (part, _) in enumerate(pairs):
+            with jax.enable_x64(True):
+                mask, lid, seg_lo, seg_hi, neurons, PL, ph, dup = (
+                    np.asarray(a) for a in pricer.structures_row(
+                        jnp.asarray(pop.cores[k]), jnp.asarray(pop.perm[k])))
+            assert mask.shape == (n_pad,)
             n = part.total_cores
-            assert batch.n_logical[k] == n
-            assert batch.mask[k, :n].all() and not batch.mask[k, n:].any()
+            assert mask[:n].all() and not mask[n:].any()
             # padded cores gather empty segments: lo == hi == 0
-            assert not batch.seg_lo[k, n:].any()
-            assert not batch.seg_hi[k, n:].any()
-            assert not batch.neurons[k, n:].any()
-            assert not batch.PL[k, n:].any()
+            assert not seg_lo[n:].any()
+            assert not seg_hi[n:].any()
+            assert not neurons[n:].any()
+            assert not PL[n:].any()
             # live cores cover each layer's neuron range exactly
-            assert batch.neurons[k, :n].sum() == \
-                sum(l.n_neurons for l in net.layers)
+            assert neurons[:n].sum() == sum(l.n_neurons for l in net.layers)
 
 
 def _assert_reports_close(a, b):
@@ -183,9 +116,18 @@ def _assert_reports_close(a, b):
         assert np.isclose(sa.imbalance, sb.imbalance, rtol=RTOL), s
 
 
-class TestVmapBackend:
+POPULATION_BACKENDS = ("device", "sharded")
+
+
+@pytest.mark.parametrize("backend", POPULATION_BACKENDS)
+class TestJittedBackends:
+    """The jitted population backends: genome arrays in, the padded
+    per-core structures derived on device, float64-roundoff parity with the
+    NumPy path.  ``sharded`` runs the ``device`` program with the
+    population axis sharded over every visible device."""
+
     @quick
-    def test_fc_parity_with_simulate(self):
+    def test_fc_parity_with_simulate(self, backend):
         net, xs = fc_workload()
         prof = loihi2_like()
         rng = np.random.default_rng(4)
@@ -194,24 +136,24 @@ class TestVmapBackend:
                  (p0.split(0), strided_mapping(p0.split(0), prof)),
                  (p0.split(1).split(1),
                   random_mapping(p0.split(1).split(1), prof, rng))]
-        reports = simulate_population(net, xs, prof, pairs, backend="vmap")
+        reports = simulate_population(net, xs, prof, pairs, backend=backend)
         for (p, m), rp in zip(pairs, reports):
             _assert_reports_close(
                 rp, simulate(net, xs, prof, p, m, engine="batched"))
 
-    def test_conv_parity_with_numpy_backend(self):
+    def test_conv_parity_with_numpy_backend(self, backend):
         net, xs = conv_workload()
         prof = loihi2_like()
         parts = [Partition((1, 1, 1)), Partition((2, 4, 2)),
                  Partition((4, 8, 1))]
         pairs = [(p, strided_mapping(p, prof)) for p in parts]
         r_np = simulate_population(net, xs, prof, pairs)
-        r_vm = simulate_population(net, xs, prof, pairs, backend="vmap")
-        for a, b in zip(r_np, r_vm):
+        r_jit = simulate_population(net, xs, prof, pairs, backend=backend)
+        for a, b in zip(r_np, r_jit):
             _assert_reports_close(a, b)
 
     @quick
-    def test_empty_core_segments(self):
+    def test_empty_core_segments(self, backend):
         net = fc_network([16, 6, 8], weight_density=1.0, seed=19)
         xs = make_inputs(16, 0.8, 3, seed=20)
         prof = loihi2_like()
@@ -221,10 +163,10 @@ class TestVmapBackend:
                                                      prof))]
         for (p, m), rp in zip(pairs, simulate_population(net, xs, prof,
                                                          pairs,
-                                                         backend="vmap")):
+                                                         backend=backend)):
             _assert_reports_close(rp, simulate(net, xs, prof, p, m))
 
-    def test_async_platform_parity(self):
+    def test_async_platform_parity(self, backend):
         """Speck-style chips take the pipeline-latency branch of the jitted
         program (per-layer segment maxima instead of the barrier max)."""
         prof = speck_like()
@@ -245,107 +187,54 @@ class TestVmapBackend:
         pairs = [(p, ordered_mapping(p, prof))]
         for (pp, m), rp in zip(pairs, simulate_population(net, xs, prof,
                                                           pairs,
-                                                          backend="vmap")):
+                                                          backend=backend)):
             _assert_reports_close(rp, simulate(net, xs, prof, pp, m))
 
     @quick
-    def test_large_population_parity_spot_checks(self):
-        """A seeded 32-candidate population vmap-prices to the same results
-        as the NumPy path (spot-checked pointwise over the whole batch)."""
+    def test_large_population_parity_spot_checks(self, backend):
+        """A seeded 32-candidate population prices to the same results as
+        the NumPy path (spot-checked pointwise over the whole batch)."""
         net, xs = fc_workload(steps=2)
         prof = loihi2_like()
         rng = np.random.default_rng(9)
         pairs = [decode(c) for c in seeded_population(net, prof, size=32,
                                                       rng=rng)]
         r_np = simulate_population(net, xs, prof, pairs)
-        r_vm = simulate_population(net, xs, prof, pairs, backend="vmap")
-        assert len(r_np) == len(r_vm) == 32
-        for a, b in zip(r_np, r_vm):
+        r_jit = simulate_population(net, xs, prof, pairs, backend=backend)
+        assert len(r_np) == len(r_jit) == 32
+        for a, b in zip(r_np, r_jit):
             _assert_reports_close(a, b)
 
     @quick
-    def test_evaluator_vmap_backend_counts_and_matches(self):
+    def test_evaluator_counts_and_matches(self, backend):
         net, xs = fc_workload()
         prof = loihi2_like()
         ev_np = SimEvaluator(net, xs, prof)
-        ev_vm = SimEvaluator(net, xs, prof, cache=ev_np.cache,
-                             population_backend="vmap")
+        ev_jit = SimEvaluator(net, xs, prof, cache=ev_np.cache,
+                              population_backend=backend)
         p0 = minimal_partition(net, prof)
         pairs = [(p0, strided_mapping(p0, prof)),
                  (p0.split(2), ordered_mapping(p0.split(2), prof))]
         a = ev_np.evaluate_population(pairs)
-        b = ev_vm.evaluate_population(pairs)
-        assert ev_vm.n_evals == 2
+        b = ev_jit.evaluate_population(pairs)
+        assert ev_jit.n_evals == 2
         for ra, rb in zip(a, b):
             _assert_reports_close(ra, rb)
 
-    @quick
-    def test_unknown_backend_raises(self):
-        net, xs = fc_workload(steps=2)
-        prof = loihi2_like()
-        p0 = minimal_partition(net, prof)
-        with pytest.raises(ValueError, match="backend"):
-            simulate_population(net, xs, prof,
-                                [(p0, ordered_mapping(p0, prof))],
-                                backend="tpu")
+
+@quick
+@pytest.mark.parametrize("backend", ["tpu", "vmap"])
+def test_unknown_backend_raises(backend):
+    net, xs = fc_workload(steps=2)
+    prof = loihi2_like()
+    p0 = minimal_partition(net, prof)
+    with pytest.raises(ValueError, match="backend"):
+        simulate_population(net, xs, prof,
+                            [(p0, ordered_mapping(p0, prof))],
+                            backend=backend)
 
 
 class TestDeviceBackend:
-    """The ``backend="device"`` pricing path: genome arrays in, the padded
-    batch structures derived on device — same float64-roundoff parity
-    contract as the vmap backend, and bit-identical to vmap itself (the
-    two share the jitted pricing program; only structure construction
-    differs, and structures are exact integers)."""
-
-    @quick
-    def test_fc_parity_with_numpy_and_vmap(self):
-        net, xs = fc_workload()
-        prof = loihi2_like()
-        rng = np.random.default_rng(21)
-        pairs = [decode(c) for c in seeded_population(net, prof, size=12,
-                                                      rng=rng)]
-        r_np = simulate_population(net, xs, prof, pairs)
-        r_dev = simulate_population(net, xs, prof, pairs, backend="device")
-        r_vm = simulate_population(net, xs, prof, pairs, backend="vmap")
-        for a, b, c in zip(r_np, r_dev, r_vm):
-            _assert_reports_close(a, b)
-            assert b.time_per_step == c.time_per_step
-            assert b.energy_per_step == c.energy_per_step
-
-    @quick
-    def test_empty_core_segments(self):
-        net = fc_network([16, 6, 8], weight_density=1.0, seed=19)
-        xs = make_inputs(16, 0.8, 3, seed=20)
-        prof = loihi2_like()
-        pairs = [(Partition((7, 2)), strided_mapping(Partition((7, 2)),
-                                                     prof))]
-        for (p, m), rp in zip(pairs, simulate_population(net, xs, prof,
-                                                         pairs,
-                                                         backend="device")):
-            _assert_reports_close(rp, simulate(net, xs, prof, p, m))
-
-    def test_async_platform_parity(self):
-        prof = speck_like()
-        rng = np.random.default_rng(7)
-        layers = []
-        h = w = 8
-        c_prev = 2
-        for i, c in enumerate((4, 4)):
-            wgt = rng.normal(0, 1 / 3.0,
-                             (3, 3, c_prev, c)).astype(np.float32)
-            layers.append(SimLayer(name=f"c{i}", kind="conv", weights=wgt,
-                                   stride=2, in_hw=(h, w), neuron_model="if",
-                                   threshold=1.0))
-            h, w, c_prev = h // 2, w // 2, c
-        net = SimNetwork(layers=layers, in_size=8 * 8 * 2)
-        xs = make_inputs(net.in_size, 0.4, 3, seed=8)
-        p = minimal_partition(net, prof)
-        pairs = [(p, ordered_mapping(p, prof))]
-        for (pp, m), rp in zip(pairs, simulate_population(net, xs, prof,
-                                                          pairs,
-                                                          backend="device")):
-            _assert_reports_close(rp, simulate(net, xs, prof, pp, m))
-
     @quick
     def test_accepts_on_device_genome_arrays(self):
         """price_population_device is the re-pricing entry point for
@@ -353,7 +242,6 @@ class TestDeviceBackend:
         pre-built batch."""
         import jax.numpy as jnp
 
-        from repro.core.search import Population
         net, xs = fc_workload(steps=2)
         prof = loihi2_like()
         cache = precompute_pricing(net, xs, prof)
@@ -367,22 +255,6 @@ class TestDeviceBackend:
                                    [decode(c) for c in cands], cache=cache)
         for a, b in zip(r_np, reports):
             _assert_reports_close(a, b)
-
-    @quick
-    def test_evaluator_device_backend_counts_and_matches(self):
-        net, xs = fc_workload(steps=2)
-        prof = loihi2_like()
-        ev_np = SimEvaluator(net, xs, prof)
-        ev_dev = SimEvaluator(net, xs, prof, cache=ev_np.cache,
-                              population_backend="device")
-        p0 = minimal_partition(net, prof)
-        pairs = [(p0, strided_mapping(p0, prof)),
-                 (p0.split(1), ordered_mapping(p0.split(1), prof))]
-        a = ev_np.evaluate_population(pairs)
-        b = ev_dev.evaluate_population(pairs)
-        assert ev_dev.n_evals == 2
-        for ra, rb in zip(a, b):
-            _assert_reports_close(ra, rb)
 
 
 class TestTensorFirstRoundTrip:
@@ -426,8 +298,8 @@ class TestTensorFirstRoundTrip:
 class TestTrainedProfileParity:
     """Acceptance contract for trained sparsity profiles: a profile-applied
     workload prices bit-identically on the numpy population backend (vs
-    per-candidate ``simulate``) and to float64-roundoff parity on vmap and
-    device — profile injection only rewrites the NETWORK (gates + masked
+    per-candidate ``simulate``) and to float64-roundoff parity on device —
+    profile injection only rewrites the NETWORK (gates + masked
     weights), never the pricing math, so every backend guarantee holds."""
 
     def _profiled_workload(self, steps=3):
@@ -454,17 +326,14 @@ class TestTrainedProfileParity:
                                                       size=8, rng=rng)]
         r_np = simulate_population(net, xs, prof, pairs,
                                    sparsity_profile=profile)
-        r_vm = simulate_population(applied, xs, prof, pairs,
-                                   backend="vmap")
         r_dev = simulate_population(applied, xs, prof, pairs,
                                     backend="device")
-        for (p, m), a, b, c in zip(pairs, r_np, r_vm, r_dev):
+        for (p, m), a, b in zip(pairs, r_np, r_dev):
             ref = simulate(net, xs, prof, p, m, sparsity_profile=profile)
             # numpy population path is BIT-identical to simulate
             assert a.time_per_step == ref.time_per_step
             assert a.energy_per_step == ref.energy_per_step
             _assert_reports_close(a, b)
-            _assert_reports_close(a, c)
 
     @quick
     def test_profile_injection_equals_pre_applied_net(self):

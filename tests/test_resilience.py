@@ -8,7 +8,7 @@ Covers the PR-6 acceptance criteria:
   resume, and the fitness trajectory, eps-Pareto front and knee match the
   uninterrupted run exactly, on both engines and both workload kinds;
 * graceful degradation — injected backend failures demote down the
-  ``device -> vmap -> numpy`` chain (logged), and the completed run matches
+  ``device -> numpy`` chain (logged), and the completed run matches
   a numpy-only run at rtol=1e-9;
 * non-finite quarantine — an injected NaN pricing row never reaches the
   survivors, the eps-archive or ``SearchResult.front``, and the ordering of
@@ -160,21 +160,21 @@ class TestResumeDeterminism:
 
 class TestDegradation:
     def test_chain_demotes_to_numpy_and_matches(self):
-        """Permanent device+vmap outage: the run completes on the numpy
-        backend with two logged demotions, and the trajectory/front match a
+        """Permanent device outage: the run completes on the numpy
+        backend with one logged demotion, and the trajectory/front match a
         numpy-only run at rtol=1e-9 (criterion; the final link is the
         bit-exact reference backend, so equality is in fact exact)."""
         net, xs, prof, ev = get_workload("fc")
         kw = dict(population_size=6, generations=3, seed=3)
         faulty = SimEvaluator(
             net, xs, prof, cache=ev.cache, population_backend="device",
-            fault_plan=FaultPlan(fail={"device": ALWAYS, "vmap": ALWAYS}),
+            fault_plan=FaultPlan(fail={"device": ALWAYS}),
             retry=RetryPolicy(max_retries=1), fallback=True)
         deg = evolutionary_search(net, prof, faulty, **kw)
         ref = evolutionary_search(
             net, prof, SimEvaluator(net, xs, prof, cache=ev.cache), **kw)
         assert [(x.frm, x.to) for x in deg.demotions] == \
-            [("device", "vmap"), ("vmap", "numpy")]
+            [("device", "numpy")]
         assert faulty.active_backend == "numpy"
         np.testing.assert_allclose(
             [g.best_time for g in deg.history],
@@ -186,20 +186,21 @@ class TestDegradation:
 
     @quick
     def test_retry_absorbs_transient_fault(self):
-        """One transient vmap fault, default one-retry policy: no demotion,
-        result identical to the fault-free run on the same backend."""
+        """One transient device fault, default one-retry policy: no
+        demotion, result identical to the fault-free run on the same
+        backend."""
         net, xs, prof, ev = get_workload("fc")
         kw = dict(population_size=5, generations=2, seed=1)
         faulty = SimEvaluator(net, xs, prof, cache=ev.cache,
-                              population_backend="vmap",
-                              fault_plan=FaultPlan(fail={"vmap": 1}),
+                              population_backend="device",
+                              fault_plan=FaultPlan(fail={"device": 1}),
                               fallback=True)
         res = evolutionary_search(net, prof, faulty, **kw)
         clean = evolutionary_search(
             net, prof, SimEvaluator(net, xs, prof, cache=ev.cache,
-                                    population_backend="vmap"), **kw)
+                                    population_backend="device"), **kw)
         assert res.demotions == []
-        assert faulty.active_backend == "vmap"
+        assert faulty.active_backend == "device"
         assert _traj(res) == _traj(clean)
 
     def test_device_engine_demotes_to_mirror(self):
@@ -430,12 +431,12 @@ class TestValidation:
 class TestFaultPlan:
     @quick
     def test_fail_budget_decrements(self):
-        plan = FaultPlan(fail={"vmap": 2})
+        plan = FaultPlan(fail={"device": 2})
         for _ in range(2):
             with pytest.raises(InjectedFault):
-                plan.check("vmap")
-        plan.check("vmap")                         # budget spent: clean
-        plan.check("device")                       # other sites untouched
+                plan.check("device")
+        plan.check("device")                       # budget spent: clean
+        plan.check("numpy")                        # other sites untouched
 
     @quick
     def test_kill_fires_once(self):
