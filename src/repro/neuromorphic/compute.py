@@ -8,9 +8,10 @@ model prices.  This module is the seam that makes that forward pluggable —
 pre-activation GEMM / conv to a :class:`LayerCompute` backend instead of
 hard-coding dense math:
 
-* ``"dense"`` (:class:`DenseCompute`, the default) — the original jnp GEMM /
-  ``conv_general_dilated`` path, moved here verbatim.  It is the bit-exact
-  reference: every counter and every float op order is unchanged, so the
+* ``"dense"`` (:class:`DenseCompute`, the default) — the original host GEMM
+  and ``conv_general_dilated`` math, each conv layer run as one device
+  program.  It is the bit-exact reference: every counter and every float op
+  order is unchanged, so the
   engine-parity suites (``tests/test_sim_equivalence.py``) and the pricing
   caches are oblivious to the refactor.
 * ``"event"`` (:class:`EventCompute`) — event-driven execution in the
@@ -59,6 +60,8 @@ name, by instance, or by the process-wide :data:`DEFAULT_COMPUTE`
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,9 +81,11 @@ DEFAULT_COMPUTE = "dense"
 def _to_device(*arrays, dtype=None) -> tuple:
     """``jnp.asarray`` of each array under the span ``kernel.put``,
     counting the bytes of every host array sent as ``h2d_bytes`` and of
-    every operand already on the device as ``h2d_reused_bytes``."""
+    every operand already on the device as ``h2d_reused_bytes``; a None
+    operand stays None."""
     with tracing.span("kernel.put"):
-        out = tuple(jnp.asarray(a, dtype) for a in arrays)
+        out = tuple(None if a is None else jnp.asarray(a, dtype)
+                    for a in arrays)
         for a, d in zip(arrays, out):
             if isinstance(a, np.ndarray):
                 tracing.count("h2d_bytes", d.nbytes)
@@ -91,9 +96,12 @@ def _to_device(*arrays, dtype=None) -> tuple:
 
 def _to_host(*arrays) -> tuple:
     """``np.asarray`` of each device result under the span
-    ``kernel.fetch``: where the host waits for the device."""
+    ``kernel.fetch``, where the host waits for the device, counting the
+    bytes fetched as ``d2h_bytes``."""
     with tracing.span("kernel.fetch"):
-        return tuple(np.asarray(a) for a in arrays)
+        out = tuple(np.asarray(a) for a in arrays)
+        tracing.count("d2h_bytes", sum(a.nbytes for a in out))
+    return out
 
 
 class LayerCompute:
@@ -159,7 +167,8 @@ class LayerCompute:
 # ------------------------------------------------------------------- dense
 
 class DenseCompute(LayerCompute):
-    """The original dense path: one GEMM / one batched conv per layer.
+    """The original dense path: one host GEMM per fc layer, one device
+    program per conv layer (:func:`_dense_conv`).
 
     Bit-exact reference — identical ops in identical order to the pre-seam
     ``SimLayer`` implementation, so every existing parity suite and every
@@ -176,29 +185,81 @@ class DenseCompute(LayerCompute):
         return pre, macs, fetches
 
     def conv_forward(self, layer, x_eff, act_mask, msgs_in):
-        """All-timesteps conv: one ``conv_general_dilated`` with batch = T
-        per (values, mask, ones) kernel.  Flat boundaries are channel-major
-        ((c, h, w)) on BOTH sides so conv->conv stacks keep consistent
-        receptive fields."""
-        T = x_eff.shape[0]
-        h, w = layer.in_hw
-        cin = layer.weights.shape[2]
-        to_nhwc = lambda a: np.transpose(a.reshape(T, cin, h, w),
-                                         (0, 2, 3, 1))
-        x4, m4 = _to_device(to_nhwc(x_eff), to_nhwc(act_mask))
-        wj, wmask, wones = layer._conv_kernels
+        """All-timesteps conv as one device program (:func:`_dense_conv`):
+        ``x_eff`` goes over as float32 and the 0/1 ``act_mask`` as bool,
+        one byte an element, and the three maps come back in one fetch."""
+        return self._conv(layer, np.asarray(x_eff, np.float32),
+                          np.asarray(act_mask) != 0, None)
 
-        conv = lambda lhs, rhs, precision=None: jax.lax.conv_general_dilated(
-            lhs, rhs, window_strides=(layer.stride, layer.stride),
-            padding="SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"),
-            precision=precision)
-        # values at f32 (HIGHEST): a TPU's default single bf16 pass moves
-        # them ~3e-3 relative; the 0/1 counter convs are exact in one pass
-        pre = _to_host(conv(x4, wj, jax.lax.Precision.HIGHEST))[0]
-        macs = _to_host(conv(m4, wmask))[0]
-        fetches = _to_host(conv(m4, wones))[0]
-        to_flat = lambda a: np.transpose(a, (0, 3, 1, 2)).reshape(T, -1)
-        return to_flat(pre), to_flat(macs), to_flat(fetches)
+    def delta_forward(self, layer, x_in, in_acc, act_mask, msgs_in):
+        """Conv layers reconstruct on the device: only the delta stream
+        ``x_in`` is sent (and ``in_acc`` where it is nonzero), and the
+        program derives the wire mask ``x_in != 0`` itself.  Fc layers keep
+        the base host cumsum."""
+        if layer.kind != "conv":
+            return super().delta_forward(layer, x_in, in_acc, act_mask,
+                                         msgs_in)
+        return self._conv(layer, np.asarray(x_in, np.float32), None,
+                          np.asarray(in_acc, np.float32)
+                          if np.any(in_acc) else None)
+
+    def _conv(self, layer, x, mask, acc):
+        """Run :func:`_dense_conv` and fetch its outputs with one
+        ``_to_host``; the fetch map, one channel on the device, is the same
+        in every output channel and is tiled here into the channel-major
+        flat map pricing reads."""
+        wj, wmask = layer._conv_kernels
+        x, mask, acc = _to_device(x, mask, acc)
+        pre, macs, fetch1, *new_acc = _to_host(*_dense_conv(
+            x, mask, acc, wj, wmask, hw=layer.in_hw, stride=layer.stride))
+        fetches = np.tile(fetch1, (1, wj.shape[3]))
+        return (pre, macs, fetches, *new_acc)
+
+
+@functools.partial(jax.jit, static_argnames=("hw", "stride"))
+def _dense_conv(x, mask, acc, wj, wmask, *, hw, stride):
+    """One dense conv layer's synaptic forward over all T steps, on the
+    device: ``(T, cin * h * w)`` channel-major flat input in,
+    ``(pre, macs, fetch1)`` out, ``pre`` and ``macs`` channel-major flat
+    ``(T, cout * oh * ow)`` and ``fetch1`` the ``(T, oh * ow)`` fetch map of
+    one output channel.  Flat boundaries are channel-major ((c, h, w)) on
+    both sides so conv->conv stacks keep consistent receptive fields.
+
+    With ``mask`` None, ``x`` is a delta stream: the wire mask is
+    ``x != 0``, the effective input is rebuilt by a sequential scan over T
+    from zero (the addition order of ``np.cumsum`` along time, so the bits
+    are the host's; ``jnp.cumsum`` may differ in the last bit), plus
+    ``acc`` where given, and the last row is returned as the new
+    accumulator.
+
+    The values conv runs at HIGHEST: a TPU's default single bf16 pass
+    moves them ~3e-3 relative, while the 0/1 MAC-mask conv is exact in one
+    pass.  The fetch map counts each window's events over every input
+    channel, so it is one conv of the channel-summed mask with a ones
+    kernel; its integer sums stay far below 2**24, so it is exact."""
+    T = x.shape[0]
+    h, w = hw
+    kh, kw, cin, _ = wj.shape
+    new_acc = ()
+    if mask is None:
+        mask = x != 0
+        _, x = jax.lax.scan(lambda c, r: (c + r, c + r),
+                            jnp.zeros_like(x[0]), x)
+        if acc is not None:
+            x = acc[None, :] + x
+        new_acc = (x[-1],)
+    to_nhwc = lambda a: a.reshape(T, cin, h, w).transpose(0, 2, 3, 1)
+    x4, m4 = to_nhwc(x), to_nhwc(mask.astype(jnp.float32))
+    conv = lambda lhs, rhs, precision=None: jax.lax.conv_general_dilated(
+        lhs, rhs, window_strides=(stride, stride), padding="SAME",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=precision)
+    highest = jax.lax.Precision.HIGHEST
+    pre = conv(x4, wj, highest)
+    macs = conv(m4, wmask)
+    fetch1 = conv(m4.sum(axis=3, keepdims=True),
+                  jnp.ones((kh, kw, 1, 1), jnp.float32), highest)
+    to_flat = lambda a: a.transpose(0, 3, 1, 2).reshape(T, -1)
+    return (to_flat(pre), to_flat(macs), fetch1.reshape(T, -1), *new_acc)
 
 
 # ------------------------------------------------------------------- event

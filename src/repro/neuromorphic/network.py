@@ -235,11 +235,11 @@ class SimLayer:
             self, "_w_nnz", lambda l: int((l.weights != 0).sum()))
 
     @property
-    def _conv_kernels(self) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-        """Device-resident conv kernels: (weights, nnz mask, all-ones)."""
+    def _conv_kernels(self) -> tuple[jnp.ndarray, jnp.ndarray]:
+        """Device-resident conv kernels: (weights, nnz mask)."""
         def build(l):
             wj = jnp.asarray(l.weights)
-            return wj, (wj != 0).astype(jnp.float32), jnp.ones_like(wj)
+            return wj, (wj != 0).astype(jnp.float32)
         return _compute.derived_from_weights(self, "_conv_kernels_cache",
                                              build)
 

@@ -270,3 +270,103 @@ class TestIm2col:
         row = pat[1 * 4 + 1]
         assert row[0 * 9 + 4] == x[0, 0, 1, 1]
         assert row[1 * 9 + 4] == x[0, 1, 1, 1]
+
+
+# ------------------------------------------------- the dense conv program
+
+def _eager_conv_layer(layer, x_eff, act_mask):
+    """The dense conv as three eager convs over host NHWC copies, each
+    fetched before the next: the formula the device program replaces."""
+    import jax
+    import jax.numpy as jnp
+    T = x_eff.shape[0]
+    h, w = layer.in_hw
+    cin = layer.weights.shape[2]
+    to_nhwc = lambda a: np.transpose(a.reshape(T, cin, h, w), (0, 2, 3, 1))
+    wj = jnp.asarray(layer.weights)
+    conv = lambda lhs, rhs, precision=None: np.asarray(
+        jax.lax.conv_general_dilated(
+            jnp.asarray(to_nhwc(lhs)), rhs,
+            window_strides=(layer.stride, layer.stride), padding="SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=precision))
+    pre = conv(x_eff, wj, jax.lax.Precision.HIGHEST)
+    macs = conv(act_mask, (wj != 0).astype(jnp.float32))
+    fetches = conv(act_mask, jnp.ones_like(wj))
+    to_flat = lambda a: np.transpose(a, (0, 3, 1, 2)).reshape(T, -1)
+    return to_flat(pre), to_flat(macs), to_flat(fetches)
+
+
+def _eager_delta_layer(layer, x_in, in_acc):
+    """The host time cumsum in front of :func:`_eager_conv_layer`."""
+    x_eff = np.cumsum(x_in, axis=0)
+    if np.any(in_acc):
+        x_eff = in_acc[None, :] + x_eff
+    act_mask = (x_in != 0).astype(np.float32)
+    return (*_eager_conv_layer(layer, x_eff, act_mask), x_eff[-1].copy())
+
+
+@quick
+@pytest.mark.parametrize("path", ["plain", "delta", "delta_acc"])
+@pytest.mark.parametrize("T", [1, 64])
+@pytest.mark.parametrize("cin", [2, 16])
+@pytest.mark.parametrize("stride", [1, 2])
+def test_dense_conv_program_is_bit_identical(stride, cin, T, path):
+    """One device program a conv layer gives the bits of the eager convs
+    on host layouts: ``pre``, ``macs``, ``fetches`` and, on a delta
+    input, the new accumulator."""
+    rng = np.random.default_rng(1000 * stride + 10 * cin + T)
+    h, w, cout = 10, 12, 6
+    wgt = rng.normal(0, 0.3, (3, 3, cin, cout)).astype(np.float32)
+    wgt *= _exact_density_mask(wgt.shape, 0.6, rng)
+    layer = SimLayer(name="c", kind="conv", weights=wgt, stride=stride,
+                     in_hw=(h, w))
+    n = cin * h * w
+    # a sparse wire stream of quantized deltas, as sigma-delta layers send
+    wire = (np.round(rng.normal(0, 4, (T, n))) * 0.05
+            * (rng.random((T, n)) < 0.2)).astype(np.float32)
+    dense = DenseCompute()
+    if path == "plain":
+        # the step-major engine's input to a delta layer: the rebuilt
+        # values beside the wire's own event mask
+        x_eff = np.cumsum(wire, axis=0)
+        act_mask = (wire != 0).astype(np.float32)
+        got = dense.conv_forward(layer, x_eff, act_mask, act_mask.sum(1))
+        want = _eager_conv_layer(layer, x_eff, act_mask)
+    else:
+        in_acc = (rng.normal(0, 1, n).astype(np.float32)
+                  if path == "delta_acc" else np.zeros(n, np.float32))
+        act_mask = (wire != 0).astype(np.float32)
+        got = dense.delta_forward(layer, wire, in_acc, act_mask,
+                                  act_mask.sum(1))
+        want = _eager_delta_layer(layer, wire, in_acc)
+    assert len(got) == len(want)
+    for name, a, b in zip(("pre", "macs", "fetches", "new_acc"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert np.array_equal(a, b), name
+
+
+@quick
+def test_dense_conv_fetch_map_is_the_window_sum_of_the_mask():
+    """An sdconv64-shaped layer (32x32x16 -> 16x16x32, 3x3 stride 2): the
+    fetch map is one map repeated in every output channel, the SAME-padded
+    3x3 window sum over all input channels of the event mask."""
+    rng = np.random.default_rng(7)
+    T, cin, cout, h, stride = 4, 16, 32, 32, 2
+    wgt = rng.normal(0, 0.3, (3, 3, cin, cout)).astype(np.float32)
+    layer = SimLayer(name="c", kind="conv", weights=wgt, stride=stride,
+                     in_hw=(h, h))
+    mask = (rng.random((T, cin * h * h)) < 0.1).astype(np.float32)
+    x_eff = mask * rng.normal(0, 1, mask.shape).astype(np.float32)
+    _, _, fetches = DenseCompute().conv_forward(layer, x_eff, mask,
+                                                mask.sum(1))
+    oh = h // stride
+    per_channel = fetches.reshape(T, cout, oh, oh)
+    assert (per_channel == per_channel[:, :1]).all()
+    # XLA's SAME split for a 3x3 window at stride 2 over 32: one row of
+    # padding at the end, none at the start
+    summed = np.pad(mask.reshape(T, cin, h, h).sum(axis=1),
+                    ((0, 0), (0, 1), (0, 1)))
+    win = np.lib.stride_tricks.sliding_window_view(summed, (3, 3),
+                                                   axis=(1, 2))
+    want = win[:, ::stride, ::stride].sum(axis=(3, 4))
+    assert np.array_equal(per_channel[:, 0], want)

@@ -30,6 +30,7 @@ from repro.kernels.flash_attn import flash_attention
 from repro.kernels.sigma_delta.ops import window_reconstruct
 from repro.launch import mesh as launch_mesh
 from repro.neuromorphic import precompute_pricing
+from repro.neuromorphic.compute import _dense_conv
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 T = chip_smoke.STEPS
@@ -85,9 +86,10 @@ def one_chip():
 
 
 def _compile(sharding, fn, shapes, **static):
-    """Compile ``fn`` for the described chip from (shape, dtype) pairs and
-    return the compiled HLO text."""
-    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    """Compile ``fn`` for the described chip from (shape, dtype) pairs, None
+    for an absent operand, and return the compiled HLO text."""
+    args = [None if sd is None else
+            jax.ShapeDtypeStruct(*sd, sharding=sharding) for sd in shapes]
     return fn.lower(*args, **static).compile().as_text()
 
 
@@ -125,6 +127,21 @@ def test_sigma_delta_encode_compiles(one_chip):
     shape = ((T, SD_WIDTH), jnp.float32)
     _assert_mosaic(_compile(one_chip, sigma_delta_encode, [shape, shape],
                             theta=0.1, interpret=False))
+
+
+@pytest.mark.parametrize("layer", range(len(chip_smoke.CONV_CHANNELS)))
+def test_dense_conv_program_compiles(one_chip, layer):
+    """The dense backend's one program a conv layer, at the sigma-delta
+    net's widths: layer 0 takes values and their event mask, the later
+    layers a delta stream they rebuild on the device."""
+    h = chip_smoke.CONV_HW[0] >> layer
+    cin = (2, *chip_smoke.CONV_CHANNELS)[layer]
+    w = ((3, 3, cin, chip_smoke.CONV_CHANNELS[layer]), jnp.float32)
+    x = ((T, cin * h * h), jnp.float32)
+    mask = None if layer else ((T, cin * h * h), jnp.bool_)
+    text = _compile(one_chip, _dense_conv, [x, mask, None, w, w],
+                    hw=(h, h), stride=2)
+    assert "convolution" in text
 
 
 @pytest.mark.parametrize("window", [8, 32, 128])

@@ -251,15 +251,43 @@ def test_h2d_bytes_of_the_event_kernel_are_its_operands():
     assert _h2d(spans) == want
 
 
+def _conv_inputs(layer) -> int:
+    return layer.weights.shape[2] * layer.in_hw[0] * layer.in_hw[1]
+
+
 def test_h2d_bytes_of_the_dense_conv_are_its_inputs_and_masks():
     net, T = _conv_net("sd_relu"), 6
     xs = make_inputs(net.in_size, 0.3, T, seed=3)
     net.run_batch(xs)                         # kernels cached on the device
     _, spans = _traced(lambda: net.run_batch(xs))
-    convs = [l for l in net.layers if l.kind == "conv"]
-    want = sum(4 * 2 * T * l.weights.shape[2] * l.in_hw[0] * l.in_hw[1]
-               for l in convs)
+    conv0, conv1 = [l for l in net.layers if l.kind == "conv"]
+    # layer 0 takes the raw stream: its values as float32 and its wire mask
+    # at one byte an element; layer 1 takes layer 0's deltas alone and
+    # derives the mask on the device
+    want = (4 + 1) * T * _conv_inputs(conv0) + 4 * T * _conv_inputs(conv1)
     assert _h2d(spans) == want
+
+
+def test_a_dense_conv_layer_fetches_once_and_counts_its_bytes():
+    net, T = _conv_net("sd_relu"), 6
+    xs = make_inputs(net.in_size, 0.3, T, seed=3)
+    net.run_batch(xs)
+    _, spans = _traced(lambda: net.run_batch(xs))
+    idx = _by_index(spans)
+    fetches = [s for s in spans if s.name == "kernel.fetch"]
+    synaptic = [s for s in spans if s.name == "sim.synaptic"]
+    convs = [l for l in net.layers if l.kind == "conv"]
+    # one fetch inside each conv layer's synaptic span; the fc layer runs
+    # on the host
+    assert sorted(idx[s.parent].index for s in fetches) == \
+        [s.index for s in synaptic[:len(convs)]]
+    for span, layer, delta in zip(fetches, convs, (False, True)):
+        oh, ow = layer.out_hw
+        # pre and macs of every output channel, the fetch map of one, and
+        # the new accumulator of a delta input
+        want = 4 * (T * (2 * layer.weights.shape[3] + 1) * oh * ow
+                    + delta * _conv_inputs(layer))
+        assert span.counts == {"d2h_bytes": want}
 
 
 def test_h2d_bytes_of_the_windowed_delta_path():
